@@ -22,9 +22,10 @@ LDLIBS += -lopencv_imgcodecs -lopencv_imgproc -lopencv_core
 # actual compile+link of jpeg_mem_src so a header-only or stub install
 # never produces a lib that fails at load time.  Only meaningful with
 # OpenCV present (the loader's fallback decoder).
+PROBE_DIR := $(or $(TMPDIR),/tmp)
 LIBJPEG_OK := $(shell printf '#include <stdio.h>\n#include <jpeglib.h>\nint main(){struct jpeg_decompress_struct c;(void)c;(void)jpeg_mem_src;return 0;}\n' \
-	      > /tmp/_mxtpu_jpeg_probe.c && \
-	      $(CXX) -x c /tmp/_mxtpu_jpeg_probe.c -ljpeg -o /tmp/_mxtpu_jpeg_probe 2>/dev/null \
+	      > $(PROBE_DIR)/_mxtpu_jpeg_probe.c && \
+	      $(CXX) -x c $(PROBE_DIR)/_mxtpu_jpeg_probe.c -ljpeg -o $(PROBE_DIR)/_mxtpu_jpeg_probe 2>/dev/null \
 	      && echo 1)
 ifeq ($(LIBJPEG_OK),1)
 CXXFLAGS += -DMXTPU_WITH_LIBJPEG
@@ -96,8 +97,14 @@ tsan:
 analyze-check:
 	python tools/analyze/mxlint.py
 
+# the quickest proof that the system still starts on the chip: ResNet-50
+# fused training then serving on one TPU (fails at once without one);
+# `python chip_smoke.py --chips 4` is the dp=2 x tp=2 phase alone
+chip-smoke:
+	python chip_smoke.py
+
 clean:
-	rm -f $(LIB) $(ASAN_LIB) $(TSAN_LIB)
+	rm -f $(LIB) $(LIB).inputs.sha256 $(ASAN_LIB) $(TSAN_LIB)
 
 # multi-process parameter-server tests (pytest -m dist): excluded from
 # quick selections by marker, run here explicitly.  Each test carries a
@@ -269,4 +276,4 @@ tp-serve-check:
 	dispatch-check fused-check ckpt-check serve-check chaos-check \
 	pallas-check feed-check shard-check feed-service-check \
 	feed-chaos-check trace-check int8-check obs-check decode-check \
-	tp-serve-check
+	tp-serve-check chip-smoke

@@ -44,8 +44,8 @@ def build(depth, classes, image):
 
 
 def score(net, batch, image, iters, warmup=4, tag="fp32", dtype=None):
-    """Fresh on-device batch per iteration (execution-memoisation-proof,
-    same anti-caching contract as bench.py)."""
+    """Fresh on-device batch per iteration (same contract as
+    bench.py)."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -18,38 +18,25 @@ from __future__ import annotations
 __version__ = "2.0.0.tpu0"
 
 
-def _init_compile_cache():
-    """Persistent XLA compilation cache (≙ the reference shipping
-    pre-built kernels: an op's first-ever compile is paid once per
-    machine, not once per process).  Opt-in via MXNET_COMPILE_CACHE=1;
-    MXNET_COMPILE_CACHE_DIR overrides the on-disk location.  Must run
-    before the first jit call, hence at package-import time."""
+def _place_compile_cache():
+    """JAX's persistent compilation cache, placed from outside.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and no
+    directory is set here; otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` (the path is part of the cache key, so a
+    directory that moves never hits).  JAX's size/time thresholds stay
+    at their defaults.  Must run before the first jit call, hence at
+    package-import time."""
     import os as _os
-    if _os.environ.get("MXNET_COMPILE_CACHE", "").lower() in \
-            ("", "0", "false", "off"):
+    if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
-    path = _os.environ.get("MXNET_COMPILE_CACHE_DIR") or _os.path.join(
-        _os.path.expanduser("~"), ".cache", "mxnet_tpu", "xla")
-    try:
-        import jax as _jax
-        _os.makedirs(path, exist_ok=True)
-        _jax.config.update("jax_compilation_cache_dir", path)
-        # default thresholds skip sub-second/small programs — exactly the
-        # per-op executables the dispatch cache produces; cache everything
-        for knob, val in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                _jax.config.update(knob, val)
-            except Exception:
-                pass    # knob renamed/absent in this jax — keep defaults
-    except Exception as e:     # never block import on a cache-dir problem
-        import sys as _sys
-        _sys.stderr.write(
-            "[mxnet_tpu] persistent compile cache disabled: %s\n" % (e,))
+    import jax as _jax
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
 
 
-_init_compile_cache()
+_place_compile_cache()
 
 # MXNET_LOCK_CHECK=1|warn: wrap threading.Lock/RLock/Condition with the
 # order-recording watchdog BEFORE any submodule constructs its locks —

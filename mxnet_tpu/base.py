@@ -3,7 +3,9 @@ over the reference's libmxnet.so C API, include/mxnet/c_api.h).
 
 The native runtime (`libmxtpu_rt.so`, sources under src/) provides the async
 dependency engine, pooled storage manager, thread pool and RecordIO reader/
-writer.  It is auto-built with g++ on first import if missing or stale;
+writer.  It is auto-built with g++ on first import if missing or stale
+(stale = the sha256 of the build inputs differs from the one stored
+beside the library — content, not mtimes);
 callers must tolerate ``LIB is None`` (pure-Python fallbacks) so the package
 still imports on machines without a toolchain.
 """
@@ -19,6 +21,9 @@ __all__ = ["LIB", "check_call", "MXTpuError", "lib_path"]
 _CUR = os.path.dirname(os.path.abspath(__file__))
 _ROOT = os.path.dirname(_CUR)
 _LIB_PATH = os.path.join(_CUR, "lib", "libmxtpu_rt.so")
+_STAMP_PATH = _LIB_PATH + ".inputs.sha256"
+
+
 def _build_inputs():
     """Everything the native build reads: all sources/headers under src/
     and include/ (globbed, not hand-listed — a hand-kept list here once
@@ -27,7 +32,24 @@ def _build_inputs():
     out = []
     for pat in ("Makefile", "src/*.cc", "src/*.h", "include/mxtpu/*.h"):
         out.extend(glob.glob(os.path.join(_ROOT, pat)))
-    return out
+    return sorted(out)
+
+
+def _inputs_digest() -> str:
+    """sha256 over the names and bytes of the build inputs.  Stored
+    beside the library when it is built, so staleness is a matter of
+    content: a copy or checkout that resets mtimes rebuilds nothing."""
+    import hashlib
+    h = hashlib.sha256()
+    for path in _build_inputs():
+        try:
+            with open(path, "rb") as f:
+                data = f.read()
+        except OSError:      # deleted between glob and read (branch switch)
+            continue
+        h.update(os.path.relpath(path, _ROOT).encode() + b"\0")
+        h.update(data + b"\0")
+    return h.hexdigest()
 
 
 class MXTpuError(RuntimeError):
@@ -37,14 +59,11 @@ class MXTpuError(RuntimeError):
 def _needs_build() -> bool:
     if not os.path.exists(_LIB_PATH):
         return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    for s in _build_inputs():
-        try:
-            if os.path.getmtime(s) > lib_mtime:
-                return True
-        except OSError:      # deleted between glob and stat (branch switch)
-            continue
-    return False
+    try:
+        with open(_STAMP_PATH) as f:
+            return f.read().strip() != _inputs_digest()
+    except OSError:          # library without a stamp: built by hand
+        return True
 
 
 def _build() -> bool:
@@ -67,11 +86,15 @@ def _build() -> bool:
             return True
         tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
         try:
+            digest = _inputs_digest()
             subprocess.run(
                 ["make", "-C", _ROOT, "-B",
                  f"LIB={os.path.relpath(tmp, _ROOT)}"],
                 check=True, capture_output=True, timeout=300)
             os.replace(tmp, _LIB_PATH)
+            with open(f"{_STAMP_PATH}.{os.getpid()}.tmp", "w") as f:
+                f.write(digest + "\n")
+            os.replace(f.name, _STAMP_PATH)
             return True
         except Exception as e:  # toolchain missing / compile error → fallback
             sys.stderr.write(f"[mxnet_tpu] native build skipped: {e}\n")

@@ -109,7 +109,8 @@ def attn_table() -> dict:
 
 def attn_enabled() -> bool:
     """Master switch for the causal Pallas route.  Default: table-driven
-    on TPU only (interpret mode is a correctness tool, not a fast path);
+    on one TPU only (``pallas_block.one_tpu``; interpret mode is a
+    correctness tool, not a fast path);
     ``MXNET_TPU_PALLAS_ATTN=1`` forces routing on any platform (tests /
     ``make decode-check``); ``0`` disables outright — every prefill
     takes the XLA masked-einsum composition."""
@@ -118,7 +119,7 @@ def attn_enabled() -> bool:
         return False
     if v == "1":
         return True
-    return jax.devices()[0].platform == "tpu"
+    return pb.one_tpu()
 
 
 _fp_cache = {"key": None, "fp": None}

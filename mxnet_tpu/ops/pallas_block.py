@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-__all__ = ["interpret", "enabled", "stage_key", "table", "decide",
+__all__ = ["interpret", "enabled", "one_tpu", "stage_key", "table", "decide",
            "conv_wins", "dispatch_fingerprint", "eligible_block",
            "conv3x3", "conv3x3_dgrad", "conv3x3_wgrad",
            "residual_block_fused", "block_active"]
@@ -59,6 +59,18 @@ __all__ = ["interpret", "enabled", "stage_key", "table", "decide",
 def _tele():
     from .. import telemetry
     return telemetry
+
+
+def one_tpu() -> bool:
+    """Default-on condition shared by every Pallas route: this process
+    drives exactly one TPU.  A Mosaic kernel cannot be partitioned by
+    GSPMD (the lowering refuses: "wrap the call in a shard_map"), and a
+    route decided at trace time cannot see whether the program it is
+    traced into will be partitioned — with more than one device any
+    program may be.  So on a multi-chip host every default route answers
+    XLA until the kernels are wrapped (ROADMAP D2)."""
+    devs = jax.devices()
+    return devs[0].platform == "tpu" and len(devs) == 1
 
 
 def interpret() -> bool:
@@ -174,15 +186,16 @@ def table() -> dict:
 
 
 def enabled() -> bool:
-    """Master switch.  Default: route per table on TPU only (interpret
-    mode is a correctness tool, not a fast path).  "1" forces routing on
-    any platform (tests / pallas-check); "0" disables outright."""
+    """Master switch.  Default: route per table on one TPU only
+    (:func:`one_tpu`; interpret mode is a correctness tool, not a fast
+    path).  "1" forces routing on any platform (tests / pallas-check);
+    "0" disables outright."""
     v = os.environ.get("MXNET_TPU_PALLAS_BLOCK", "")
     if v == "0":
         return False
     if v == "1":
         return True
-    return jax.devices()[0].platform == "tpu"
+    return one_tpu()
 
 
 def block_active() -> bool:
@@ -305,11 +318,12 @@ def conv_wins(x_shape, w_shape, stride, pad, dilate, groups, dtype) -> bool:
 
 
 # ---------------------------------------------------------------- kernels
-def _patches(xp, r0, bh, W, C):
+def _patches(xp_ref, r0, bh, W, C):
     """(bh·W, 9C) patch matrix for output rows [r0, r0+bh): nine shifted
-    row-block slices of the padded image, tap-major columns (matches the
+    row-block loads from the padded-image ref (the TPU lowering slices
+    refs, not loaded values), tap-major columns (matches the
     (3,3,C,Cout) → (9C,Cout) weight reshape)."""
-    cols = [lax.dynamic_slice(xp, (r0 + dh, dw, 0), (bh, W, C))
+    cols = [xp_ref[0, pl.ds(r0 + dh, bh), pl.ds(dw, W), :]
             .reshape(bh * W, C)
             for dh in range(3) for dw in range(3)]
     return jnp.concatenate(cols, axis=1)
@@ -317,7 +331,7 @@ def _patches(xp, r0, bh, W, C):
 
 def _conv_kernel(xp_ref, w_ref, out_ref, *, bh, W, C, Cout):
     i = pl.program_id(1)
-    acc = jnp.dot(_patches(xp_ref[0], i * bh, bh, W, C), w_ref[:],
+    acc = jnp.dot(_patches(xp_ref, i * bh, bh, W, C), w_ref[:],
                   preferred_element_type=jnp.float32)
     out_ref[0] = acc.reshape(bh, W, Cout).astype(out_ref.dtype)
 
@@ -330,7 +344,7 @@ def _conv_affine_kernel(*refs, bh, W, C, Cout, add, relu):
     else:
         xp_ref, w_ref, sc_ref, sh_ref, out_ref = refs
     i = pl.program_id(1)
-    acc = jnp.dot(_patches(xp_ref[0], i * bh, bh, W, C), w_ref[:],
+    acc = jnp.dot(_patches(xp_ref, i * bh, bh, W, C), w_ref[:],
                   preferred_element_type=jnp.float32)
     acc = acc * sc_ref[0] + sh_ref[0]
     if add:
@@ -346,7 +360,7 @@ def _conv_stats_kernel(xp_ref, w_ref, z_ref, s1_ref, s2_ref,
     revisited (1, Cout) f32 block across the whole grid (sequential TPU
     grid → revisiting is safe), read straight off the f32 accumulator."""
     n, i = pl.program_id(0), pl.program_id(1)
-    acc = jnp.dot(_patches(xp_ref[0], i * bh, bh, W, C), w_ref[:],
+    acc = jnp.dot(_patches(xp_ref, i * bh, bh, W, C), w_ref[:],
                   preferred_element_type=jnp.float32)
     z_ref[0] = acc.reshape(bh, W, Cout).astype(z_ref.dtype)
     s1 = jnp.sum(acc, axis=0, keepdims=True)
@@ -381,7 +395,7 @@ def _affine_kernel(*refs, Cout, add, relu):
 def _wgrad_kernel(xp_ref, dy_ref, out_ref, *, bh, W, C, Cout):
     """dW (9C, Cout) accumulated over the (batch × row-block) grid."""
     n, i = pl.program_id(0), pl.program_id(1)
-    patches = _patches(xp_ref[0], i * bh, bh, W, C)
+    patches = _patches(xp_ref, i * bh, bh, W, C)
     dy = dy_ref[0].reshape(bh * W, Cout)
     contrib = lax.dot_general(patches, dy, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)

@@ -105,7 +105,8 @@ def table() -> dict:
 
 def int8_enabled() -> bool:
     """Master switch for the int8 Pallas route.  Default: table-driven
-    on TPU only (interpret mode is a correctness tool, not a fast path);
+    on one TPU only (``pallas_block.one_tpu``; interpret mode is a
+    correctness tool, not a fast path);
     ``MXNET_TPU_PALLAS_INT8=1`` forces routing on any platform (tests /
     ``make int8-check``); ``0`` disables outright — every quantized conv
     takes the XLA int8 composition."""
@@ -114,7 +115,7 @@ def int8_enabled() -> bool:
         return False
     if v == "1":
         return True
-    return jax.devices()[0].platform == "tpu"
+    return pb.one_tpu()
 
 
 _fp_cache = {"key": None, "fp": None}
@@ -204,7 +205,7 @@ def _qconv_affine_kernel(*refs, bh, W, C, Cout, add, relu):
     else:
         xp_ref, w_ref, sc_ref, sh_ref, out_ref = refs
     i = pl.program_id(1)
-    acc = jnp.dot(pb._patches(xp_ref[0], i * bh, bh, W, C), w_ref[:],
+    acc = jnp.dot(pb._patches(xp_ref, i * bh, bh, W, C), w_ref[:],
                   preferred_element_type=jnp.int32)
     y = acc.astype(jnp.float32) * sc_ref[0] + sh_ref[0]
     if add:

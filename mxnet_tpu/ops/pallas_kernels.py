@@ -7,9 +7,11 @@ kernel streams a row-block from HBM into VMEM once and finishes all math
 there (one read + one write per element instead of XLA's worst-case
 multi-pass).
 
-Dispatch contract: `*_fused` entry points run the Pallas kernel on TPU
-for tile-friendly shapes and fall back to the jnp reference elsewhere
-(CPU tests force `interpret=True` through the `_FORCE_INTERPRET` switch).
+Dispatch contract: `*_fused` entry points run the Pallas kernel where
+this process drives exactly one TPU (a Mosaic kernel cannot be
+partitioned by GSPMD, see `pallas_block.one_tpu`) for tile-friendly
+shapes, and the jnp reference elsewhere (CPU tests force
+`interpret=True` through the `_FORCE_INTERPRET` switch).
 Backward passes are custom_vjp closed forms — Pallas kernels are not
 auto-differentiable.
 """
@@ -20,32 +22,21 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    _HAVE_PALLAS = True
-except Exception:                                    # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+
+from . import pallas_block as _pb
 
 _FORCE_INTERPRET = False     # tests flip this to exercise kernels on CPU
 
 
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:                                # pragma: no cover
-        return False
-
-
 def _use_pallas(last_dim):
-    if not _HAVE_PALLAS:
-        return False
     if _FORCE_INTERPRET:
         return True
-    return _on_tpu() and last_dim % 128 == 0
+    return _pb.one_tpu() and last_dim % 128 == 0
 
 
 def _interpret():
-    return _FORCE_INTERPRET or not _on_tpu()
+    return _FORCE_INTERPRET or _pb.interpret()
 
 
 def _fit_block(n, block):

@@ -77,7 +77,9 @@ def _selfcheck(verbose: bool = True) -> int:
     ladder; a barrier-released burst of 16 concurrent single-item
     requests must be served through coalesced bucketed batches with
 
-    - every prediction bit-for-bit equal to the unbatched forward,
+    - every prediction equal to the unbatched forward within float
+      tolerance (a bucket-8 and a bucket-1 program are two XLA programs:
+      bits are equal only within one bucket, tests/test_serve.py),
     - at least one batch with fill > 1 (coalescing actually happened),
     - exactly 0 retraces after warm-up,
     - a reportable p99 from telemetry.quantile,
@@ -134,7 +136,8 @@ def _selfcheck(verbose: bool = True) -> int:
     for t in threads:
         t.join(30.0)
 
-    # bit-for-bit vs the unbatched eager forward of the same net
+    # vs the unbatched eager forward of the same net: another XLA
+    # program than the coalesced bucket, so close, not equal bits
     exact = True
     for i in range(n_req):
         if errors[i] is not None or results[i] is None:
@@ -142,7 +145,8 @@ def _selfcheck(verbose: bool = True) -> int:
             break
         ref = onp.asarray(net(mx.np.array(xs[i][None]))._data)
         got = results[i][0]
-        if got.shape != ref.shape or not (got == ref).all():
+        if got.shape != ref.shape or not onp.allclose(
+                got, ref, rtol=1e-5, atol=1e-6):
             exact = False
             break
 
@@ -207,7 +211,7 @@ def _selfcheck(verbose: bool = True) -> int:
         ("all %d requests served" % n_req,
          all(e is None for e in errors) and
          all(r is not None for r in results)),
-        ("predictions bit-for-bit vs unbatched forward", exact),
+        ("predictions equal unbatched forward within tolerance", exact),
         ("≥1 coalesced batch (fill > 1) in %d batches" % batches,
          coalesced >= 1),
         ("0 retraces after warm-up", retraces == 0),

@@ -1,0 +1,146 @@
+"""Compile for the chip, without the chip.
+
+The only file of the suite that describes a TPU: the installed TPU compiler
+compiles for a v5e that is described and not attached, so what it refuses
+(an unimplemented primitive in the Pallas TPU lowering, an unaligned slice,
+too much VMEM) fails here, on the CPU, at no chip time.  A compile that
+passes is not a chip run: ``chip_smoke.py`` is that.
+
+Only one process may load the TPU's library, so the topology is described
+inside a fixture (never at import, in a ``skipif`` or in ``parametrize``
+arguments), the compiles run in this test's own process, and every such
+test lives in this one file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops import (pallas_attention, pallas_block, pallas_int8,
+                           pallas_kernels)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """jax.devices() still says CPU here: steer the kernels out of
+    interpret mode in the test, not through an option of the program."""
+    monkeypatch.delenv("MXNET_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(pallas_block, "interpret", lambda: False)
+    monkeypatch.setattr(pallas_block, "one_tpu", lambda: True)
+
+
+def _compile(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return text
+
+
+def test_softmax_fused_fwd_and_grad(one_chip, compiled_kernels):
+    x = jax.ShapeDtypeStruct((4096, 1024), jnp.float32, sharding=one_chip)
+    _compile(pallas_kernels.softmax_fused, x)
+    _compile(jax.grad(lambda a: jnp.sum(pallas_kernels.softmax_fused(a) ** 2)),
+             x)
+
+
+def test_layernorm_fused(one_chip, compiled_kernels):
+    x = jax.ShapeDtypeStruct((8, 512, 768), jnp.float32, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((768,), jnp.float32, sharding=one_chip)
+    _compile(lambda a, b, c: pallas_kernels.layernorm_fused(a, b, c, 1e-5),
+             x, g, g)
+
+
+def test_causal_flash_forward(one_chip, compiled_kernels):
+    q = jax.ShapeDtypeStruct((8, 8, 1024, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    _compile(lambda a, b, c: pallas_attention._causal_attention_pallas(
+        a, b, c, 128 ** -0.5), q, q, q)
+
+
+def _stage_shape(stage, n=128):
+    h, w, c = (int(t) for t in stage.split("x"))
+    return (n, h, w, c)
+
+
+def test_default_block_routes_compile(one_chip, compiled_kernels,
+                                      monkeypatch):
+    """The invariant: every stage the default block table routes to
+    Pallas compiles for the chip at N=128 bf16 — forward with batch
+    stats (conv+stats, affine), frozen forward, and the backward the
+    table asks for (dgrad + wgrad when "pallas")."""
+    monkeypatch.delenv("MXNET_TPU_PALLAS_TABLE", raising=False)
+    monkeypatch.delenv("MXNET_TPU_PALLAS_STAGES", raising=False)
+    table = pallas_block.table()
+    assert table == pallas_block._DEFAULT_TABLE, \
+        "committed pallas_block_ab.json and _DEFAULT_TABLE disagree"
+    for stage, ent in sorted(table.items()):
+        if ent["fwd"] != "pallas":
+            continue
+        shape = _stage_shape(stage)
+        c = shape[-1]
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+        w = jax.ShapeDtypeStruct((3, 3, c, c), jnp.bfloat16,
+                                 sharding=one_chip)
+        v = jax.ShapeDtypeStruct((c,), jnp.float32, sharding=one_chip)
+        assert pallas_block.eligible_block(shape, w.shape, x.dtype, True)
+
+        def block(x, w, g, b, m, s, r, frozen):
+            return pallas_block.residual_block_fused(
+                x, w, g, b, m, s, r, frozen=frozen, bwd=ent["bwd"])[0]
+
+        def train(x, w, g, b, m, s, r):
+            return jax.grad(
+                lambda *a: jnp.sum(block(*a, m, s, r, False)
+                                   .astype(jnp.float32)),
+                argnums=(0, 1, 2, 3))(x, w, g, b)
+
+        text = _compile(train, x, w, v, v, v, v, x)
+        # conv+stats, affine, and dgrad + wgrad where the table says so
+        want = 4 if ent["bwd"] == "pallas" else 2
+        assert text.count("tpu_custom_call") >= want, stage
+        _compile(lambda *a: block(*a, True), x, w, v, v, v, v, x)
+
+
+def test_default_int8_routes_compile(one_chip, compiled_kernels,
+                                     monkeypatch):
+    """Same invariant for the int8 table: every routed stage's fused
+    dequant kernel compiles at N=128, with and without a residual."""
+    monkeypatch.delenv("MXNET_TPU_PALLAS_INT8_TABLE", raising=False)
+    for stage, ent in sorted(pallas_int8.table().items()):
+        if ent["fwd"] != "pallas":
+            continue
+        shape = _stage_shape(stage)
+        c = shape[-1]
+        qx = jax.ShapeDtypeStruct(shape, jnp.int8, sharding=one_chip)
+        qw = jax.ShapeDtypeStruct((3, 3, c, c), jnp.int8, sharding=one_chip)
+        v = jax.ShapeDtypeStruct((c,), jnp.float32, sharding=one_chip)
+        res = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+        assert pallas_int8.eligible_int8(shape, qw.shape, True)
+        _compile(pallas_int8.qconv3x3_affine, qx, qw, v, v, res)
+        _compile(pallas_int8.qconv3x3_affine, qx, qw, v, v)

@@ -104,3 +104,38 @@ def test_tensorboard_callback_fallback():
     cb(mx.callback.BatchEndParam(epoch=0, nbatch=1, eval_metric=metric,
                                  locals=None))
     assert cb.events and cb.events[0][0] == "accuracy"
+
+
+def test_tpu_context_raises_without_a_tpu():
+    """mx.tpu() never stands in for another platform: resolving it on a
+    host without a TPU is an error, not a CPU device under another name."""
+    import pytest
+    assert mx.cpu().jax_device.platform == "cpu"
+    with pytest.raises(RuntimeError, match="no 'tpu' device"):
+        mx.tpu().jax_device
+    with pytest.raises(RuntimeError, match="no 'tpu' device"):
+        mx.np.ones((2,), ctx=mx.tpu())
+
+
+def test_native_build_staleness_is_content_not_mtime(tmp_path, monkeypatch):
+    """base._needs_build compares a hash of the build inputs stored
+    beside the library: a copy that resets mtimes rebuilds nothing, an
+    edited source does."""
+    import os
+    from mxnet_tpu import base
+    src = tmp_path / "a.cc"
+    src.write_text("int f() { return 1; }\n")
+    lib = tmp_path / "lib.so"
+    lib.write_text("")
+    monkeypatch.setattr(base, "_build_inputs", lambda: [str(src)])
+    monkeypatch.setattr(base, "_LIB_PATH", str(lib))
+    monkeypatch.setattr(base, "_STAMP_PATH", str(lib) + ".inputs.sha256")
+    assert base._needs_build()                    # no stamp yet
+    (tmp_path / "lib.so.inputs.sha256").write_text(base._inputs_digest())
+    assert not base._needs_build()
+    os.utime(src, (2e9, 2e9))                     # newer mtime, same bytes
+    assert not base._needs_build()
+    src.write_text("int f() { return 2; }\n")     # new bytes
+    assert base._needs_build()
+    lib.unlink()
+    assert base._needs_build()                    # library missing

@@ -276,3 +276,26 @@ def test_fuse_step_zero_retraces(pallas_on):
     new_decisions = sum(cur.get(k, 0) - base.get(k, 0) for k in cur
                        if k.startswith("dispatch.pallas.hits."))
     assert new_decisions == 0
+
+
+def test_default_routes_need_exactly_one_tpu(monkeypatch):
+    """Default-on only where this process drives exactly one TPU: a
+    Mosaic kernel cannot be partitioned by GSPMD, so on a multi-chip
+    host (any program may be partitioned) every route answers XLA."""
+    from mxnet_tpu.ops import pallas_attention, pallas_int8, pallas_kernels
+
+    class Dev:
+        def __init__(self, platform):
+            self.platform = platform
+
+    for name in ("MXNET_TPU_PALLAS_BLOCK", "MXNET_TPU_PALLAS_INT8",
+                 "MXNET_TPU_PALLAS_ATTN"):
+        monkeypatch.delenv(name, raising=False)
+    gates = (pb.enabled, pallas_int8.int8_enabled,
+             pallas_attention.attn_enabled,
+             lambda: pallas_kernels._use_pallas(128))
+    for devs, want in (([Dev("tpu")], True), ([Dev("tpu")] * 4, False),
+                       ([Dev("cpu")], False)):
+        monkeypatch.setattr(jax, "devices", lambda *a, _d=devs: _d)
+        assert pb.one_tpu() is want
+        assert [g() for g in gates] == [want] * len(gates), devs
