@@ -142,6 +142,9 @@ class Block:
     def __setattr__(self, name, value):
         if isinstance(value, Block):
             self.__dict__.setdefault("_children", OrderedDict())[name] = value
+            # the name this child's ops carry in a device trace
+            # (jax.named_scope in __call__, only while a program is traced)
+            value.__dict__["_scope_name"] = name.replace("/", "_")
         elif isinstance(value, Parameter):
             self.__dict__.setdefault("_reg_params", OrderedDict())[name] = value
         super().__setattr__(name, value)
@@ -210,10 +213,22 @@ class Block:
     def __call__(self, *args, **kwargs):
         for h in self._forward_pre_hooks:
             h(self, args)
-        out = self.forward(*args, **kwargs)
+        if _trace_ctx.active:
+            with self._named_scope():
+                out = self.forward(*args, **kwargs)
+        else:
+            out = self.forward(*args, **kwargs)
         for h in self._forward_hooks:
             h(self, args, out)
         return out
+
+    def _named_scope(self):
+        """``jax.named_scope`` under this block's attribute name in its
+        parent (the class name for a root): nested calls give the ops of a
+        traced program the path ``features/4/0/body/3`` — the parameter
+        path with ``/`` for ``.`` (docs/tracing.md)."""
+        return jax.named_scope(
+            self.__dict__.get("_scope_name") or type(self).__name__)
 
     def forward(self, *args, **kwargs):
         raise NotImplementedError
@@ -335,7 +350,8 @@ class HybridBlock(Block):
         if self._active and not kwargs and args and all(
                 isinstance(a, NDArray) for a in args):
             if _trace_ctx.active:
-                return self.forward(*args)        # nested: outer jit covers us
+                with self._named_scope():          # nested: outer jit covers us
+                    return self.forward(*args)
             if not _bulk_exec_enabled():
                 # MXNET_EXEC_BULK_EXEC_{TRAIN,INFERENCE}=0 disables op
                 # batching in the reference's graph executor; the jit

@@ -107,7 +107,7 @@ def softmax(x, axis=-1, temperature: Optional[float] = None):
         x = x / temperature
     if axis in (-1, x.ndim - 1):
         from . import pallas_kernels as _pk
-        if _pk._use_pallas(x.shape[-1]):
+        if _pk._use_pallas(x.shape[-1], "softmax"):
             return _pk.softmax_fused(x)   # single-HBM-pass Pallas kernel
     return jax.nn.softmax(x, axis=axis)
 
@@ -483,7 +483,7 @@ def layer_norm(x, gamma, beta, axis=-1, eps=1e-5):
     """≙ LayerNorm (src/operator/nn/layer_norm.cc); fp32 stats."""
     if axis in (-1, x.ndim - 1) and x.dtype == jnp.float32:
         from . import pallas_kernels as _pk
-        if _pk._use_pallas(x.shape[-1]):
+        if _pk._use_pallas(x.shape[-1], "layernorm"):
             return _pk.layernorm_fused(x, gamma, beta, eps)
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=axis, keepdims=True)

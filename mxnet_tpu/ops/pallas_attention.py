@@ -256,6 +256,7 @@ def _causal_attention_pallas(q, k, v, scale):
                   pl.BlockSpec((1, Lk, D), lambda b, i: (b, 0, 0))],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         interpret=pb.interpret(),
+        name="mx_flash_fwd",
     )(q3, k3, v3)
     return out.reshape(B, H, Lq, D)
 

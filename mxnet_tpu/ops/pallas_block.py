@@ -430,9 +430,10 @@ def _out_spec(bh, W, Cout):
     return pl.BlockSpec((1, bh, W, Cout), lambda n, i: (n, i, 0, 0))
 
 
-def conv3x3(x, w, out_dtype=None):
+def conv3x3(x, w, out_dtype=None, name="mx_block_conv"):
     """Row-blocked 3×3/s1 SAME conv (no epilogue) — the plain forward
-    and, with rotated weights, the dgrad."""
+    and, with rotated weights, the dgrad (``name``: the kernel's
+    instruction name in a device trace, docs/tracing.md)."""
     N, H, W, C = x.shape
     Cout = w.shape[-1]
     bh = _pick_bh(H, W, C, jnp.dtype(x.dtype).itemsize)
@@ -446,13 +447,14 @@ def conv3x3(x, w, out_dtype=None):
         out_specs=_out_spec(bh, W, Cout),
         out_shape=jax.ShapeDtypeStruct((N, H, W, Cout), out_dtype or x.dtype),
         interpret=interpret(),
+        name=name,
     )(xp, wf)
 
 
 def conv3x3_dgrad(w, dy):
     """dx = conv3x3(dy, w rotated 180° and IO-transposed)."""
     w_rot = jnp.flip(jnp.flip(w, 0), 1).transpose(0, 1, 3, 2)
-    return conv3x3(dy, w_rot.astype(dy.dtype))
+    return conv3x3(dy, w_rot.astype(dy.dtype), name="mx_block_dx")
 
 
 def conv3x3_wgrad(x, dy):
@@ -472,6 +474,7 @@ def conv3x3_wgrad(x, dy):
         out_specs=pl.BlockSpec((9 * C, Cout), lambda n, i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((9 * C, Cout), jnp.float32),
         interpret=interpret(),
+        name="mx_block_dw",
     )(xp, dy)
     return dw.reshape(3, 3, C, Cout)
 
@@ -496,6 +499,7 @@ def _conv_affine(x, w, scale, shift, res, relu):
         out_specs=_out_spec(bh, W, Cout),
         out_shape=jax.ShapeDtypeStruct((N, H, W, Cout), x.dtype),
         interpret=interpret(),
+        name="mx_block_fwd",
     )(*args)
 
 
@@ -518,6 +522,7 @@ def _conv_stats(x, w):
                    jax.ShapeDtypeStruct((1, Cout), jnp.float32),
                    jax.ShapeDtypeStruct((1, Cout), jnp.float32)],
         interpret=interpret(),
+        name="mx_block_stats",
     )(xp, wf)
     return z, s1[0], s2[0]
 
@@ -542,6 +547,7 @@ def _affine(z, scale, shift, res, relu):
         out_specs=_out_spec(bh, W, Cout),
         out_shape=jax.ShapeDtypeStruct(z.shape, z.dtype),
         interpret=interpret(),
+        name="mx_block_affine",
     )(*args)
 
 

@@ -241,6 +241,7 @@ def qconv3x3_affine(qx, qw, scale, shift, res=None, relu=True,
         out_specs=pb._out_spec(bh, W, Cout),
         out_shape=jax.ShapeDtypeStruct((N, H, W, Cout), out_dtype),
         interpret=pb.interpret(),
+        name="mx_qconv3x3",
     )(*args)
 
 

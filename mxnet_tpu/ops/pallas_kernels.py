@@ -14,6 +14,12 @@ shapes, and the jnp reference elsewhere (CPU tests force
 `interpret=True` through the `_FORCE_INTERPRET` switch).
 Backward passes are custom_vjp closed forms — Pallas kernels are not
 auto-differentiable.
+
+Every routed call counts once, like ``pallas_block.decide``, when it is
+decided (trace time): ``dispatch.pallas.hits.<kernel>.<last_dim>`` where
+the forward kernel is emitted, ``dispatch.pallas.fallbacks.<kernel>.
+<last_dim>`` where the answer is XLA (off the tile, not one TPU, or
+LayerNorm's training forward).
 """
 from __future__ import annotations
 
@@ -29,10 +35,20 @@ from . import pallas_block as _pb
 _FORCE_INTERPRET = False     # tests flip this to exercise kernels on CPU
 
 
-def _use_pallas(last_dim):
-    if _FORCE_INTERPRET:
+def _count(outcome, kernel, last_dim):
+    _pb._tele().counter_add(
+        f"dispatch.pallas.{outcome}.{kernel}.{last_dim}", 1)
+
+
+def _use_pallas(last_dim, kernel=None):
+    """The routing decision.  With ``kernel`` a "no" counts one fallback;
+    the "yes" is counted where the kernel is emitted, so a caller may ask
+    first (``ops/nn.py``) and the ``*_fused`` entry point ask again."""
+    if _FORCE_INTERPRET or (_pb.one_tpu() and last_dim % 128 == 0):
         return True
-    return _pb.one_tpu() and last_dim % 128 == 0
+    if kernel:
+        _count("fallbacks", kernel, last_dim)
+    return False
 
 
 def _interpret():
@@ -58,6 +74,7 @@ def _softmax_kernel(x_ref, o_ref):
 
 def _softmax_pallas(x2d):
     rows, cols = x2d.shape
+    _count("hits", "softmax", cols)
     block_rows = _fit_block(rows, 512 * 128 // max(cols, 1))
     return pl.pallas_call(
         _softmax_kernel,
@@ -66,13 +83,14 @@ def _softmax_pallas(x2d):
         in_specs=[pl.BlockSpec((block_rows, cols), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         interpret=_interpret(),
+        name="mx_softmax_fwd",
     )(x2d)
 
 
 @jax.custom_vjp
 def softmax_fused(x):
     """Row softmax over the last axis, one HBM pass."""
-    if not _use_pallas(x.shape[-1]):
+    if not _use_pallas(x.shape[-1], "softmax"):
         return jax.nn.softmax(x, axis=-1)
     x2d = x.reshape(-1, x.shape[-1])
     return _softmax_pallas(x2d).reshape(x.shape)
@@ -102,6 +120,7 @@ def _layernorm_kernel(x_ref, g_ref, b_ref, o_ref, *, eps):
 
 def _layernorm_pallas(x2d, gamma, beta, eps):
     rows, cols = x2d.shape
+    _count("hits", "layernorm", cols)
     block_rows = _fit_block(rows, 512 * 128 // max(cols, 1))
     return pl.pallas_call(
         functools.partial(_layernorm_kernel, eps=eps),
@@ -112,13 +131,14 @@ def _layernorm_pallas(x2d, gamma, beta, eps):
                   pl.BlockSpec((cols,), lambda i: (0,))],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         interpret=_interpret(),
+        name="mx_layernorm_fwd",
     )(x2d, gamma, beta)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def layernorm_fused(x, gamma, beta, eps=1e-5):
     """LayerNorm over the last axis: stats + scale/shift in one pass."""
-    if not _use_pallas(x.shape[-1]):
+    if not _use_pallas(x.shape[-1], "layernorm"):
         mu = jnp.mean(x, axis=-1, keepdims=True)
         xc = x - mu
         var = jnp.mean(xc * xc, axis=-1, keepdims=True)
@@ -130,6 +150,7 @@ def layernorm_fused(x, gamma, beta, eps=1e-5):
 def _ln_fwd(x, gamma, beta, eps):
     # training forward: compute output straight from the residuals so the
     # stats pass runs once (the fused kernel stays the inference path)
+    _count("fallbacks", "layernorm", x.shape[-1])
     mu = jnp.mean(x, axis=-1, keepdims=True)
     xc = x - mu
     var = jnp.mean(xc * xc, axis=-1, keepdims=True)
@@ -193,6 +214,7 @@ def _attention_pallas(q, k, v, scale, block_q=128, block_k=128):
     (XLA DCEs the unused output)."""
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
+    _count("hits", "attention", D)
     block_q = _fit_block(Lq, block_q)
     block_k = _fit_block(Lk, block_k)
     q3 = q.reshape(B * H, Lq, D)
@@ -210,6 +232,7 @@ def _attention_pallas(q, k, v, scale, block_q=128, block_k=128):
         out_specs=(pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, block_q), lambda b, i: (b, i))),
         interpret=_interpret(),
+        name="mx_attn_fwd",
     )(q3, k3, v3)
     return out.reshape(B, H, Lq, D), lse.reshape(B, H, Lq)
 
@@ -227,8 +250,11 @@ def _attention_ref(q, k, v, scale):
 def _attn_use_pallas(q, k):
     """ONE forward/backward eligibility predicate — the two passes must
     always take matching code paths for a given shape."""
-    return _use_pallas(q.shape[-1]) and q.shape[-1] % 128 == 0 and \
+    ok = _use_pallas(q.shape[-1]) and q.shape[-1] % 128 == 0 and \
         not any(sz % 8 for sz in (q.shape[2], k.shape[2]))
+    if not ok:
+        _count("fallbacks", "attention", q.shape[-1])
+    return ok
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -357,6 +383,7 @@ def _attn_bwd_pallas(s, q, k, v, g, o, lse, block_q=128, block_k=128):
                   pl.BlockSpec((1, block_q), lambda b, i: (b, i))],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         interpret=_interpret(),
+        name="mx_attn_dq",
     )(q3, k3, v3, g3, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_attn_dkv_kernel, scale=s, q_len=Lq,
@@ -373,6 +400,7 @@ def _attn_bwd_pallas(s, q, k, v, g, o, lse, block_q=128, block_k=128):
         out_specs=(pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
                    pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0))),
         interpret=_interpret(),
+        name="mx_attn_dkv",
     )(q3, k3, v3, g3, lse, delta)
     return (dq.reshape(q.shape), dk.reshape(k.shape),
             dv.reshape(v.shape))

@@ -160,8 +160,11 @@ class Optimizer:
         wd = jnp.asarray(self.wd, jnp.float32)
         out_w, out_s = {}, {}
         for k in ws:
-            g = self._preprocess_grad(gs[k].astype(ws[k].dtype))
-            out_w[k], out_s[k] = self._update(ws[k], g, states[k], lr, wd, t)
+            # the parameter's name on its update's ops in a device trace
+            with jax.named_scope(str(k).replace("/", "_")):
+                g = self._preprocess_grad(gs[k].astype(ws[k].dtype))
+                out_w[k], out_s[k] = self._update(ws[k], g, states[k], lr,
+                                                  wd, t)
         return out_w, out_s
 
     def _fused_sig(self):

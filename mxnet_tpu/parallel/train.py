@@ -25,8 +25,10 @@ per-device parameter footprint drops to 1/tp.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import sys
+import time
 from typing import Callable, Optional
 
 import jax
@@ -88,6 +90,23 @@ def _fused_step_env() -> Optional[bool]:
     if v is None or v == "":
         return None
     return v not in ("0", "false", "False", "off")
+
+
+_NOT_BUILDING = contextlib.nullcontext()
+
+
+@contextlib.contextmanager
+def _building():
+    """``train.build`` around the call that makes a step program: collect
+    + trace + lower + compile-or-load.  Its duration also goes to the
+    counter ``fused.build_us``."""
+    t0 = time.perf_counter_ns()
+    try:
+        with _telemetry.span("train.build"):
+            yield
+    finally:
+        _telemetry.counter_add("fused.build_us",
+                               (time.perf_counter_ns() - t0) // 1000)
 
 
 _programs_built = 0
@@ -195,17 +214,22 @@ class FusedTrainStep:
                         # uint8/int8 loader batches (ImageRecordIter dtype=):
                         # pixels ride the wire 4× smaller; the cast to compute
                         # dtype fuses into the step here, on device
-                        x = x.astype(self._dtype or jnp.float32)
-                    out = net.forward(NDArray(x))
+                        with jax.named_scope("mx.cast"):
+                            x = x.astype(self._dtype or jnp.float32)
+                    with jax.named_scope("mx.fwd"):
+                        out = net.forward(NDArray(x))
                     if self._dtype is not None:
                         # logits back to f32 before the loss (softmax/log stay
                         # full precision, ≙ amp FP32_OPS list)
-                        if isinstance(out, (tuple, list)):
-                            out = type(out)(o.astype(jnp.float32) for o in out)
-                        else:
-                            out = out.astype(jnp.float32)
-                    l = loss_fn(out, NDArray(y))
-                    l = l.mean() if l.ndim > 0 else l
+                        with jax.named_scope("mx.cast"):
+                            if isinstance(out, (tuple, list)):
+                                out = type(out)(o.astype(jnp.float32)
+                                                for o in out)
+                            else:
+                                out = out.astype(jnp.float32)
+                    with jax.named_scope("mx.loss"):
+                        l = loss_fn(out, NDArray(y))
+                        l = l.mean() if l.ndim > 0 else l
                     by_id = {id(p): name for name, p in params.items()}
                     aux_vals = {by_id[id(p)]: ctx.aux_out[id(p)]
                                 for p in ctx.aux_params}
@@ -236,9 +260,11 @@ class FusedTrainStep:
             t = ctl["t"] + 1
 
             def loss_of(tr_):
-                sub = {k: cast_low(v) for k, v in tr_.items()}
-                sub.update({k: cast_frozen(k, v) for k, v in fr.items()})
-                lval, aux = forward(sub, sub_key, cast_low(x), y)
+                with jax.named_scope("mx.cast"):
+                    sub = {k: cast_low(v) for k, v in tr_.items()}
+                    sub.update({k: cast_frozen(k, v) for k, v in fr.items()})
+                    x_low = cast_low(x)
+                lval, aux = forward(sub, sub_key, x_low, y)
                 if scale:
                     lval = lval * scale
                 return lval, aux
@@ -248,8 +274,11 @@ class FusedTrainStep:
             if scale:
                 lval = lval / scale
                 grads = jax.tree_util.tree_map(lambda g: g / scale, grads)
-            grads = aggregate_grads(grads, self._mesh)
-            new_tr, new_states = opt._tree_update(tr, grads, states, lr, t)
+            with jax.named_scope("mx.grad_sync"):
+                grads = aggregate_grads(grads, self._mesh)
+            with jax.named_scope("mx.opt"):
+                new_tr, new_states = opt._tree_update(tr, grads, states,
+                                                      lr, t)
             new_fr = dict(fr)
             new_fr.update(aux)
             return lval, new_tr, new_fr, new_states, {"rng": rng, "t": t}
@@ -260,6 +289,29 @@ class FusedTrainStep:
 
     # ------------------------------------------------------------------- call
     def __call__(self, x, y):
+        first = self._compiled is None
+        # rotate the per-step trace id: this step's span, the DataFeed
+        # wait that follows it and any checkpoint pause share one trace
+        _telemetry.set_current_trace()
+        with _telemetry.span("train.step", step=self._opt.num_update + 1):
+            with _building() if first else _NOT_BUILDING:
+                with _telemetry.span("train.prep"):
+                    x_raw, y_raw = self._prepare(x, y)
+                _telemetry.counter_add("fused.steps")
+                _telemetry.counter_add("fused.dispatches")
+                with _telemetry.span("train.launch"), \
+                        _telemetry.timed("fused.step_us"):
+                    (lval, self._tr, self._fr, self._states,
+                     self._ctl) = self._compiled(
+                        self._tr, self._fr, self._states, self._ctl,
+                        self._lr_dev, x_raw, y_raw)
+            with _telemetry.span("train.writeback"):
+                self._writeback()
+        return NDArray(lval)
+
+    def _prepare(self, x, y):
+        """Everything the host does before the launch: the first call's
+        collect + build, batch placement, the ``t`` and ``lr`` resync."""
         x_raw = x._data if isinstance(x, NDArray) else jnp.asarray(x)
         y_raw = y._data if isinstance(y, NDArray) else jnp.asarray(y)
         if self._compiled is None:
@@ -287,18 +339,7 @@ class FusedTrainStep:
         if lr != self._lr_host:
             self._lr_host = lr
             self._lr_dev = jnp.asarray(lr, jnp.float32)
-        _telemetry.counter_add("fused.steps")
-        _telemetry.counter_add("fused.dispatches")
-        # rotate the per-step trace id: this step's span, the DataFeed
-        # wait that follows it and any checkpoint pause share one trace
-        _telemetry.set_current_trace()
-        with _telemetry.span("train.step", step=self._t_host), \
-                _telemetry.timed("fused.step_us"):
-            lval, self._tr, self._fr, self._states, self._ctl = self._compiled(
-                self._tr, self._fr, self._states, self._ctl, self._lr_dev,
-                x_raw, y_raw)
-        self._writeback()
-        return NDArray(lval)
+        return x_raw, y_raw
 
     def _writeback(self):
         """Reflect updated buffers into the user-visible Parameters (cheap:
@@ -512,25 +553,31 @@ class TrainerFusedStep:
                 pvals.update(fr)
                 prev_train = tape.set_training(True)
                 try:
-                    outs, aux = fn(sub_key, pvals, x)
+                    with jax.named_scope("mx.fwd"):
+                        outs, aux = fn(sub_key, pvals, x)
                 finally:
                     tape.set_training(prev_train)
                 out_nd = tuple(NDArray(o) for o in outs)
-                l = loss_fn(out_nd[0] if len(out_nd) == 1 else out_nd,
-                            NDArray(y))
-                lraw = l._data if isinstance(l, NDArray) else l
-                # grads of SUM(loss): identical to the legacy tape, which
-                # seeds backward() with ones over the per-sample loss —
-                # the mean comes from rescale_grad inside _tree_update
-                return lraw.sum(), (lraw, aux)
+                with jax.named_scope("mx.loss"):
+                    l = loss_fn(out_nd[0] if len(out_nd) == 1 else out_nd,
+                                NDArray(y))
+                    lraw = l._data if isinstance(l, NDArray) else l
+                    # grads of SUM(loss): identical to the legacy tape,
+                    # which seeds backward() with ones over the per-sample
+                    # loss — the mean comes from rescale_grad inside
+                    # _tree_update
+                    return lraw.sum(), (lraw, aux)
 
             (lsum, (lraw, aux)), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(tr)
             # with a plan, grads land in the STORAGE layout (dp all-reduce
             # + tp slice in one collective — no gather of gradients) and
             # the optimizer update below is tp-local 1/tp work
-            grads = aggregate_grads(grads, mesh, shardings=storage)
-            new_tr, new_states = opt._tree_update(tr, grads, states, lr, t)
+            with jax.named_scope("mx.grad_sync"):
+                grads = aggregate_grads(grads, mesh, shardings=storage)
+            with jax.named_scope("mx.opt"):
+                new_tr, new_states = opt._tree_update(tr, grads, states,
+                                                      lr, t)
             if storage is not None:
                 new_tr = {n: jax.lax.with_sharding_constraint(v, storage[n])
                           for n, v in new_tr.items()}
@@ -577,20 +624,17 @@ class TrainerFusedStep:
         y_raw = y._data if isinstance(y, NDArray) else jnp.asarray(y)
         if batch_size is None:
             batch_size = int(x_raw.shape[0])
-        if self.fallback_reason is None and self._fn is None:
-            self._build_data(x_raw)
-        if self.fallback_reason is not None:
-            return self._legacy_step(x_raw, y_raw, batch_size,
-                                     ignore_stale_grad)
-        _telemetry.counter_add("fused.steps")
-        # per-step trace rotation (step id = the post-increment count
-        # _fused_step is about to commit — continues across a
-        # checkpoint restore because num_update is restored state)
-        _telemetry.set_current_trace()
-        with _telemetry.span("train.step",
-                             step=int(self._opt.num_update) + 1), \
-                _telemetry.timed("fused.step_us"):
-            return self._fused_step(x_raw, y_raw, batch_size)
+        if self.fallback_reason is None:
+            # per-step trace rotation (step id = the post-increment count
+            # _fused_step is about to commit — continues across a
+            # checkpoint restore because num_update is restored state)
+            _telemetry.set_current_trace()
+            with _telemetry.span("train.step",
+                                 step=int(self._opt.num_update) + 1):
+                out = self._fused_step(x_raw, y_raw, batch_size)
+            if out is not None:
+                return out
+        return self._legacy_step(x_raw, y_raw, batch_size, ignore_stale_grad)
 
     def _legacy_step(self, x_raw, y_raw, batch_size, ignore_stale_grad):
         _telemetry.counter_add("fused.steps")
@@ -615,6 +659,8 @@ class TrainerFusedStep:
         return l.mean() if l.ndim > 0 else l
 
     def _fused_step(self, x_raw, y_raw, batch_size):
+        """One fused step, or None where the first call's collect found a
+        reason to fall back (``fallback_reason`` then says which)."""
         tr, opt = self._trainer, self._opt
         # mirror Trainer.step's bookkeeping exactly: rescale from the
         # batch size, THEN advance num_update, THEN read the lr property
@@ -622,58 +668,78 @@ class TrainerFusedStep:
         opt.rescale_grad = tr._scale / batch_size
         sig = (opt._fused_sig(),
                self._plan.fingerprint if self._plan is not None else None)
-        if self._compiled is None:
-            self._build_jit()
-        elif sig != self._sig:
-            # rescale/clip/wd are python constants of the trace — a new
-            # batch size (or live optimizer mutation) means a new program;
-            # a changed PLAN fingerprint additionally re-lays the stored
-            # tensors before recompiling against the new shardings
-            _telemetry.counter_add("fused.rebuilds")
-            if self._plan is not None and sig[1] != self._sig[1]:
-                self._place_storage()
-            self._build_jit()
-        if opt.num_update != self._t_host:
-            # legacy steps (or checkpoint resume) advanced the counter
-            # outside this executor — resync the device mirror
-            self._ctl = dict(self._ctl,
-                             t=jnp.asarray(opt.num_update, jnp.int32))
-        opt.num_update += 1
-        self._t_host = opt.num_update
-        lr = float(opt.learning_rate)
-        if lr != self._lr_host:
-            self._lr_host = lr
-            self._lr_dev = jnp.asarray(lr, jnp.float32)
-        tr_vals = {n: self._params[n]._data._data for n in self._tr_names}
-        fr_vals = {n: self._params[n]._data._data for n in self._fr_names}
-        states = {n: tr._states[self._tname[n]] for n in self._tr_names}
-        if self._mesh is not None:
-            # batch_sharding resolves a nested data axis (dp_out, dp_in)
-            # to the tuple spec — the WorkersMerge hierarchy at the
-            # collective layer (ICI-first inner reduce, DCN-second outer)
-            bs = _batch_sharding(self._mesh, x_raw.ndim, self._batch_axis)
-            ys = _batch_sharding(self._mesh, y_raw.ndim, self._batch_axis)
-            x_raw = jax.device_put(x_raw, bs)
-            y_raw = jax.device_put(y_raw, ys)
-        if self._coll_bytes:
-            for ax, nbytes in self._coll_bytes.items():
-                _telemetry.counter_add(f"collective.{ax}.bytes", nbytes)
-        _telemetry.counter_add("fused.dispatches")
-        lval, new_tr, new_fr, new_states, self._ctl = self._compiled(
-            tr_vals, fr_vals, states, self._ctl, self._lr_dev, x_raw, y_raw)
+        build = self._compiled is None or sig != self._sig
+        with _building() if build else _NOT_BUILDING:
+            with _telemetry.span("train.prep"):
+                if self._fn is None:
+                    self._build_data(x_raw)
+                    if self.fallback_reason is not None:
+                        return None
+                if self._compiled is None:
+                    self._build_jit()
+                elif build:
+                    # rescale/clip/wd are python constants of the trace —
+                    # a new batch size (or live optimizer mutation) means
+                    # a new program; a changed PLAN fingerprint
+                    # additionally re-lays the stored tensors before
+                    # recompiling against the new shardings
+                    _telemetry.counter_add("fused.rebuilds")
+                    if self._plan is not None and sig[1] != self._sig[1]:
+                        self._place_storage()
+                    self._build_jit()
+                if opt.num_update != self._t_host:
+                    # legacy steps (or checkpoint resume) advanced the
+                    # counter outside this executor — resync the device
+                    # mirror
+                    self._ctl = dict(self._ctl,
+                                     t=jnp.asarray(opt.num_update, jnp.int32))
+                opt.num_update += 1
+                self._t_host = opt.num_update
+                lr = float(opt.learning_rate)
+                if lr != self._lr_host:
+                    self._lr_host = lr
+                    self._lr_dev = jnp.asarray(lr, jnp.float32)
+                tr_vals = {n: self._params[n]._data._data
+                           for n in self._tr_names}
+                fr_vals = {n: self._params[n]._data._data
+                           for n in self._fr_names}
+                states = {n: tr._states[self._tname[n]]
+                          for n in self._tr_names}
+                if self._mesh is not None:
+                    # batch_sharding resolves a nested data axis (dp_out,
+                    # dp_in) to the tuple spec — the WorkersMerge hierarchy
+                    # at the collective layer (ICI-first inner reduce,
+                    # DCN-second outer)
+                    bs = _batch_sharding(self._mesh, x_raw.ndim,
+                                         self._batch_axis)
+                    ys = _batch_sharding(self._mesh, y_raw.ndim,
+                                         self._batch_axis)
+                    x_raw = jax.device_put(x_raw, bs)
+                    y_raw = jax.device_put(y_raw, ys)
+            if self._coll_bytes:
+                for ax, nbytes in self._coll_bytes.items():
+                    _telemetry.counter_add(f"collective.{ax}.bytes", nbytes)
+            _telemetry.counter_add("fused.steps")
+            _telemetry.counter_add("fused.dispatches")
+            with _telemetry.span("train.launch"), \
+                    _telemetry.timed("fused.step_us"):
+                lval, new_tr, new_fr, new_states, self._ctl = self._compiled(
+                    tr_vals, fr_vals, states, self._ctl, self._lr_dev,
+                    x_raw, y_raw)
         # write back: swap raw buffers inside the existing NDArray handles
         # (no transfer), push fresh optimizer state into trainer._states,
         # and CONSUME every trainable grad edge — a fused step counts as
         # backward+step, so a following legacy update() must see stale
         # grads (raise), never re-apply old ones
-        for n in self._tr_names:
-            d = self._params[n]._data
-            d._data = new_tr[n]
-            if d._grad_edge is not None:
-                d._grad_edge.grad = None
-            tr._states[self._tname[n]] = new_states[n]
-        for n in self._fr_names:
-            self._params[n]._data._data = new_fr[n]
+        with _telemetry.span("train.writeback"):
+            for n in self._tr_names:
+                d = self._params[n]._data
+                d._data = new_tr[n]
+                if d._grad_edge is not None:
+                    d._grad_edge.grad = None
+                tr._states[self._tname[n]] = new_states[n]
+            for n in self._fr_names:
+                self._params[n]._data._data = new_fr[n]
         return NDArray(lval)
 
     def sync(self):

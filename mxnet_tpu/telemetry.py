@@ -35,7 +35,6 @@ import atexit
 import ctypes
 import json
 import os
-import random as _random
 import re
 import signal as _signal
 import sys
@@ -66,7 +65,7 @@ BUCKET_BOUNDS_US = [1.0, 2.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
 # else lands under "other".
 SECTIONS = ("engine", "storage", "dataio", "kvstore", "datafeed", "dispatch",
             "fused", "checkpoint", "serve", "router", "collective",
-            "feed_service", "quant", "obs", "decode")
+            "feed_service", "quant", "obs", "decode", "jit")
 
 _FALSY = ("0", "false", "off")
 
@@ -221,6 +220,44 @@ def reset():
     trace_reset()
 
 
+# ------------------------------------------------------- JAX's own events
+# One listener each way turns what JAX reports about tracing, lowering,
+# compiling and its persistent cache into registry counters: THAT a
+# program was compiled or loaded and what it cost (`fused.retraces` /
+# `fused.rebuilds` / `serve.retraces` say WHICH program).
+_JAX_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "jit.cache_hits",
+    "/jax/compilation_cache/cache_misses": "jit.cache_misses",
+    # a duration around compile-or-load (a persistent-cache hit is
+    # counted too); its occurrences are the count
+    "/jax/core/compile/backend_compile_duration": "jit.compiles",
+}
+_JAX_MICROS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace_us",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower_us",
+    "/jax/core/compile/backend_compile_duration": "jit.compile_us",
+}
+
+
+def _on_jax_event(event, **_kw):
+    name = _JAX_COUNTS.get(event)
+    if name:
+        counter_add(name)
+
+
+def _on_jax_duration(event, secs, **_kw):
+    name = _JAX_MICROS.get(event)
+    if name:
+        counter_add(name, int(secs * 1e6))
+        _on_jax_event(event)
+
+
+def _listen_to_jax():
+    from jax import monitoring
+    monitoring.register_event_listener(_on_jax_event)
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
 # ------------------------------------------------------------------ tracing
 # The flight recorder: spans land in a bounded lock-sharded per-process
 # ring buffer, always on by default (MXNET_TRACE=0 disables; the off
@@ -311,10 +348,21 @@ _tid_names: Dict[int, str] = {}     # thread ident → name, for "M" rows
 class _TraceTL(threading.local):
     trace_id: Optional[int] = None
     span_id: Optional[int] = None
+    id_pool: bytes = b""            # this thread's unread urandom bytes
+    id_at: int = 0
+    id_epoch: int = -1
 
 
 _trace_tl = _TraceTL()
 _INHERIT = object()                 # sentinel: parent from thread-local
+_TraceAnnotation = None             # jax.profiler's, loaded by the first span
+
+
+def _load_annotation():
+    global _TraceAnnotation
+    from jax.profiler import TraceAnnotation
+    _TraceAnnotation = TraceAnnotation
+    return TraceAnnotation
 
 
 def trace_enabled() -> bool:
@@ -333,14 +381,30 @@ def set_trace_enabled(on: bool) -> bool:
 # ids must be unique ACROSS the fleet: every process calls mx.seed(0),
 # which seeds the global `random` module — drawing from it would give
 # every rank the identical id stream (and colliding span ids on the
-# merged timeline).  SystemRandom reads urandom directly: immune to
-# seeding and to fork-duplicated PRNG state.
-_id_rand = _random.SystemRandom()
+# merged timeline).  They come from urandom: immune to seeding.  One
+# read serves _ID_POOL ids of the calling thread (a read per id was
+# 40-45 us on the chip's host, PERF.md PR 27); a forked child drops the
+# pools it inherited, so it never repeats its parent's ids.
+_ID_POOL = 512
+_id_epoch = 0
+
+
+def _after_fork():
+    global _id_epoch
+    _id_epoch += 1
+
+
+os.register_at_fork(after_in_child=_after_fork)
 
 
 def _new_id() -> int:
     # non-zero 64-bit id
-    return _id_rand.getrandbits(64) | 1
+    tl = _trace_tl
+    if tl.id_at >= len(tl.id_pool) or tl.id_epoch != _id_epoch:
+        tl.id_pool, tl.id_at = os.urandom(8 * _ID_POOL), 0
+        tl.id_epoch = _id_epoch
+    tl.id_at += 8
+    return int.from_bytes(tl.id_pool[tl.id_at - 8:tl.id_at], "little") | 1
 
 
 def current_context() -> Optional[Tuple[int, Optional[int]]]:
@@ -405,11 +469,13 @@ class span:
     spans this one served (the batcher's fan-in join).  Timing is
     wall-clock µs from one clock at enter and exit, so a child's
     interval is contained in its parent's and shards from different
-    processes align on one merged timeline.  With MXNET_TRACE=0 enter
-    and exit are a single module-global check."""
+    processes align on one merged timeline.  Every span is also a
+    ``jax.profiler.TraceAnnotation`` of the same name and attributes, so
+    a profiler trace carries it on the profiler's clock.  With
+    MXNET_TRACE=0 enter and exit are a single module-global check."""
 
     __slots__ = ("name", "attrs", "_links", "_parent", "_t0",
-                 "_trace_id", "_span_id", "_parent_id", "_prev")
+                 "_trace_id", "_span_id", "_parent_id", "_prev", "_ann")
 
     def __init__(self, name: str, parent=_INHERIT, links=None, **attrs):
         self.name = name
@@ -435,6 +501,12 @@ class span:
         self._span_id = _new_id()
         self._prev = (tl.trace_id, tl.span_id)
         tl.trace_id, tl.span_id = trace_id, self._span_id
+        # the same span on the profiler's clock: a jax.profiler trace
+        # shows it on /host:CPU beside the device plane (a no-op check
+        # while no profiler session is running)
+        self._ann = (_TraceAnnotation or _load_annotation())(
+            self.name, **self.attrs)
+        self._ann.__enter__()
         self._t0 = time.time_ns() // 1000
         return self
 
@@ -442,6 +514,8 @@ class span:
         """Attach attributes to an open span (e.g. the hedge loser's
         ``cancelled=True``)."""
         self.attrs.update(attrs)
+        if self._t0 is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def context(self) -> Optional[Tuple[int, int]]:
@@ -461,6 +535,7 @@ class span:
         if self._t0 is None:
             return False
         t_end = time.time_ns() // 1000
+        self._ann.__exit__(exc_type, exc, tb)
         tl = _trace_tl
         tl.trace_id, tl.span_id = self._prev
         if exc_type is not None and "error" not in self.attrs:
@@ -751,6 +826,10 @@ def _prom_name(name: str) -> str:
 
 def _prom_fmt(v) -> str:
     if isinstance(v, float):
+        if v != v:
+            return "NaN"
+        if v in (float("inf"), float("-inf")):
+            return "+Inf" if v > 0 else "-Inf"
         return repr(v)
     return str(v)
 
@@ -760,23 +839,36 @@ def dump_prometheus() -> str:
     exposition format: a ``# HELP`` + ``# TYPE`` pair precedes every
     metric family and histogram buckets are emitted CUMULATIVE with a
     final le="+Inf", per the exposition spec — valid for a real
-    Prometheus scraper, not just our own router sweep."""
+    Prometheus scraper, not just our own router sweep.  One family per
+    exposition name whatever the registry holds: a name recorded as two
+    kinds (``dataio.decode_us`` is a counter and a histogram), two names
+    that sanitise to one, or a name that ends like a histogram's sample
+    (``_count``): the first keeps the plain name, a later one gets its
+    kind as a suffix."""
     raw = raw_snapshot()
-    lines = []
+    lines, taken = [], set()
+
+    def samples(p, kind):
+        return {p, p + "_bucket", p + "_sum", p + "_count"} \
+            if kind == "histogram" else {p}
+
+    def family(name, kind, note=""):
+        p = _prom_name(name)
+        if samples(p, kind) & taken:
+            p = f"{p}_{kind}"
+        while samples(p, kind) & taken:
+            p += "_"
+        taken.update(samples(p, kind))
+        lines.append(f"# HELP {p} mxnet_tpu {kind} {name}{note}")
+        lines.append(f"# TYPE {p} {kind}")
+        return p
+
     for name, v in raw.get("counters", {}).items():
-        p = _prom_name(name)
-        lines.append(f"# HELP {p} mxnet_tpu counter {name}")
-        lines.append(f"# TYPE {p} counter")
-        lines.append(f"{p} {v}")
+        lines.append(f"{family(name, 'counter')} {v}")
     for name, v in raw.get("gauges", {}).items():
-        p = _prom_name(name)
-        lines.append(f"# HELP {p} mxnet_tpu gauge {name}")
-        lines.append(f"# TYPE {p} gauge")
-        lines.append(f"{p} {v}")
+        lines.append(f"{family(name, 'gauge')} {v}")
     for name, h in raw.get("histograms", {}).items():
-        p = _prom_name(name)
-        lines.append(f"# HELP {p} mxnet_tpu histogram {name} (microseconds)")
-        lines.append(f"# TYPE {p} histogram")
+        p = family(name, "histogram", " (microseconds)")
         cum = 0
         for le, c in zip(h["le"], h["counts"]):
             cum += c
@@ -896,6 +988,7 @@ def _install_hooks():
         atexit.register(lambda: dump(reason="exit"))
     if os.environ.get("MXNET_TRACE_DIR"):
         atexit.register(_dump_trace_shard_quiet)
+    _listen_to_jax()
     if not hasattr(_signal, "SIGUSR2"):
         return
     if os.environ.get("MXNET_TELEMETRY_SIGNAL", "1").lower() in _FALSY:

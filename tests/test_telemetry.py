@@ -121,8 +121,19 @@ def test_observe_lands_in_correct_bucket(enabled_telemetry):
 
 # -------------------------------------------------------------- prometheus
 def test_prometheus_exposition_parses(enabled_telemetry):
+    # whatever earlier files of this worker left in the registry (names
+    # stay interned through reset()), plus every way two metrics can
+    # meet in one family: two kinds under one name, two names that
+    # sanitise to one, a counter named like a histogram's sample, and
+    # values with a negative exponent
+    telemetry.reset()
     _burst(16)
     telemetry.counter_add("test.prom_counter", 3)
+    telemetry.counter_add("test.prom_twice", 2)
+    telemetry.gauge_set("test.prom_twice", 5)
+    telemetry.observe("test.prom_twice", 1e-5)
+    telemetry.counter_add("test.prom-twice", 1)
+    telemetry.counter_add("test.prom_twice_count", 1)
     text = telemetry.dump_prometheus()
     assert "mxtpu_test_prom_counter 3" in text or \
         re.search(r"^mxtpu_test_prom_counter \d+$", text, re.M)
@@ -146,14 +157,14 @@ def test_prometheus_exposition_parses(enabled_telemetry):
                 types[fam] = rest
             continue
         m = re.match(r"^([a-zA-Z_:][a-zA-Z0-9_:]*)(\{[^}]*\})? "
-                     r"(-?[0-9.eE+]+|[+-]Inf)$", line)
+                     r"(-?[0-9.]+([eE][+-]?[0-9]+)?|NaN|[+-]Inf)$", line)
         assert m, f"malformed exposition line: {line!r}"
         series.setdefault(m.group(1), []).append(line)
     # every family is announced: a sample's base name (histogram
     # samples collapse _bucket/_sum/_count) has BOTH # HELP and # TYPE
     for name in series:
         base = re.sub(r"_(bucket|sum|count)$", "", name)
-        fam = base if base in types else name
+        fam = name if name in types else base
         assert fam in types, f"{name}: no # TYPE"
         assert fam in helps, f"{name}: no # HELP"
         if name != fam:       # a collapsed histogram sample suffix

@@ -1,179 +1,149 @@
 #!/usr/bin/env python
-"""Summarize a jax.profiler xplane trace: top HLO ops by total device time.
+"""Device time by scope: read a jax.profiler trace as an operator.
 
-Usage: python tools/xprof/summarize.py /tmp/jaxprof [N]
+    python tools/xprof/summarize.py <trace dir or .xplane.pb> [--depth 2] [--top 15]
 
-Replaces the tensorboard profile UI for sandbox use.  Parses the xplane
-protobuf with a dependency-free wire-format walker (schema: public tsl
-xplane.proto — XSpace.planes=1; XPlane.name=2,.lines=3,.event_metadata=4;
-XLine.name=3,.display_name=4,.events=7; XEvent.metadata_id=1,
-.duration_ps=3; XEventMetadata{key=1,value=2}, value.name=2,
-.display_name=3).
+Prints, for the window of the trace (the ``chipbench.window`` annotation
+where there is one, else everything): busy time by the program's named
+scopes (``mx.fwd/<block path>`` with its backward beside it, ``mx.loss``,
+``mx.opt`` ...; docs/tracing.md "Names in a device trace"), by Pallas
+kernel name (``mx_block_dw``), the longest instructions with their scope,
+and the program's ``train.*`` spans on the host plane.
+
+Events, times and host annotations come from ``jax.profiler.ProfileData``.
+The scope path of an instruction is the ``tf_op`` stat of its event
+*metadata*, which ProfileData (jaxlib 0.9.0) does not expose: it is read
+with the xplane schema that an installed TensorFlow ships
+(``xplane_pb2.py``, loaded by path; TensorFlow itself is not imported).
+Without it everything is listed under ``(no scope: xplane_pb2 not found)``.
 """
+import argparse
 import collections
 import glob
+import importlib.util
 import os
 import re
 import sys
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+from chipbench.trace_reduce import (DEVICE_PLANE, HOST_PLANE, OPS_LINE,  # noqa: E402
+                                    WINDOW, clip, merge, self_times)
 
-def _walk(buf, pos, end):
-    """Yield (field_no, wire_type, value, raw_bytes_or_None)."""
-    while pos < end:
-        tag, pos = _uvarint(buf, pos)
-        field, wt = tag >> 3, tag & 7
-        if wt == 0:
-            v, pos = _uvarint(buf, pos)
-            yield field, wt, v, None
-        elif wt == 1:
-            yield field, wt, int.from_bytes(buf[pos:pos + 8], "little"), None
-            pos += 8
-        elif wt == 2:
-            ln, pos = _uvarint(buf, pos)
-            yield field, wt, None, buf[pos:pos + ln]
-            pos += ln
-        elif wt == 5:
-            yield field, wt, int.from_bytes(buf[pos:pos + 4], "little"), None
-            pos += 4
-        else:
-            raise ValueError(f"wire type {wt}")
+PHASE = re.compile(r"(transpose\(jvp\()?(?:jvp\()?(mx\.\w+)\)*")
+KERNEL = re.compile(r"^%?(mx_\w+?)(\.\d+)? = .*tpu_custom_call")
+NO_SCHEMA = "(no scope: xplane_pb2 not found)"
 
 
-def _uvarint(buf, pos):
-    res = shift = 0
-    while True:
-        b = buf[pos]
-        pos += 1
-        res |= (b & 0x7F) << shift
-        if not b & 0x80:
-            return res, pos
-        shift += 7
-
-
-def _fields(raw):
-    return list(_walk(raw, 0, len(raw)))
-
-
-def parse_plane(raw):
-    """XPlane (vm.xplane.pb layout): {2: name, 3: lines, 4: event_metadata
-    map, 5: stat_metadata map}.  Each event_metadata value: {1: id, 2: HLO
-    long text, 4: short name, 5: stats (incl. hlo_category id 24)}."""
-    name, lines, meta, cat = "", [], {}, {}
-    for f, wt, v, b in _fields(raw):
-        if f == 2 and wt == 2:
-            name = b.decode("utf-8", "replace")
-        elif f == 3 and wt == 2:
-            lines.append(b)
-        elif f == 4 and wt == 2:
-            k, mname, mcat = None, "", ""
-            for f2, wt2, v2, b2 in _fields(b):
-                if f2 == 1 and wt2 == 0:
-                    k = v2
-                elif f2 == 2 and wt2 == 2:
-                    nm, disp = "", ""
-                    for f3, wt3, v3, b3 in _fields(b2):
-                        if f3 == 2 and wt3 == 2:
-                            nm = b3.decode("utf-8", "replace")
-                        elif f3 == 4 and wt3 == 2:
-                            disp = b3.decode("utf-8", "replace")
-                        elif f3 == 5 and wt3 == 2:
-                            sid, sval = None, ""
-                            for f4, wt4, v4, b4 in _fields(b3):
-                                if f4 == 1 and wt4 == 0:
-                                    sid = v4
-                                elif f4 == 5 and wt4 == 2:
-                                    sval = b4.decode("utf-8", "replace")
-                            if sid == 24:  # hlo_category
-                                mcat = sval
-                    mname = disp or nm[:80]
-            if k is not None:
-                meta[k] = mname
-                cat[k] = mcat
-    return name, lines, meta, cat
-
-
-def parse_line(raw):
-    """XLine: {1: id, 2: name, 4: repeated XEvent}.  XEvent: {1:
-    metadata_id, 2: offset_ps, 3: duration_ps, 4: stats}."""
-    lname, events = "", []
-    for f, wt, v, b in _fields(raw):
-        if f == 2 and wt == 2:
-            lname = b.decode("utf-8", "replace")
-        elif f == 4 and wt == 2:
-            mid = dur = 0
-            for f2, wt2, v2, b2 in _fields(b):
-                if f2 == 1 and wt2 == 0:
-                    mid = v2
-                elif f2 == 3 and wt2 == 0:
-                    dur = v2
-            events.append((mid, dur))
-    return lname, events
-
-
-def load(path):
-    if os.path.isdir(path):
-        cands = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
-                                 recursive=True))
-        path = cands[-1]
+def scopes_of(path):
+    """{instruction text: tf_op} from the event metadata of the device
+    planes, or None where no xplane schema is installed."""
+    spec = importlib.util.find_spec("tensorflow")
+    where = spec and os.path.join(os.path.dirname(spec.origin), "tsl",
+                                  "profiler", "protobuf", "xplane_pb2.py")
+    if not where or not os.path.exists(where):
+        return None
+    s = importlib.util.spec_from_file_location("_xplane_pb2", where)
+    pb2 = importlib.util.module_from_spec(s)
+    s.loader.exec_module(pb2)
+    space = pb2.XSpace()
     with open(path, "rb") as f:
-        buf = f.read()
-    planes = [b for f_, wt, v, b in _walk(buf, 0, len(buf))
-              if f_ == 1 and wt == 2]
-    return [parse_plane(p) for p in planes]
-
-
-GROUPS = [
-    ("conv", re.compile(r"convolution|conv(?![a-z])")),
-    ("matmul", re.compile(r"dot|matmul")),
-    ("collective", re.compile(r"all-reduce|reduce-scatter|all-gather")),
-    ("reduce", re.compile(r"reduce")),
-    ("copy/transpose", re.compile(r"copy|transpose|reshape|bitcast")),
-    ("convert", re.compile(r"convert")),
-    ("fusion(elementwise)", re.compile(r"fusion|add|multiply|subtract")),
-]
-
-
-def classify(name):
-    for label, pat in GROUPS:
-        if pat.search(name):
-            return label
-    return "other"
-
-
-def main():
-    path = sys.argv[1] if len(sys.argv) > 1 else "/tmp/jaxprof"
-    topn = int(sys.argv[2]) if len(sys.argv) > 2 else 30
-    per_op = collections.Counter()
-    per_op_count = collections.Counter()
-    planes_used = []
-    per_cat = collections.Counter()
-    for name, lines, meta, cat in load(path):
-        if "TPU" not in name:
+        space.ParseFromString(f.read())
+    out = {}
+    for plane in space.planes:
+        if not DEVICE_PLANE.match(plane.name):
             continue
-        for lraw in lines:
-            lname, events = parse_line(lraw)
-            if lname != "XLA Ops":
-                continue  # Steps/Modules/Async lines double-count time
-            planes_used.append(f"{name}/{lname}")
-            for mid, dur in events:
-                opname = meta.get(mid, "?")
-                per_op[opname] += dur
-                per_op_count[opname] += 1
-                per_cat[cat.get(mid) or classify(opname)] += dur
-    total = sum(per_op.values())
-    if not total:
-        print("no device events found")
-        return
-    print(f"planes: {planes_used}")
-    print(f"total device time: {total/1e9:.3f} ms (all events)\n")
-    print("== by hlo_category ==")
-    for g, ps in per_cat.most_common():
-        print(f"  {g:22s} {ps/1e9:9.3f} ms  {100.0*ps/total:5.1f}%")
-    print(f"\n== top {topn} ops ==")
-    for opname, ps in per_op.most_common(topn):
-        print(f"  {ps/1e9:9.3f} ms  {100.0*ps/total:5.1f}%  "
-              f"x{per_op_count[opname]:<4d} {opname[:90]}")
+        tf_op = [k for k, v in plane.stat_metadata.items()
+                 if v.name == "tf_op"]
+        for em in plane.event_metadata.values():
+            out[em.name] = next((st.str_value for st in em.stats
+                                 if st.metadata_id in tf_op), "")
+    return out
+
+
+def split(tf_op, depth):
+    """``jit(step)/transpose(jvp(mx.fwd))/encoder/layer7/attention/dot:``
+    -> (``mx.fwd/encoder/layer7``, ``bwd``).  What carries no ``mx.``
+    scope goes under ``(outside)``."""
+    parts = tf_op.rstrip(":").split("/")[1:-1]      # drop jit(...) and the op
+    for i, p in enumerate(parts):
+        m = PHASE.fullmatch(p)
+        if m:
+            return "/".join([m.group(2)] + parts[i + 1:i + 1 + depth]), \
+                "bwd" if m.group(1) else "fwd"
+    return "(outside)", "fwd"
+
+
+def table(title, rows, total, top):
+    print(f"\n== {title} (rows of 0.05 % of busy time and more) ==")
+    for key, cols in sorted(rows.items(), key=lambda kv: -sum(kv[1]))[:top]:
+        if sum(cols) < 5e-4 * total:
+            break
+        ms = "".join(f"{c / 1e6:10.2f}" for c in cols)
+        print(f"{ms} ms {100.0 * sum(cols) / total:6.2f} %  {key}")
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("trace")
+    ap.add_argument("--depth", type=int, default=2)
+    ap.add_argument("--top", type=int, default=15)
+    args = ap.parse_args(argv)
+    path = args.trace
+    if os.path.isdir(path):
+        path = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                                recursive=True))[-1]
+    from jax.profiler import ProfileData
+    planes = list(ProfileData.from_file(path).planes)
+    host = [(e.name, e.start_ns, e.duration_ns) for p in planes
+            if p.name == HOST_PLANE for ln in p.lines for e in ln.events
+            if e.name.startswith(("train.", WINDOW))]
+    ops = [(e.name, e.start_ns, e.duration_ns) for p in planes
+           if DEVICE_PLANE.match(p.name) for ln in p.lines
+           if ln.name == OPS_LINE for e in ln.events]
+    if not ops:
+        print("no device plane with an XLA Ops line in this trace")
+        return 1
+    window = [h for h in host if h[0] == WINDOW]
+    lo, hi = (window[0][1], window[0][1] + window[0][2]) if window else \
+        (min(o[1] for o in ops), max(o[1] + o[2] for o in ops))
+    ops = clip(ops, lo, hi)
+    busy = sum(e - s for s, e in merge((s, s + d) for _, s, d in ops))
+    scope = scopes_of(path)
+    by_scope = collections.defaultdict(lambda: [0, 0])
+    by_phase = collections.defaultdict(lambda: [0, 0])
+    by_kernel, by_op = collections.Counter(), collections.Counter()
+    calls = collections.Counter()
+    for text, ns in self_times(ops):
+        key, side = split(scope.get(text, ""), args.depth) \
+            if scope is not None else (NO_SCHEMA, "fwd")
+        by_scope[key][side == "bwd"] += ns
+        by_phase[key.split("/")[0]][side == "bwd"] += ns
+        k = KERNEL.match(text)
+        if k:
+            by_kernel[k.group(1)] += ns
+            calls[k.group(1)] += 1
+        by_op[(text.split(" = ")[0].lstrip("%"),
+               (scope or {}).get(text, "").rstrip(":"))] += ns
+    print(f"{path}\nwindow {(hi - lo) / 1e6:.3f} ms, busy {busy / 1e6:.3f} ms"
+          f" ({100.0 * busy / (hi - lo):.2f} %), {len(ops)} instructions run")
+    named = sum(sum(v) for k, v in by_phase.items() if k.startswith("mx."))
+    print(f"under an mx. scope: {100.0 * named / busy:.2f} % of busy time")
+    table("by phase: forward ms, backward ms", by_phase, busy, 99)
+    table(f"by scope, depth {args.depth}: forward ms, backward ms", by_scope,
+          busy, args.top * 4)
+    table("by Pallas kernel", {f"{k} x{calls[k]}": [v] for k, v in
+                               by_kernel.items()}, busy, 99)
+    table("longest instructions", {f"{n}  [{s}]": [v] for (n, s), v in
+                                   by_op.items()}, busy, args.top)
+    spans = collections.defaultdict(list)
+    for name, _, d in clip([h for h in host if h[0] != WINDOW], lo, hi):
+        spans[name].append(d)
+    print("\n== the program's spans on the host plane, inside the window ==")
+    for name, ds in sorted(spans.items()):
+        print(f"{len(ds):6d} x {sum(ds) / len(ds) / 1e3:10.1f} us  {name}")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())
