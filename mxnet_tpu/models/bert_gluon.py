@@ -20,6 +20,8 @@ import math
 from ..gluon import nn
 from .. import numpy as mnp
 from .. import numpy_extension as npx
+from .. import tape
+from ..ops import pallas_kernels as _pk
 
 __all__ = ["BERTSelfAttention", "BERTEncoderCell", "BERTEncoder",
            "BERTModel", "bert_12_768_12", "bert_small"]
@@ -43,6 +45,13 @@ class BERTSelfAttention(nn.HybridBlock):
         H = self._heads
         hd = D // H
         qkv = self.qkv(x)                               # (B, T, 3D)
+        # nothing between the scores and their use but the softmax: the
+        # fused kernels take it where shape and platform allow (the heads
+        # are read out of qkv in place, no (T, T) matrix reaches HBM)
+        plain = mask is None and not (self.dropout is not None
+                                      and tape.is_training())
+        if _pk.self_attention_use_pallas(T, hd, plain):
+            return self.proj(npx.multihead_self_attention(qkv, H))
         qkv = qkv.reshape(B, T, 3, H, hd).transpose(2, 0, 3, 1, 4)
         q, k, v = qkv[0], qkv[1], qkv[2]                # (B, H, T, hd)
         scores = mnp.matmul(q, k.transpose(0, 1, 3, 2)) / math.sqrt(hd)

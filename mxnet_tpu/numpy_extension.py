@@ -17,14 +17,14 @@ from .ops import nn as _nn
 
 __all__ = [
     "relu", "sigmoid", "tanh", "softmax", "log_softmax", "masked_softmax",
-    "masked_log_softmax", "activation", "leaky_relu", "gelu", "elu", "selu",
-    "fully_connected", "dense", "convolution", "conv_transpose", "pooling",
-    "batch_norm", "layer_norm", "rms_norm", "instance_norm", "group_norm",
-    "dropout", "embedding", "one_hot", "pick", "topk", "sequence_mask",
-    "sequence_last", "sequence_reverse", "softmax_cross_entropy",
-    "amp_cast", "amp_multicast", "all_finite", "waitall", "seed",
-    "save", "load", "set_np", "reset_np", "is_np_array", "use_np",
-    "gamma", "erf", "erfinv", "ctc_loss",
+    "masked_log_softmax", "multihead_self_attention", "activation",
+    "leaky_relu", "gelu", "elu", "selu", "fully_connected", "dense",
+    "convolution", "conv_transpose", "pooling", "batch_norm", "layer_norm",
+    "rms_norm", "instance_norm", "group_norm", "dropout", "embedding",
+    "one_hot", "pick", "topk", "sequence_mask", "sequence_last",
+    "sequence_reverse", "softmax_cross_entropy", "amp_cast", "amp_multicast",
+    "all_finite", "waitall", "seed", "save", "load", "set_np", "reset_np",
+    "is_np_array", "use_np", "gamma", "erf", "erfinv", "ctc_loss",
     "gather_nd", "scatter_nd", "batch_dot", "smooth_l1",
     "slice", "slice_axis", "slice_like", "arange_like",
     "broadcast_like", "broadcast_axis",
@@ -47,6 +47,7 @@ softmax = _wrap1(_nn.softmax)
 log_softmax = _wrap1(_nn.log_softmax)
 masked_softmax = _wrap1(_nn.masked_softmax)
 masked_log_softmax = _wrap1(_nn.masked_log_softmax)
+multihead_self_attention = _wrap1(_nn.multihead_self_attention)
 activation = _wrap1(_nn.activation)
 leaky_relu = _wrap1(_nn.leaky_relu)
 gelu = _wrap1(_nn.gelu)

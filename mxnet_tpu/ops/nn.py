@@ -887,3 +887,13 @@ amp_cast = _cached_call(amp_cast)
 convolution_nd = _cached_call(convolution_nd)
 pooling_nd = _cached_call(pooling_nd)
 reflection_pad2d = _cached_call(reflection_pad2d)
+
+
+# ------------------------------------------------------------- attention
+def multihead_self_attention(qkv, heads):
+    """softmax(q·kᵀ/√hd)·v of every head of a fused QKV projection
+    ``(B, T, 3·heads·hd)`` (thirds q | k | v, heads side by side in each)
+    → ``(B, T, heads·hd)``.  One pair of Pallas kernels where they apply,
+    the matmul/softmax/matmul composition elsewhere."""
+    from . import pallas_kernels as _pk
+    return _pk.self_attention_fused(qkv, heads)

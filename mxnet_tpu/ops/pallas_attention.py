@@ -1,8 +1,8 @@
 """Causal flash-attention forward — the decode fast path's prefill op.
 
 ROADMAP item 3 (generative decoding): the paper's "Pallas for fused
-Softmax" promise applied to attention itself.  The kernel is the
-online-softmax (FlashAttention) forward of ``pallas_kernels._attn_kernel``
+Softmax" promise applied to attention itself.  The kernel is an
+online-softmax (FlashAttention) forward, streamed over key blocks,
 with the causal band folded into the streaming loop:
 
 - **row-blocked**: grid ``(B·H, Lq // block_q)`` — one (block_q, D)

@@ -175,66 +175,171 @@ layernorm_fused.defvjp(_ln_fwd, _ln_bwd)
 
 
 # ------------------------------------------------- attention (flash-style)
+#
+# One program holds every row of one batch element for one lane block of
+# heads, so neither pass has anything to stream or to carry from tile to
+# tile: the forward is a row softmax per query tile, the backward makes
+# its own statistics from the recomputed scores.  An (L, L) matrix lives
+# in VMEM a query tile at a time and never in HBM, and the backward's
+# only residuals are q, k and v themselves.
+#
+# Arrays are (G, L, C) with heads side by side on the last axis.  A block
+# is (1, L, W) lanes wide and holds W // head_dim heads: two for the
+# head_dim 64 of a packed (B, T, 3·H·hd) projection, whose q, k and v are
+# then three windows of ONE array (`cols`), one otherwise.  Heads that
+# share a block are told apart by a lane mask on one operand of each
+# product, never by a slice: a 128-deep contraction over 64 zeros costs
+# the MXU what a 64-deep one does, and nothing has to move across lanes.
+#
+# Precision: HBM arrays, scores, statistics and accumulators are float32;
+# the MXU operands are rounded to bfloat16, which is what XLA's DEFAULT
+# precision does to the float32 `dot_general`s of the composition.
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, kv_len,
-                 block_k):
-    """One (block_q, d) query tile vs the full K/V, online softmax —
-    the FlashAttention recurrence; K/V stream through VMEM block_k rows
-    at a time so the (block_q, kv_len) score matrix never materializes
-    in HBM.  Emits the row logsumexp too — the backward's only extra
-    residual (O(L) next to q/k/v)."""
-    q = q_ref[0] * scale
-    block_q, d = q.shape
-    m = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-    acc = jnp.zeros((block_q, d), jnp.float32)
+_ATTN_TILE = 512 * 512       # scores a step of the in-kernel loop (1 MiB
+#                              of f32; 512 x 512 measured fastest, PERF.md)
+_ATTN_MAX_LEN = 1024         # whole-L blocks: the backward's 14 (L, W)
+#                              f32 buffers and 6 score tiles, under 16 MiB
+_NT = (((1,), (1,)), ((), ()))    # a · bᵀ
+_TN = (((0,), (0,)), ((), ()))    # aᵀ · b
+
+
+def _attn_block_q(lq, lk):
+    """Query rows a step: the most 128-row groups that divide lq and keep
+    the score tile within `_ATTN_TILE`."""
+    groups = lq // 128
+    return 128 * max(n for n in range(1, groups + 1)
+                     if groups % n == 0 and (n == 1 or
+                                             128 * n * lk <= _ATTN_TILE))
+
+
+def _head_masks(width, heads):
+    """→ per head a (1, width) lane mask, or [None] for one head."""
+    if heads == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    hd = width // heads
+    return [(lane >= h * hd) & (lane < (h + 1) * hd) for h in range(heads)]
+
+
+def _mxu(x, keep=None, scale=None):
+    """An MXU operand: one head's lanes, scaled, rounded to bfloat16."""
+    if scale is not None:
+        x = x * scale
+    if keep is not None:
+        x = jnp.where(keep, x, 0.0)
+    return x.astype(jnp.bfloat16)
+
+
+def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, heads):
+    """o = softmax(q·kᵀ·scale)·v, a (block_q, Lk) score tile at a time."""
+    lq, width = q_ref.shape[1:]
+    block_q = _attn_block_q(lq, k_ref.shape[1])
+    masks = _head_masks(width, heads)
+    k = _mxu(k_ref[0])
+    v = _mxu(v_ref[0])
 
     def body(i, carry):
-        m, l, acc = carry
-        # pl.ds ref indexing (not lax.dynamic_slice on a value): the form
-        # the Pallas TPU lowering supports for a moving VMEM window
-        k = k_ref[0, pl.ds(i * block_k, block_k), :]
-        v = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jnp.dot(p.astype(v.dtype), v,
-                                   preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        q = q_ref[0, rows, :]
+        out = 0.0
+        for keep in masks:
+            s = jax.lax.dot_general(_mxu(q, keep, scale), k, _NT,
+                                    preferred_element_type=jnp.float32)
+            e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+            o = jnp.dot(e.astype(jnp.bfloat16), v,
+                        preferred_element_type=jnp.float32) \
+                / jnp.sum(e, axis=-1, keepdims=True)
+            out += o if keep is None else jnp.where(keep, o, 0.0)
+        o_ref[0, rows, :] = out.astype(o_ref.dtype)
+        return carry
 
-    m, l, acc = jax.lax.fori_loop(0, kv_len // block_k, body, (m, l, acc))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = (m + jnp.log(l))[:, 0]
+    jax.lax.fori_loop(0, lq // block_q, body, 0)
 
 
-def _attention_pallas(q, k, v, scale, block_q=128, block_k=128):
-    """→ (out, lse): lse is the backward residual; inference drops it
-    (XLA DCEs the unused output)."""
-    B, H, Lq, D = q.shape
-    Lk = k.shape[2]
-    _count("hits", "attention", D)
-    block_q = _fit_block(Lq, block_q)
-    block_k = _fit_block(Lk, block_k)
-    q3 = q.reshape(B * H, Lq, D)
-    k3 = k.reshape(B * H, Lk, D)
-    v3 = v.reshape(B * H, Lk, D)
-    out, lse = pl.pallas_call(
-        functools.partial(_attn_kernel, scale=scale, kv_len=Lk,
-                          block_k=block_k),
-        out_shape=(jax.ShapeDtypeStruct(q3.shape, q.dtype),
-                   jax.ShapeDtypeStruct((B * H, Lq), jnp.float32)),
-        grid=(B * H, Lq // block_q),
-        in_specs=[pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((1, Lk, D), lambda b, i: (b, 0, 0)),
-                  pl.BlockSpec((1, Lk, D), lambda b, i: (b, 0, 0))],
-        out_specs=(pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, block_q), lambda b, i: (b, i))),
+def _attn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, dq_ref, dk_ref, dv_ref, *,
+                     scale, heads):
+    """dq, dk, dv from q, k, v and the cotangent g alone.  Scores are
+    recomputed TRANSPOSED, (Lk, block_q), so that the softmax statistics
+    and Δ = Σₖ p·dp are sums over sublanes (rows of a lane vector, no
+    relayout) and four of the five products need no transpose:
+    sᵀ = k·qᵀ, dpᵀ = v·gᵀ, dv += pᵀ·g, dk += dsᵀ·q; dq = (dsᵀ)ᵀ·k is the
+    one the MXU transposes.  Δ from p·dp is the softmax VJP's own
+    Σ g·y, term for term."""
+    lq, width = q_ref.shape[1:]
+    block_q = _attn_block_q(lq, k_ref.shape[1])
+    masks = _head_masks(width, heads)
+    k_all = k_ref[0]
+    k = _mxu(k_all)
+    v = _mxu(v_ref[0])
+    k_heads = [_mxu(k_all, keep, scale) for keep in masks]
+    dk_ref[0] = jnp.zeros(dk_ref.shape[1:], dk_ref.dtype)
+    dv_ref[0] = jnp.zeros(dv_ref.shape[1:], dv_ref.dtype)
+
+    def body(i, carry):
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        q_all = q_ref[0, rows, :]
+        g_all = g_ref[0, rows, :].astype(jnp.float32)
+        dq = dk = dv = 0.0
+        for keep, k_h in zip(masks, k_heads):
+            q = _mxu(q_all, keep, scale)
+            g = _mxu(g_all, keep)
+            s = jax.lax.dot_general(k, q, _NT,
+                                    preferred_element_type=jnp.float32)
+            e = jnp.exp(s - jnp.max(s, axis=0, keepdims=True))
+            p = e * (1.0 / jnp.sum(e, axis=0, keepdims=True))
+            dp = jax.lax.dot_general(v, g, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - jnp.sum(p * dp, axis=0, keepdims=True))
+            p = p.astype(jnp.bfloat16)
+            ds = ds.astype(jnp.bfloat16)
+            dv += jnp.dot(p, g, preferred_element_type=jnp.float32)
+            dk += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+            dq += jax.lax.dot_general(ds, k_h, _TN,
+                                      preferred_element_type=jnp.float32)
+        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
+        dk_ref[0] += dk.astype(dk_ref.dtype)
+        dv_ref[0] += dv.astype(dv_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, lq // block_q, body, 0)
+
+
+def _attn_call(kernel, name, ins, outs_like, scale, *, d, width=None,
+               cols=(0, 0, 0), n_blocks=1):
+    """One kernel over a (G, n_blocks) grid of (1, L, width) blocks of
+    (G, L, C) arrays.  `width` lanes (default: one head) hold heads of
+    head_dim `d`; q, k and v start `cols` blocks into their last axis,
+    every other array at 0; outputs are (G, L, n_blocks · width), shaped
+    after `outs_like`."""
+    width = width or d
+
+    def block(a, first=0):
+        return pl.BlockSpec((1, a.shape[1], width),
+                            lambda g, j: (g, 0, first + j))
+
+    return pl.pallas_call(
+        functools.partial(kernel, scale=scale, heads=width // d),
+        out_shape=[jax.ShapeDtypeStruct(a.shape[:2] + (n_blocks * width,),
+                                        a.dtype) for a in outs_like],
+        grid=(ins[0].shape[0], n_blocks),
+        in_specs=[block(a, c) for a, c in zip(ins, cols + (0,))],
+        out_specs=[block(a) for a in outs_like],
         interpret=_interpret(),
-        name="mx_attn_fwd",
-    )(q3, k3, v3)
-    return out.reshape(B, H, Lq, D), lse.reshape(B, H, Lq)
+        name=name,
+    )(*ins)
+
+
+def _attention_pallas(q, k, v, scale, *, d, **geometry):
+    """Forward → o, (G, Lq, n_blocks · width)."""
+    _count("hits", "attention", d)
+    return _attn_call(_attn_fwd_kernel, "mx_attn_fwd", (q, k, v), (q,),
+                      scale, d=d, **geometry)[0]
+
+
+def _attn_bwd_pallas(q, k, v, g, scale, **geometry):
+    """→ [dq, dk, dv]."""
+    return _attn_call(_attn_bwd_kernel, "mx_attn_bwd", (q, k, v, g),
+                      (q, k, v), scale, **geometry)
 
 
 def _attention_ref(q, k, v, scale):
@@ -247,14 +352,26 @@ def _attention_ref(q, k, v, scale):
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-def _attn_use_pallas(q, k):
-    """ONE forward/backward eligibility predicate — the two passes must
-    always take matching code paths for a given shape."""
-    ok = _use_pallas(q.shape[-1]) and q.shape[-1] % 128 == 0 and \
-        not any(sz % 8 for sz in (q.shape[2], k.shape[2]))
+def _attn_route(lq, lk, d):
+    """Does the kernel take these shapes here?  Asks, counts nothing."""
+    return (_FORCE_INTERPRET or _pb.one_tpu()) and d % 64 == 0 and \
+        lq % 128 == 0 and lk % 128 == 0 and \
+        max(lq, lk) <= _ATTN_MAX_LEN
+
+
+def _attn_use_pallas(lq, lk, d, plain=True):
+    """The routing decision of both entry points.  `plain` is the
+    caller's own condition (no mask, no dropout on the weights); a "no"
+    of either kind counts one fallback, a "yes" is counted where the
+    forward kernel is emitted."""
+    ok = plain and _attn_route(lq, lk, d)
     if not ok:
-        _count("fallbacks", "attention", q.shape[-1])
+        _count("fallbacks", "attention", d)
     return ok
+
+
+def _heads_to_rows(x):
+    return x.reshape((-1,) + x.shape[2:])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -262,23 +379,19 @@ def attention_fused(q, k, v, scale=None):
     """Softmax(QKᵀ·scale)V for (B, H, L, D) tensors — flash-style fused on
     TPU (jnp reference elsewhere). Differentiable: the custom VJP
     recomputes attention weights in the backward (FlashAttention's
-    recompute strategy) so the fused forward never materialises the
-    (L, L) score matrix in HBM."""
+    recompute strategy) so neither pass materialises the (L, L) score
+    matrix in HBM."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    if not _attn_use_pallas(q, k):
+    if not _attn_use_pallas(q.shape[2], k.shape[2], q.shape[3]):
         return _attention_ref(q, k, v, scale)
-    return _attention_pallas(q, k, v, scale)[0]
+    return _attention_pallas(_heads_to_rows(q), _heads_to_rows(k),
+                             _heads_to_rows(v), scale,
+                             d=q.shape[3]).reshape(q.shape)
 
 
 def _attn_fwd(q, k, v, scale):
-    s = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
-    if not _attn_use_pallas(q, k):
-        return _attention_ref(q, k, v, s), (q, k, v, None, None)
-    # save o + lse (O(L·D) + O(L), tiny next to q/k/v): the backward then
-    # needs exactly two streamed passes (dq, dkv) — no o/lse recompute
-    o, lse = _attention_pallas(q, k, v, s)
-    return o, (q, k, v, o, lse)
+    return attention_fused(q, k, v, scale), (q, k, v)
 
 
 def _attn_bwd_ref(s, q, k, v, g):
@@ -293,125 +406,80 @@ def _attn_bwd_ref(s, q, k, v, g):
     return dq, dk, dv
 
 
-# ---- flash-style backward: stream K/V (resp. Q) blocks, never hold the
-# (L, L) score matrix in HBM (FlashAttention backward, recompute from the
-# row statistics lse = m + log l saved by a stats forward pass).
-
-def _attn_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                    dq_ref, *, scale, kv_len, block_k):
-    """dq tile: loop K/V blocks; p = exp(s·scale − lse);
-    ds = p·(g·vᵀ − Δ); dq += ds·k·scale."""
-    q = q_ref[0]
-    g = g_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, None]
-    delta = delta_ref[0][:, None]
-    block_q, d = q.shape
-    acc = jnp.zeros((block_q, d), jnp.float32)
-
-    def body(i, acc):
-        k = k_ref[0, pl.ds(i * block_k, block_k), :]
-        v = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - lse)
-        dp = jnp.dot(g, v.T.astype(jnp.float32),
-                     preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return acc + jnp.dot(ds.astype(k.dtype), k,
-                             preferred_element_type=jnp.float32) * scale
-
-    acc = jax.lax.fori_loop(0, kv_len // block_k, body, acc)
-    dq_ref[0] = acc.astype(dq_ref.dtype)
-
-
-def _attn_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                     dk_ref, dv_ref, *, scale, q_len, block_q):
-    """dk/dv tile: loop Q blocks; pᵀ accumulations."""
-    k = k_ref[0]
-    v = v_ref[0]
-    block_k, d = k.shape
-    dk = jnp.zeros((block_k, d), jnp.float32)
-    dv = jnp.zeros((block_k, d), jnp.float32)
-
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :]
-        g = g_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * block_q, block_q)][:, None]
-        delta = delta_ref[0, pl.ds(i * block_q, block_q)][:, None]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - lse)                          # (bq, bk)
-        dv = dv + jnp.dot(p.T.astype(g.dtype), g,
-                          preferred_element_type=jnp.float32)
-        dp = jnp.dot(g, v.T.astype(jnp.float32),
-                     preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk = dk + jnp.dot(ds.T.astype(q.dtype), q,
-                          preferred_element_type=jnp.float32) * scale
-        return dk, dv
-
-    dk, dv = jax.lax.fori_loop(0, q_len // block_q, body, (dk, dv))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-
-
-def _attn_bwd_pallas(s, q, k, v, g, o, lse, block_q=128, block_k=128):
-    """Two streamed passes (dq tiles; dk/dv tiles) from the saved o/lse
-    residuals — the (L, L) score matrix never exists in HBM."""
-    B, H, Lq, D = q.shape
-    Lk = k.shape[2]
-    block_q = _fit_block(Lq, block_q)
-    block_k = _fit_block(Lk, block_k)
-    q3 = q.reshape(B * H, Lq, D)
-    k3 = k.reshape(B * H, Lk, D)
-    v3 = v.reshape(B * H, Lk, D)
-    g3 = g.reshape(B * H, Lq, D)
-    lse = lse.reshape(B * H, Lq)
-    # Δ = rowsum(g ⊙ o) from the SAVED forward output (O(L·D) residual —
-    # what FlashAttention keeps; only p is ever recomputed)
-    delta = jnp.sum(g3.astype(jnp.float32) *
-                    o.reshape(B * H, Lq, D).astype(jnp.float32), axis=-1)
-    dq = pl.pallas_call(
-        functools.partial(_attn_dq_kernel, scale=s, kv_len=Lk,
-                          block_k=block_k),
-        out_shape=jax.ShapeDtypeStruct(q3.shape, q.dtype),
-        grid=(B * H, Lq // block_q),
-        in_specs=[pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((1, Lk, D), lambda b, i: (b, 0, 0)),
-                  pl.BlockSpec((1, Lk, D), lambda b, i: (b, 0, 0)),
-                  pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
-                  pl.BlockSpec((1, block_q), lambda b, i: (b, i))],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-        interpret=_interpret(),
-        name="mx_attn_dq",
-    )(q3, k3, v3, g3, lse, delta)
-    dk, dv = pl.pallas_call(
-        functools.partial(_attn_dkv_kernel, scale=s, q_len=Lq,
-                          block_q=block_q),
-        out_shape=(jax.ShapeDtypeStruct(k3.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v3.shape, v.dtype)),
-        grid=(B * H, Lk // block_k),
-        in_specs=[pl.BlockSpec((1, Lq, D), lambda b, j: (b, 0, 0)),
-                  pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
-                  pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
-                  pl.BlockSpec((1, Lq, D), lambda b, j: (b, 0, 0)),
-                  pl.BlockSpec((1, Lq), lambda b, j: (b, 0)),
-                  pl.BlockSpec((1, Lq), lambda b, j: (b, 0))],
-        out_specs=(pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
-                   pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0))),
-        interpret=_interpret(),
-        name="mx_attn_dkv",
-    )(q3, k3, v3, g3, lse, delta)
-    return (dq.reshape(q.shape), dk.reshape(k.shape),
-            dv.reshape(v.shape))
-
-
 def _attn_bwd(scale, res, g):
-    q, k, v, o, lse = res
+    q, k, v = res
     s = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
-    if o is None:                # fwd took the jnp reference path
+    # the forward's question again, uncounted: same shapes, same answer
+    if not _attn_route(q.shape[2], k.shape[2], q.shape[3]):
         return _attn_bwd_ref(s, q, k, v, g)
-    return _attn_bwd_pallas(s, q, k, v, g, o, lse)
+    dq, dk, dv = _attn_bwd_pallas(
+        _heads_to_rows(q), _heads_to_rows(k), _heads_to_rows(v),
+        _heads_to_rows(g), s, d=q.shape[3])
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 attention_fused.defvjp(_attn_fwd, _attn_bwd)
+
+
+# ---- the same kernels over a packed projection: q, k and v of head h are
+# columns [h·hd, (h+1)·hd) of thirds 0, 1, 2 of a (B, T, 3·H·hd) array,
+# the output is (B, T, H·hd) — what a fused QKV Dense writes and what the
+# output Dense reads, so no head is ever transposed in HBM.
+
+def _split_heads(qkv, heads):
+    """(B, T, 3·H·hd) → q, k, v, each (B, H, T, hd)."""
+    b, t, c = qkv.shape
+    x = qkv.reshape(b, t, 3, heads, c // (3 * heads)).transpose(2, 0, 3, 1, 4)
+    return x[0], x[1], x[2]
+
+
+def _packed_width(heads, d):
+    """Lanes of one block: a whole 128-lane row of heads where they tile
+    it, one head otherwise."""
+    return 128 if 128 % d == 0 and (heads * d) % 128 == 0 else d
+
+
+def _packed(qkv, heads):
+    """→ the geometry keywords of the two `pallas_call` wrappers."""
+    d = qkv.shape[2] // (3 * heads)
+    width = _packed_width(heads, d)
+    n = heads * d // width
+    return dict(d=d, width=width, cols=(0, n, 2 * n), n_blocks=n)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _self_attention_pallas(qkv, heads):
+    geo = _packed(qkv, heads)
+    return _attention_pallas(qkv, qkv, qkv, geo["d"] ** -0.5, **geo)
+
+
+def _self_attn_fwd(qkv, heads):
+    return _self_attention_pallas(qkv, heads), qkv
+
+
+def _self_attn_bwd(heads, qkv, g):
+    geo = _packed(qkv, heads)
+    return (jnp.concatenate(_attn_bwd_pallas(
+        qkv, qkv, qkv, g, geo["d"] ** -0.5, **geo), axis=-1),)
+
+
+_self_attention_pallas.defvjp(_self_attn_fwd, _self_attn_bwd)
+
+
+def self_attention_use_pallas(length, head_dim, plain=True):
+    """A block that keeps its own composition for masks and dropout asks
+    here first (a "no" counts the fallback)."""
+    return _attn_use_pallas(length, length, head_dim, plain)
+
+
+def self_attention_fused(qkv, heads):
+    """softmax(q·kᵀ/√hd)·v of every head of a packed (B, T, 3·H·hd)
+    projection → (B, T, H·hd): the fused kernels where they apply, the
+    composition they replace elsewhere."""
+    b, t, c = qkv.shape
+    d = c // (3 * heads)
+    if self_attention_use_pallas(t, d):
+        return _self_attention_pallas(qkv, heads)
+    q, k, v = _split_heads(qkv, heads)
+    return _attention_ref(q, k, v, d ** -0.5) \
+        .transpose(0, 2, 1, 3).reshape(b, t, heads * d)

@@ -83,6 +83,41 @@ def test_causal_flash_forward(one_chip, compiled_kernels):
         a, b, c, 128 ** -0.5), q, q, q)
 
 
+def _no_square(text, length):
+    """No (L, L) buffer of any batch/head arrangement in the program."""
+    assert f"{length},{length}]" not in text, \
+        f"an ({length}, {length}) matrix exists outside the kernels"
+
+
+# the BERT cell's shape; head_dim 128 at the longest length the route takes
+@pytest.mark.parametrize("shape", [(16, 12, 512, 64), (2, 4, 1024, 128)],
+                         ids=["cell-hd64", "hd128"])
+def test_attention_fused_fwd_and_grad(one_chip, compiled_kernels, shape):
+    q = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    assert pallas_kernels._attn_route(shape[2], shape[2], shape[3])
+    text = _compile(pallas_kernels.attention_fused, q, q, q)
+    _no_square(text, shape[2])
+    text = _compile(jax.grad(
+        lambda a, b, c: jnp.sum(pallas_kernels.attention_fused(a, b, c) ** 2),
+        argnums=(0, 1, 2)), q, q, q)
+    assert text.count("tpu_custom_call") >= 2      # forward + backward
+    _no_square(text, shape[2])
+
+
+def test_self_attention_packed_fwd_and_grad(one_chip, compiled_kernels):
+    """What `bert-train-ring` runs twelve times a step: the heads read out
+    of the f32[16,512,2304] projection in place, two a 128-lane block."""
+    qkv = jax.ShapeDtypeStruct((16, 512, 3 * 768), jnp.float32,
+                               sharding=one_chip)
+    text = _compile(lambda a: pallas_kernels.self_attention_fused(a, 12), qkv)
+    _no_square(text, 512)
+    assert "transpose" not in text          # no head moves in HBM
+    text = _compile(jax.grad(lambda a: jnp.sum(
+        pallas_kernels.self_attention_fused(a, 12) ** 2)), qkv)
+    assert text.count("tpu_custom_call") >= 2
+    _no_square(text, 512)
+
+
 def _stage_shape(stage, n=128):
     h, w, c = (int(t) for t in stage.split("x"))
     return (n, h, w, c)

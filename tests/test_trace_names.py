@@ -141,7 +141,7 @@ _X = jnp.ones((1, 8, 8, 8), jnp.float32)
 _W = jnp.ones((3, 3, 8, 8), jnp.float32)
 _C = jnp.ones((8,), jnp.float32)
 _ROWS = jnp.ones((16, 128), jnp.float32)
-_QKV = jnp.ones((1, 2, 16, 128), jnp.float32)
+_QKV = jnp.ones((1, 2, 128, 128), jnp.float32)
 _Q8 = jnp.ones((1, 8, 8, 8), jnp.int8)
 
 KERNEL_SITES = {
@@ -165,12 +165,13 @@ KERNEL_SITES = {
                                                  1e-5),
         ["mx_layernorm_fwd"]),
     "kernels.attention": (
-        lambda: pallas_kernels._attention_pallas(_QKV, _QKV, _QKV, 0.1),
+        lambda: pallas_kernels._attention_pallas(_QKV[0], _QKV[0], _QKV[0],
+                                                 0.1, d=128),
         ["mx_attn_fwd"]),
     "kernels.attention_bwd": (
         lambda: pallas_kernels._attn_bwd_pallas(
-            0.1, _QKV, _QKV, _QKV, _QKV, _QKV, _QKV[..., 0]),
-        ["mx_attn_dq", "mx_attn_dkv"]),
+            _QKV[0], _QKV[0], _QKV[0], _QKV[0], 0.1, d=128),
+        ["mx_attn_bwd"]),
     "attention.causal": (
         lambda: pallas_attention._causal_attention_pallas(_QKV, _QKV, _QKV,
                                                           0.1),
