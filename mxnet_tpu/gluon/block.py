@@ -730,3 +730,36 @@ class HybridSequential(HybridBlock):
 
     def __iter__(self):
         return iter(self._layers)
+
+
+def recompute(fn, *args):
+    """``fn(*args)`` (NDArrays in, one NDArray out) so that, while a
+    *training* program is traced, nothing ``fn`` computes is kept for the
+    backward pass: it runs under ``jax.checkpoint`` and is recomputed from
+    ``args`` and the parameters when its gradient is taken.  A block whose
+    activations would not fit otherwise calls this around its own body (a
+    layer of ``models/nemotron_h.py``); the step builders need no option.
+    Aux state written inside (``Parameter.set_data``) leaves through the
+    checkpoint as an output and is registered outside, so it reaches the
+    step's write-back like BatchNorm's statistics.  Outside a traced
+    training program (eager, inference) it is a plain call."""
+    if not (_trace_ctx.active and tape.is_training()):
+        return fn(*args)
+    ctx, written = _trace_ctx, []
+
+    def pure(*raw):
+        outer = (ctx.aux_out, ctx.aux_params)
+        ctx.aux_out, ctx.aux_params = dict(outer[0]), []
+        try:
+            out = fn(*[NDArray(r) for r in raw])
+            written[:] = [p for p in ctx.aux_params
+                          if ctx.aux_out[id(p)] is not outer[0].get(id(p))]
+            aux = tuple(ctx.aux_out[id(p)] for p in written)
+        finally:
+            ctx.aux_out, ctx.aux_params = outer
+        return out._data, aux
+
+    out, aux = jax.checkpoint(pure)(*[a._data for a in args])
+    for p, v in zip(written, aux):
+        p.set_data(NDArray(v))
+    return NDArray(out)

@@ -127,7 +127,17 @@ class SoftmaxCrossEntropyLoss(Loss):
             if sparse:
                 return -_nn.pick(logp, l, axis=axis)
             return -jnp.sum(logp * l, axis=axis)
-        loss = _call(fn, pred, label)
+        # a head that offered its product ``h @ w.T`` in factors (a language
+        # model's, while a training program is traced) gets the loss taken
+        # in blocks of rows: the whole (T, V) matrix is never computed
+        factors = _nn.claim_product(pred._data) \
+            if sparse and not from_logits and axis in (-1, pred.ndim - 1) \
+            else None
+        if factors is not None:
+            loss = _call(_nn.linear_cross_entropy, NDArray(factors[0]),
+                         NDArray(factors[1]), label)
+        else:
+            loss = _call(fn, pred, label)
         loss = _apply_weight(loss, self._weight, sample_weight)
         return _batch_mean(loss, self._batch_axis)
 

@@ -695,3 +695,46 @@ __all__ += ["Conv3D", "Conv1DTranspose", "MaxPool3D", "AvgPool3D",
             "AvgPool1D", "GlobalMaxPool1D", "GlobalAvgPool1D",
             "GlobalMaxPool3D", "GlobalAvgPool3D", "ReflectionPad2D",
             "SyncBatchNorm", "HybridConcatenate", "Concatenate"]
+
+
+class RMSNorm(HybridBlock):
+    """``gamma * x / sqrt(mean(x**2, axis) + epsilon)`` (no mean taken off,
+    no shift): the norm of pre-norm language models."""
+
+    def __init__(self, axis=-1, epsilon=1e-5, in_channels=0, **kwargs):
+        super().__init__(**kwargs)
+        self._axis = axis
+        self._eps = epsilon
+        self.gamma = Parameter("gamma", shape=(in_channels,),
+                               init=init.One())
+
+    def forward(self, x):
+        if not self.gamma._shape_known():
+            self.gamma.shape = (x.shape[self._axis],)
+        if not self.gamma.is_initialized:
+            self.gamma._finish_deferred_init()
+        return _call(_nn.rms_norm, x, self.gamma.data(), axis=self._axis,
+                     eps=self._eps)
+
+
+class GatedGroupRMSNorm(HybridBlock):
+    """``gamma * groupRMSNorm(x * silu(gate))`` with the RMS over each of
+    ``groups`` equal runs of the last axis (Mamba-2's output norm)."""
+
+    def __init__(self, groups=1, epsilon=1e-5, in_channels=0, **kwargs):
+        super().__init__(**kwargs)
+        self._groups = groups
+        self._eps = epsilon
+        self.gamma = Parameter("gamma", shape=(in_channels,),
+                               init=init.One())
+
+    def forward(self, x, gate):
+        if not self.gamma._shape_known():
+            self.gamma.shape = (x.shape[-1],)
+        if not self.gamma.is_initialized:
+            self.gamma._finish_deferred_init()
+        return _call(_nn.gated_group_rms_norm, x, gate, self.gamma.data(),
+                     groups=self._groups, eps=self._eps)
+
+
+__all__ += ["RMSNorm", "GatedGroupRMSNorm"]

@@ -897,3 +897,200 @@ def multihead_self_attention(qkv, heads):
     the matmul/softmax/matmul composition elsewhere."""
     from . import pallas_kernels as _pk
     return _pk.self_attention_fused(qkv, heads)
+
+
+# ------------------------------------------- state-space, causal GQA, LM loss
+# The mixers of a hybrid Mamba-2 / expert / attention language model
+# (models/nemotron_h.py) as compositions XLA compiles: none holds a (T, T)
+# matrix or a (T, V) float32 matrix twice in HBM.  Each counts the route it
+# took at trace time (``dispatch.ssm.*``, ``dispatch.attention.causal.*``,
+# ``dispatch.loss.*``) so that a later kernel shows as another name.
+def relu2(x):
+    """``relu(x)**2`` (``mlp_hidden_act = "relu2"``)."""
+    return jnp.square(jnp.maximum(x, 0))
+
+
+_ACTIVATIONS["relu2"] = relu2
+
+
+def _count_route(name):
+    from .. import telemetry
+    telemetry.counter_add("dispatch." + name)
+
+
+def gated_group_rms_norm(y, z, gamma, groups, eps=1e-5):
+    """``groupRMSNorm(y * silu(z)) * gamma``: the RMS is taken over each of
+    ``groups`` equal runs of the last axis (Mamba-2's gated norm)."""
+    g = y * jax.nn.silu(z)
+    grouped = g.reshape(*g.shape[:-1], groups, g.shape[-1] // groups)
+    return rms_norm(grouped, 1.0, eps=eps).reshape(g.shape) * gamma
+
+
+def causal_conv1d(x, weight, bias=None):
+    """Causal depthwise convolution over time: ``y[b, t, c] = bias[c] +
+    sum_j weight[c, j] * x[b, t - (K-1) + j, c]``, zeros before the
+    sequence.  ``x`` (B, T, C), ``weight`` (C, K).  K shifted multiplies,
+    which XLA fuses into one pass."""
+    k = weight.shape[1]
+    t = x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    y = sum(xp[:, j:j + t] * weight[:, j] for j in range(k))
+    return y if bias is None else y + bias
+
+
+def ssd_chunked(x, dt, a, b, c, d=None, chunk=128):
+    """Mamba-2's selective state-space layer by chunks (SSD, Dao & Gu 2024):
+    per head ``S_t = exp(dt_t a) S_{t-1} + dt_t x_t b_t^T``, ``y_t = S_t c_t
+    + d x_t``, computed as products inside chunks of ``chunk`` steps plus a
+    recurrence over the chunks' end states.
+
+    ``x`` (B, T, H, P), ``dt`` (B, T, H) positive, ``a`` (H,) negative, ``b``
+    and ``c`` (B, T, G, N) with H / G heads sharing a group, ``d`` (H,).
+    Heads are batch dimensions and time is minor throughout, so the largest
+    temporaries are (B, T/chunk, H, chunk, chunk) and (B, T/chunk, H, P, N):
+    linear in T.  Decays are exponentials of differences of an inclusive
+    cumulative sum taken where the difference is not positive, so none
+    exceeds 1."""
+    _count_route("ssm.xla_chunked")
+    B, T, H, P = x.shape
+    G, N = b.shape[2:]
+    q = chunk if T % chunk == 0 else T
+    nc, r = T // q, H // G
+    f32 = jnp.float32
+    xc = x.reshape(B, nc, q, G, r, P).transpose(0, 1, 3, 4, 2, 5)
+    dtc = dt.astype(f32).reshape(B, nc, q, G, r).transpose(0, 1, 3, 4, 2)
+    bc = b.reshape(B, nc, q, G, N).transpose(0, 1, 3, 2, 4)
+    cc = c.reshape(B, nc, q, G, N).transpose(0, 1, 3, 2, 4)
+    cum = jnp.cumsum(dtc * a.astype(f32).reshape(G, r, 1), axis=-1)
+    # inside a chunk: y[t] = sum_{s<=t} exp(cum_t - cum_s) dt_s (c_t.b_s) x_s
+    cb = jnp.einsum("bcgtn,bcgsn->bcgts", cc, bc, preferred_element_type=f32)
+    tri = jnp.tril(jnp.ones((q, q), bool))
+    seg = jnp.where(tri, cum[..., :, None] - cum[..., None, :], 0.0)
+    decay = jnp.where(tri, jnp.exp(seg), 0.0)            # (B,nc,G,r,t,s)
+    m = (cb[:, :, :, None] * decay * dtc[..., None, :]).astype(x.dtype)
+    y = jnp.einsum("bcgrts,bcgrsp->bcgrtp", m, xc, preferred_element_type=f32)
+    # each chunk's own end state, then the state entering every chunk
+    last = cum[..., -1]                                   # (B,nc,G,r)
+    to_end = (jnp.exp(last[..., None] - cum) * dtc).astype(x.dtype)
+    own = jnp.einsum("bcgrsp,bcgsn->bcgrpn", xc * to_end[..., None], bc,
+                     preferred_element_type=f32)
+    total = jnp.cumsum(last, axis=1)
+    # entering[c] = sum_{j<c} exp(total[c-1] - total[j]) own[j]
+    before = jnp.tril(jnp.ones((nc, nc), bool), -1)[:, :, None, None]
+    span = jnp.where(before, (total - last)[:, :, None] - total[:, None], 0.0)
+    carry = jnp.where(before, jnp.exp(span), 0.0)         # (B,c,j,G,r)
+    entering = jnp.einsum("bcjgr,bjgrpn->bcgrpn", carry, own,
+                          preferred_element_type=f32)
+    y = y + jnp.einsum("bcgtn,bcgrpn->bcgrtp", cc, entering.astype(x.dtype),
+                       preferred_element_type=f32) * jnp.exp(cum)[..., None]
+    y = y.transpose(0, 1, 4, 2, 3, 5).reshape(B, T, H, P).astype(x.dtype)
+    return y if d is None else y + x * d[:, None]
+
+
+def causal_gqa_attention(q, k, v, q_block=512, k_block=1024):
+    """Causal ``softmax(q k^T / sqrt(hd)) v`` with grouped keys and values:
+    ``q`` (B, T, Hq, hd), ``k`` and ``v`` (B, T, Hkv, hd), each key-value
+    head serving Hq / Hkv query heads -> (B, T, Hq, hd).
+
+    A loop over blocks of ``q_block`` query rows, and inside it a loop over
+    blocks of ``k_block`` keys with the running maximum, sum and weighted
+    values of an online softmax; key blocks wholly above the diagonal are
+    skipped (``lax.cond``), so only the causal half is computed.  A query
+    block is recomputed in the backward pass.  Both loops are ``lax.scan``s:
+    one (B, Hq, q_block, k_block) score tile a step forward, one query
+    block's tiles backward, and no (T, T) matrix in HBM.  (Unrolled blocks
+    chained by ``optimization_barrier`` are scheduled all at once by the
+    TPU compiler: compile-only rehearsal, PR 29.)"""
+    _count_route("attention.causal.xla_blocked")
+    B, T, Hq, hd = q.shape
+    G = k.shape[2]
+    r = Hq // G
+    qb = q_block if T % q_block == 0 else T
+    kb = k_block if T % k_block == 0 else T
+    f32, low = jnp.float32, -1e30
+    qs = (q * hd ** -0.5).reshape(B, T // qb, qb, G, r, hd) \
+        .transpose(1, 0, 3, 4, 2, 5)                     # (nq,B,G,r,qb,hd)
+    ks = k.reshape(B, T // kb, kb, G, hd).transpose(1, 0, 3, 2, 4)
+    vs = v.reshape(B, T // kb, kb, G, hd).transpose(1, 0, 3, 2, 4)
+
+    @jax.checkpoint
+    def rows(args):
+        i, q_i = args
+        row = i * qb + jnp.arange(qb)
+
+        def keys(carry, inp):
+            j, k_j, v_j = inp
+
+            def attend(carry):
+                m, l, acc = carry
+                s = jnp.einsum("bgrqd,bgkd->bgrqk", q_i, k_j,
+                               preferred_element_type=f32)
+                s = jnp.where(row[:, None] >= j * kb + jnp.arange(kb)[None],
+                              s, low)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+                p = jnp.exp(s - m_new[..., None])
+                old = jnp.exp(m - m_new)
+                pv = jnp.einsum("bgrqk,bgkd->bgrqd", p.astype(v_j.dtype),
+                                v_j, preferred_element_type=f32)
+                return (m_new, l * old + jnp.sum(p, axis=-1),
+                        acc * old[..., None] + pv)
+            return lax.cond(j * kb <= i * qb + qb - 1, attend,
+                            lambda c: c, carry), None
+
+        init = (jnp.full((B, G, r, qb), low, f32),
+                jnp.zeros((B, G, r, qb), f32),
+                jnp.zeros((B, G, r, qb, hd), f32))
+        (_, l, acc), _ = lax.scan(keys, init,
+                                  (jnp.arange(T // kb), ks, vs))
+        return acc / l[..., None]
+
+    o = lax.map(rows, (jnp.arange(T // qb), qs))          # (nq,B,G,r,qb,hd)
+    return o.transpose(1, 0, 4, 2, 3, 5).reshape(B, T, Hq, hd).astype(q.dtype)
+
+
+def linear_cross_entropy(h, weight, labels, block=1024):
+    """``-log softmax(h @ weight.T)[labels]`` per row of ``h`` without the
+    whole product: rows are taken ``block`` at a time and each block's
+    logits are recomputed in the backward pass, so HBM never holds more
+    than (block, V) of them.  ``h`` (..., D), ``weight`` (V, D), ``labels``
+    (...,) -> (...,) float32."""
+    _count_route("loss.linear_blocked")
+    lead = labels.shape
+    h2 = h.reshape(-1, h.shape[-1])
+    y2 = labels.reshape(-1)
+    n = h2.shape[0]
+    blk = block if n % block == 0 else n
+
+    @jax.checkpoint
+    def rows(args):
+        h_b, y_b = args
+        z = jnp.matmul(h_b, weight.T, preferred_element_type=jnp.float32)
+        m = jnp.max(z, axis=-1)
+        lse = m + jnp.log(jnp.sum(jnp.exp(z - m[:, None]), axis=-1))
+        return lse - jnp.take_along_axis(
+            z, y_b[:, None].astype(jnp.int32), axis=-1)[:, 0]
+
+    out = lax.map(rows, (h2.reshape(n // blk, blk, -1),
+                         y2.reshape(n // blk, blk)))
+    return out.reshape(lead)
+
+
+# A block whose output is a product ``h @ weight.T`` that its consumer may
+# not need whole (a language model's head in training) *offers* the factors
+# while its forward is traced; ``SoftmaxCrossEntropyLoss`` *claims* them when
+# the very array it is given is that product, and takes the loss in blocks.
+# One slot, identity of the traced value, emptied by the claim: an unclaimed
+# offer changes nothing (the product is then computed as written).
+_offered = [None]
+
+
+def offer_product(product, h, weight):
+    _offered[0] = (product, h, weight)
+
+
+def claim_product(product):
+    """``(h, weight)`` if ``product`` is the array last offered, else None."""
+    held, _offered[0] = _offered[0], None
+    if held is not None and held[0] is product:
+        return held[1], held[2]
+    return None

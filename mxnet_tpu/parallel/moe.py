@@ -107,3 +107,112 @@ def moe_ffn(x, params, n_experts: int, axis_name: str = "ep",
         out = out.reshape(E, cap, D)
     y = jnp.einsum("sec,ecd->sd", combine, out.astype(jnp.float32))
     return y.astype(x.dtype), aux_loss.astype(jnp.float32)
+
+
+# --------------------------------------------------- dropless top-k, a share
+# rows of a held expert's slot, in even shares (S * top_k / E): room for the
+# fullest expert of an untrained router that follows four batches (1 742 of
+# 8 192 tokens at 6 of 128, PERF.md section 6), so that the step's time does
+# not follow the routing
+SLOT_SHARES = 6
+
+
+def moe_topk_held(x, router_w, bias, up, down, held, top_k, scaling=1.0,
+                  norm_topk=True, slot_rows=None, act=None):
+    """Routed experts of one expert-parallel share: the layer is *told which
+    experts it holds* (``held = (first, count)``), routes over all of them,
+    and returns the part of the result its own experts give.
+
+    ``x`` (S, D) tokens; ``router_w`` (E, D) and ``bias`` (E,) over all E
+    experts; ``up`` (count, D, F) and ``down`` (count, F, D) of the experts
+    held.  Scores are ``sigmoid(x router_w^T)`` in float32 at the highest
+    matmul precision (a near-tie must not be decided by rounding); the
+    ``top_k`` of score + ``bias`` are chosen; their scores, divided by their
+    sum when ``norm_topk``, times ``scaling`` weigh the experts' outputs
+    ``down_e(act(up_e x))``.  Terms of experts not held are left out: with
+    every share's result added, the whole layer results.
+
+    No token is dropped under any imbalance and no one-hot dispatch tensor
+    is built: the (token, expert) pairs are sorted by expert, and every held
+    expert is given its first ``slot_rows`` rows in one product a projection
+    (the empty rows zero), then ``slot_rows`` more while it has any.  So the
+    work is count x slot_rows rows -- the same whatever the routing -- until
+    an expert is sent more than ``slot_rows`` tokens; each further slot
+    costs that one expert's, and S tokens to one expert are served in
+    ceil(S / slot_rows) slots.  ``slot_rows`` defaults to ``SLOT_SHARES``
+    times an expert's even share S * top_k / E, in whole tiles of 256 rows.
+
+    Returns ``(y, load)``: (S, D) and the tokens routed to each of the E
+    experts (int32), held or not."""
+    from .. import telemetry
+    telemetry.counter_add("dispatch.moe.sorted_slots")
+    act = act or jax.nn.relu
+    S, D = x.shape
+    E = router_w.shape[0]
+    first, count = held
+    if slot_rows is None:
+        slot_rows = -(-SLOT_SHARES * S * top_k // (E * 256)) * 256
+    rows = min(S, slot_rows)
+    with jax.named_scope("moe.route"):
+        s = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), router_w.astype(jnp.float32).T,
+            precision=lax.Precision.HIGHEST))
+        _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)   # (S, k)
+        chosen = jnp.take_along_axis(s, idx, axis=-1)
+        if norm_topk:
+            chosen = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)
+        weight = (chosen * scaling).astype(x.dtype)
+        # compared and summed, not scattered: a scatter's time follows its
+        # collisions, and the step's time must not follow the routing
+        load = (idx.reshape(-1, 1) == jnp.arange(E, dtype=idx.dtype)).sum(
+            axis=0, dtype=jnp.int32)
+    with jax.named_scope("moe.dispatch"):
+        local = idx.reshape(-1) - first
+        local = jnp.where((local >= 0) & (local < count), local, count)
+        order = jnp.argsort(local, stable=True)     # held pairs first
+        token = (order // top_k).astype(jnp.int32)
+        w_sorted = weight.reshape(-1)[order]
+        sizes = lax.dynamic_slice(load, (first,), (count,))
+        starts = jnp.cumsum(sizes) - sizes
+
+    def slot(y, e_up, e_down, size, start, r):
+        """``y`` and what rows [r * rows, (r + 1) * rows) of one expert
+        add: a row past ``size`` is token S, which is none -- it reads
+        zeros and is written nowhere."""
+        with jax.named_scope("moe.dispatch"):
+            j = r * rows + jnp.arange(rows, dtype=jnp.int32)
+            live = j < size
+            pos = jnp.where(live, start + j, 0)
+            tok = jnp.where(live, token[pos], S)
+            w = jnp.where(live, w_sorted[pos], 0)
+
+        def product(e_up, e_down, tok, w):
+            with jax.named_scope("moe.dispatch"):
+                xs = x.at[tok].get(mode="fill", fill_value=0)
+            with jax.named_scope("moe.experts"):
+                h = act(jnp.matmul(xs, e_up))
+                return jnp.matmul(h.astype(x.dtype), e_down) * w[:, None]
+        # recomputed in the backward pass: one slot's rows live at a time
+        out = jax.checkpoint(product)(e_up, e_down, tok, w)
+        with jax.named_scope("moe.combine"):
+            return y.at[tok].add(out.astype(y.dtype), mode="drop")
+
+    def further(e_up, e_down, size, start):
+        """What an expert's rows past its first slot add."""
+        def more(y, r):
+            return lax.cond(r * rows < size, slot, lambda y, *_: y,
+                            y, e_up, e_down, size, start, r), None
+        return lax.scan(more, jnp.zeros_like(x),
+                        jnp.arange(1, -(-S // rows), dtype=jnp.int32))[0]
+
+    def expert(y, held_e):
+        e_up, e_down, size, start = held_e
+        y = slot(y, e_up, e_down, size, start, 0)
+        # the backward pass keeps the expert's weights, not each slot's copy
+        y = lax.cond(rows < size,
+                     lambda y: y + jax.checkpoint(further)(*held_e),
+                     lambda y: y, y)
+        return y, None
+
+    y, _ = lax.scan(expert, jnp.zeros_like(x), (up, down, sizes, starts))
+    return y, load

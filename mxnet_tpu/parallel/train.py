@@ -745,6 +745,34 @@ class TrainerFusedStep:
     def sync(self):
         for n in self._tr_names or ():
             jax.block_until_ready(self._params[n]._data._data)
+        _publish_aux(self)
+
+    def hlo_text(self, x, y):
+        """The compiled step program's HLO text for a batch like ``(x,
+        y)``: every instruction with its ``op_name`` (the ``mx.fwd/<block
+        path>`` scopes), which is how a reader of a device trace turns the
+        ``XLA Ops`` line's instruction names into scopes.  Lowered from
+        shapes, so no buffer is touched; the trace is the step's own (no
+        retrace is counted), the compilation is served from the
+        compilation cache where one is on."""
+        if self._compiled is None:
+            raise RuntimeError("hlo_text() needs a step that has run")
+
+        def like(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+        raw = [a._data if isinstance(a, NDArray) else jnp.asarray(a)
+               for a in (x, y)]
+        if self._mesh is not None:
+            raw = [jax.device_put(a, _batch_sharding(
+                self._mesh, a.ndim, self._batch_axis)) for a in raw]
+        args = (
+            {n: self._params[n]._data._data for n in self._tr_names},
+            {n: self._params[n]._data._data for n in self._fr_names},
+            {n: self._trainer._states[self._tname[n]]
+             for n in self._tr_names},
+            self._ctl, self._lr_dev, *raw)
+        return self._compiled.lower(
+            *jax.tree_util.tree_map(like, args)).compile().as_text()
 
     # ---------------------------------------------------------- checkpoint
     def export_ctl(self):
@@ -772,6 +800,38 @@ class TrainerFusedStep:
             rep = NamedSharding(self._mesh, PartitionSpec())
             ctl = jax.device_put(ctl, rep)
         self._ctl = ctl
+
+
+def _publish_aux(step):
+    """Aux state that a block marked for publication (``Parameter.publish``)
+    becomes telemetry when the step is synced: the arrays are the newest
+    step's outputs, so reading them waits for that step and no other, and a
+    step in flight is never stalled for a counter.  ``("moe.load", held,
+    "load_total")`` adds what came since the last sync to
+    ``moe.tokens_routed`` (all experts) and ``moe.tokens_held`` (the experts
+    held here); ``("moe.load", held, "load")`` sets the gauge
+    ``moe.load_max_over_mean`` (thousandths; the fullest held expert of any
+    layer over its layer's mean, last step)."""
+    import numpy as onp
+    seen = step.__dict__.setdefault("_published", {})
+    worst = None
+    for n in step._fr_names or ():
+        mark = getattr(step._params[n], "publish", None)
+        if not mark or mark[0] != "moe.load":
+            continue
+        (first, count), which = mark[1], mark[2]
+        v = onp.asarray(step._params[n]._data._data).astype("int64")
+        if which == "load_total":
+            d = (v - seen.get(n, 0)) % (1 << 32)     # int32 wraps
+            seen[n] = v
+            _telemetry.counter_add("moe.tokens_routed", int(d.sum()))
+            _telemetry.counter_add("moe.tokens_held",
+                                   int(d[first:first + count].sum()))
+        elif v[first:first + count].sum() > 0:
+            held = v[first:first + count]
+            worst = max(worst or 0.0, float(held.max() / held.mean()))
+    if worst is not None:
+        _telemetry.gauge_set("moe.load_max_over_mean", int(1000 * worst))
 
 
 # --------------------------------------------------------------------- check

@@ -1,0 +1,277 @@
+"""The operators Nemotron-H brought (ops/nn.py, parallel/moe.py, gluon.nn,
+gluon.block.recompute) against plain formulations at small sizes on the
+CPU: the chunked scan against the recurrence, the blocked causal GQA against
+the plain one, the blocked loss, the norms, and the expert shares against
+the uncut layer.  The reference is chipbench/reference/nemotron_h.py."""
+import importlib.util
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu.gluon import Trainer, nn
+from mxnet_tpu.gluon.block import recompute
+from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
+from mxnet_tpu.ops import nn as ops
+from mxnet_tpu.parallel.moe import moe_topk_held
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_spec = importlib.util.spec_from_file_location(
+    "nemotron_ref_ops",
+    os.path.join(REPO, "chipbench", "reference", "nemotron_h.py"))
+ref = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(ref)
+
+
+def _err(a, b):
+    return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+
+
+# ------------------------------------------------------------ the operators
+def _ssm_inputs(t=16, h=4, p=8, g=2, n=8, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return (jax.random.normal(ks[0], (2, t, h, p)),
+            jax.nn.softplus(jax.random.normal(ks[1], (2, t, h))),
+            -jnp.exp(jax.random.normal(ks[2], (h,))),
+            jax.random.normal(ks[3], (2, t, g, n)),
+            jax.random.normal(ks[4], (2, t, g, n)),
+            jax.random.normal(ks[5], (h,)))
+
+
+@pytest.mark.parametrize("chunk", [4, 16, 128])
+def test_chunked_scan_is_the_recurrence(chunk):
+    """Forward and gradients; ``chunk`` 128 does not divide 16: one chunk."""
+    args = _ssm_inputs()
+    w = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+
+    def chunked(*a):
+        return ops.ssd_chunked(*a, chunk=chunk)
+
+    def recurrence(*a):
+        return jax.vmap(ref.ssm_recurrence,
+                        in_axes=(0, 0, None, 0, 0, None))(*a)
+    with jax.default_matmul_precision("highest"):
+        assert _err(chunked(*args), recurrence(*args)) < 1e-5
+        got = jax.grad(lambda *a: (chunked(*a) * w).sum(),
+                       argnums=range(6))(*args)
+        want = jax.grad(lambda *a: (recurrence(*a) * w).sum(),
+                        argnums=range(6))(*args)
+    for a, b in zip(got, want):
+        assert _err(a, b) < 1e-4
+
+
+def test_scan_decays_do_not_overflow_over_a_long_strongly_decaying_chunk():
+    x, dt, a, b, c, d = _ssm_inputs(t=64)
+    y = ops.ssd_chunked(x, dt * 50.0, a * 20.0, b, c, d, chunk=64)
+    assert bool(jnp.isfinite(y).all())
+
+
+def _plain_attention(q, k, v):
+    t, h, g = q.shape[1], q.shape[2], k.shape[2]
+    kk, vv = jnp.repeat(k, h // g, axis=2), jnp.repeat(v, h // g, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / math.sqrt(q.shape[-1])
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vv)
+
+
+@pytest.mark.parametrize("blocks", [(8, 16), (16, 8), (8, 8), (512, 1024)])
+def test_blocked_causal_gqa_is_the_plain_one(blocks):
+    """(512, 1024) divide nothing here: one block each way."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (2, 64, 4, 8))
+    k = jax.random.normal(ks[1], (2, 64, 2, 8))
+    v = jax.random.normal(ks[2], (2, 64, 2, 8))
+    w = jax.random.normal(ks[3], q.shape)
+
+    def blocked(*a):
+        return ops.causal_gqa_attention(*a, q_block=blocks[0],
+                                        k_block=blocks[1])
+    with jax.default_matmul_precision("highest"):
+        assert _err(blocked(q, k, v), _plain_attention(q, k, v)) < 1e-5
+        got = jax.grad(lambda *a: (blocked(*a) * w).sum(),
+                       argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: (_plain_attention(*a) * w).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert _err(a, b) < 1e-5
+
+
+def test_causal_conv1d_and_gated_norm_are_the_references():
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    x = jax.random.normal(ks[0], (2, 16, 12))
+    w, b = jax.random.normal(ks[1], (12, 4)), jax.random.normal(ks[2], (12,))
+    want = jax.vmap(ref.causal_conv1d, in_axes=(0, None, None))(x, w, b)
+    assert _err(ops.causal_conv1d(x, w, b), want) < 1e-6
+    # nothing of a later step reaches an earlier one
+    later = ops.causal_conv1d(x.at[:, 9:].set(0.0), w, b)
+    assert bool((later[:, :9] == ops.causal_conv1d(x, w, b)[:, :9]).all())
+    z, g = jax.random.normal(ks[3], x.shape), jax.random.normal(ks[4], (12,))
+    got = ops.gated_group_rms_norm(x, z, g, groups=3)
+    want = ref.rms_norm((x * ref.silu(z)).reshape(2, 16, 3, 4), 1.0) \
+        .reshape(x.shape) * g
+    assert _err(got, want) < 1e-6
+
+
+@pytest.mark.parametrize("block", [8, 1024])
+def test_linear_cross_entropy_is_log_softmax_of_the_product(block):
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    h = jax.random.normal(ks[0], (2, 16, 12))
+    w = jax.random.normal(ks[1], (40, 12))
+    y = jax.random.randint(ks[2], (2, 16), 0, 40)
+
+    def plain(h, w):
+        logp = jax.nn.log_softmax(h @ w.T, axis=-1)
+        return -jnp.take_along_axis(logp, y[..., None], -1)[..., 0]
+    with jax.default_matmul_precision("highest"):
+        got = ops.linear_cross_entropy(h, w, y, block=block)
+        assert got.shape == (2, 16) and _err(got, plain(h, w)) < 1e-6
+        g = jax.grad(lambda h, w: ops.linear_cross_entropy(
+            h, w, y, block=block).sum(), argnums=(0, 1))(h, w)
+        gp = jax.grad(lambda h, w: plain(h, w).sum(), argnums=(0, 1))(h, w)
+    assert _err(g[0], gp[0]) < 1e-5 and _err(g[1], gp[1]) < 1e-5
+
+
+def test_an_offered_product_is_claimed_once_and_only_by_identity():
+    a, h, w = jnp.ones((2, 3)), jnp.ones((2, 4)), jnp.ones((3, 4))
+    ops.offer_product(a, h, w)
+    assert ops.claim_product(jnp.ones((2, 3))) is None     # another array
+    assert ops.claim_product(a) is None                    # offer is spent
+    ops.offer_product(a, h, w)
+    assert ops.claim_product(a) == (h, w)
+    assert ops.claim_product(a) is None
+
+
+def test_rmsnorm_blocks_and_relu2_activation():
+    x = mx.np.array(onp.random.RandomState(0).randn(2, 5, 8).astype("f4"))
+    norm = nn.RMSNorm(in_channels=8)
+    norm.initialize()
+    want = ref.rms_norm(x._data, 1.0)
+    assert _err(norm(x)._data, want) < 1e-6
+    gated = nn.GatedGroupRMSNorm(groups=2, in_channels=8)
+    gated.initialize()
+    want = ref.rms_norm((x._data * ref.silu(x._data)).reshape(2, 5, 2, 4),
+                        1.0).reshape(2, 5, 8)
+    assert _err(gated(x, x)._data, want) < 1e-6
+    act = nn.Activation("relu2")
+    assert _err(act(x)._data, ref.relu2(x._data)) == 0.0
+
+
+def test_recompute_carries_aux_state_out_of_the_checkpoint():
+    """BatchNorm's running statistics written inside ``recompute`` reach the
+    fused step's write-back, and the gradients are those of a plain call."""
+    class Net(nn.HybridBlock):
+        def __init__(self, re):
+            super().__init__()
+            self.re = re
+            self.fc = nn.Dense(6, in_units=6)
+            self.bn = nn.BatchNorm(in_channels=6)
+            self.out = nn.Dense(3, in_units=6)
+
+        def forward(self, x):
+            body = lambda x: self.bn(self.fc(x))
+            return self.out(recompute(body, x) if self.re else body(x))
+
+    rs = onp.random.RandomState(0)
+    x = mx.np.array(rs.randn(8, 6).astype("f4"))
+    y = mx.np.array(rs.randint(0, 3, (8,)).astype("int32"))
+    seen = []
+    for re in (True, False):
+        mx.seed(5)
+        net = Net(re)
+        net.initialize()
+        net.hybridize()
+        step = Trainer(net.collect_params(), "sgd",
+                       {"learning_rate": 0.1}).fuse_step(
+                           SoftmaxCrossEntropyLoss())
+        losses = [float(step(x, y).asnumpy()) for _ in range(3)]
+        seen.append((losses, net.bn.running_mean.data().asnumpy(),
+                     net.fc.weight.data().asnumpy()))
+    assert onp.abs(seen[0][1]).max() > 0          # the statistics moved
+    for a, b in zip(seen[0], seen[1]):
+        onp.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+# ------------------------------------------------------------ expert shares
+def _expert_layer(e=32, d=16, f=8, s=48, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (s, d)),
+            jax.random.normal(ks[1], (e, d)) * 0.5,
+            jax.random.normal(ks[2], (e, d, f)) * 0.3,
+            jax.random.normal(ks[3], (e, f, d)) * 0.3)
+
+
+def test_the_shares_of_all_16_chips_add_up_to_the_uncut_expert_layer():
+    """32 experts over 16 chips, 2 a chip: the routed parts of every share
+    plus the shared expert, counted once, are the reference's whole layer."""
+    x, rw, up, down = _expert_layer()
+    ks = jax.random.split(jax.random.PRNGKey(7), 2)
+    su = jax.random.normal(ks[0], (12, 16)) * 0.3
+    sd = jax.random.normal(ks[1], (16, 12)) * 0.3
+    cfg = dict(num_experts_per_tok=6, routed_scaling_factor=2.5,
+               norm_topk_prob=True, experts_held=(0, 32))
+    w = {"router_weight": rw, "correction_bias": jnp.zeros(32),
+         "experts_up": up, "experts_down": down,
+         "shared_up.weight": su, "shared_down.weight": sd}
+    with jax.default_matmul_precision("highest"):
+        whole = ref.experts(x, w, cfg)
+        total = ref.relu2(x @ su.T) @ sd.T
+        routed = 0
+        share = jax.jit(lambda up, down, first: moe_topk_held(
+            x, rw, jnp.zeros(32), up, down, (first, 2), 6, 2.5,
+            act=ops.relu2, slot_rows=8))
+        for chip in range(16):
+            first = 2 * chip
+            y, load = share(up[first:first + 2], down[first:first + 2], first)
+            total = total + y
+            routed += int(load[first:first + 2].sum())
+    assert routed == 48 * 6                       # every pair held once
+    assert _err(total, whole) < 1e-5
+
+
+@pytest.mark.parametrize("slot_rows", [16, 20, 48, None])
+def test_every_token_to_one_expert_none_dropped(slot_rows):
+    """All 48 tokens choose experts 5, 6, 7 (the bias decides): a top-1
+    layer with a capacity would drop most; here each held expert serves all
+    48, whatever the rows a round gives it (three rounds of 16, 20 + 20 + 8,
+    one of 48; the default is 256 rows, cut to the 48 tokens there are)."""
+    x, _, up, down = _expert_layer(e=16)
+    bias = jnp.zeros(16).at[5].set(9.0).at[6].set(8.0).at[7].set(7.0)
+    rw = jnp.zeros((16, 16))
+    with jax.default_matmul_precision("highest"):
+        y, load = moe_topk_held(x, rw, bias, up[4:8], down[4:8], (4, 4), 3,
+                                2.5, act=ops.relu2,
+                                slot_rows=slot_rows)
+        want = sum(2.5 / 3 * (ops.relu2(x @ up[e]) @ down[e])
+                   for e in (5, 6, 7))
+    assert load.tolist() == [0] * 5 + [48] * 3 + [0] * 8
+    assert _err(y, want) < 1e-5
+
+
+def test_a_share_that_holds_none_of_the_chosen_experts_adds_nothing():
+    x, rw, up, down = _expert_layer(e=16)
+    bias = jnp.zeros(16).at[0].set(9.0).at[1].set(8.0).at[2].set(7.0)
+    y, load = moe_topk_held(x, jnp.zeros((16, 16)), bias, up[8:12],
+                            down[8:12], (8, 4), 3, 2.5, act=ops.relu2)
+    assert float(jnp.abs(y).max()) == 0.0 and int(load.sum()) == 48 * 3
+
+
+def test_the_work_of_the_routed_experts_does_not_follow_the_routing():
+    """Every held expert serves one slot of slot_rows rows whatever the
+    routing, while none is sent more than slot_rows tokens: a dense product
+    of that shape and no grouped one in the compiled program, for an even
+    and an uneven router alike, and the loads are counted exactly."""
+    x, rw, up, down = _expert_layer(e=16)
+    fn = jax.jit(lambda rw, bias: moe_topk_held(
+        x, rw, bias, up[4:8], down[4:8], (4, 4), 3, 2.5, act=ops.relu2,
+        slot_rows=32))
+    even = fn(rw, jnp.zeros(16))
+    uneven = fn(rw, jnp.zeros(16).at[5].set(0.3))
+    assert int(uneven[1][5]) > int(even[1][5]) and int(uneven[1].max()) <= 32
+    for y, load in (even, uneven):
+        assert int(load.sum()) == 48 * 3
+    hlo = fn.lower(rw, jnp.zeros(16)).compile().as_text()
+    assert "ragged" not in hlo and "f32[32,16]" in hlo
