@@ -1000,10 +1000,20 @@ def causal_gqa_attention(q, k, v, q_block=512, k_block=1024):
     one (B, Hq, q_block, k_block) score tile a step forward, one query
     block's tiles backward, and no (T, T) matrix in HBM.  (Unrolled blocks
     chained by ``optimization_barrier`` are scheduled all at once by the
-    TPU compiler: compile-only rehearsal, PR 29.)"""
-    _count_route("attention.causal.xla_blocked")
+    TPU compiler: compile-only rehearsal, PR 29.)
+
+    Where `pallas_kernels.causal_attention_use_pallas` says so (one TPU,
+    head_dim in 128s, whole groups, T in 128s, a K/V head of at most
+    8192 x 128) the same quantity is one forward and one backward Pallas
+    kernel that read the heads in place and keep every tile in VMEM."""
+    from . import pallas_kernels as _pk
     B, T, Hq, hd = q.shape
     G = k.shape[2]
+    if _pk.causal_attention_use_pallas(T, Hq, G, hd):
+        return _pk.causal_gqa_attention_fused(
+            q.reshape(B, T, Hq * hd), k.reshape(B, T, G * hd),
+            v.reshape(B, T, G * hd), Hq, G).reshape(q.shape)
+    _count_route("attention.causal.xla_blocked")
     r = Hq // G
     qb = q_block if T % q_block == 0 else T
     kb = k_block if T % k_block == 0 else T

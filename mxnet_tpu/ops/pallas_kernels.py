@@ -483,3 +483,260 @@ def self_attention_fused(qkv, heads):
     q, k, v = _split_heads(qkv, heads)
     return _attention_ref(q, k, v, d ** -0.5) \
         .transpose(0, 2, 1, 3).reshape(b, t, heads * d)
+
+
+# ----------------------------------- causal grouped-query attention (trainable)
+#
+# softmax(q·kᵀ/√hd + causal mask)·v where Hq / Hkv query heads share a
+# key-value head, for a sequence too long to hold a row of scores: the
+# online softmax over key blocks at or below the diagonal forward, a saved
+# row log-sum-exp, and ONE backward kernel that recomputes the scores tile
+# by tile.  No (block_q, block_k) tile of scores, probabilities or masks
+# reaches HBM in either pass.
+#
+# Arrays stay as the projections wrote them: q, o and the cotangent
+# (B, T, Hq·hd), k and v (B, T, Hkv·hd); a head is a column block.  The
+# grid is (B, Hkv, Hq/Hkv, T/block_q): through all the inner steps of one
+# (batch, key-value head) the K and V blocks keep their index — one whole
+# head each, fetched once, rounded to bfloat16 into scratch once — and the
+# key blocks above the diagonal are never visited (a dynamic trip count).
+# The backward's dk and dv blocks keep their index over the same steps and
+# accumulate the group's query heads and query blocks in VMEM.
+#
+# Precision as above: HBM arrays, scores, statistics and accumulators
+# float32, MXU operands rounded to bfloat16, q scaled in float32 before it
+# is rounded — what the two-scan composition in `ops/nn.py` computes at
+# XLA's DEFAULT precision.
+
+_CAUSAL_BLOCK = 512          # query rows a grid step, keys a loop step
+_CAUSAL_MAX_HEAD = 8192 * 128    # T·hd of a K or V head whole in VMEM: the
+#                              backward holds 8 of them in float32 (k, v, dk,
+#                              dv, double-buffered) and 2 in bfloat16, 36 MiB
+_CAUSAL_LOW = -1e30          # a masked score (the composition's)
+
+
+def _causal_blocks(t):
+    """→ (block_q, block_k): the most 128-row groups up to `_CAUSAL_BLOCK`
+    that divide t."""
+    return (128 * max(n for n in range(1, _CAUSAL_BLOCK // 128 + 1)
+                      if (t // 128) % n == 0),) * 2
+
+
+def _causal_span(first, block_q, block_k):
+    """Key blocks a query block starting at row `first` needs: those below
+    index `full` lie wholly at or below its first row (no mask), those
+    from `full` to `need` cross the diagonal."""
+    return (first + 1) // block_k, (first + block_q + block_k - 1) // block_k
+
+
+def _causal_round_kv(k_ref, v_ref, kb_ref, vb_ref, block_k):
+    """A new key-value head: its MXU operands, rounded once."""
+    def rows(j, carry):
+        r = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        kb_ref[r, :] = k_ref[0, r, :].astype(jnp.bfloat16)
+        vb_ref[r, :] = v_ref[0, r, :].astype(jnp.bfloat16)
+        return carry
+
+    jax.lax.fori_loop(0, k_ref.shape[1] // block_k, rows, 0)
+
+
+def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, kb_ref, vb_ref,
+                       *, scale, block_k):
+    """One query block of one query head: o and the rows' log-sum-exp."""
+    h, i = pl.program_id(2), pl.program_id(3)
+    block_q, d = q_ref.shape[1:]
+
+    @pl.when((h == 0) & (i == 0))
+    def _():
+        _causal_round_kv(k_ref, v_ref, kb_ref, vb_ref, block_k)
+
+    q = _mxu(q_ref[0].astype(jnp.float32), scale=scale)
+    first = i * block_q
+
+    def tile(j, carry, masked):
+        m, l, acc = carry
+        keys = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        s = jax.lax.dot_general(q, kb_ref[keys, :], _NT,
+                                preferred_element_type=jnp.float32)
+        if masked:
+            shape = (block_q, block_k)
+            row = first + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            key = j * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            s = jnp.where(row >= key, s, _CAUSAL_LOW)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        old = jnp.exp(m - m_new)
+        pv = jnp.dot(p.astype(jnp.bfloat16), vb_ref[keys, :],
+                     preferred_element_type=jnp.float32)
+        return (m_new, l * old + jnp.sum(p, axis=-1, keepdims=True),
+                acc * old + pv)
+
+    full, need = _causal_span(first, block_q, block_k)
+    carry = (jnp.full((block_q, 1), _CAUSAL_LOW, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32),
+             jnp.zeros((block_q, d), jnp.float32))
+    carry = jax.lax.fori_loop(0, full, functools.partial(tile, masked=False),
+                              carry)
+    m, l, acc = jax.lax.fori_loop(full, need,
+                                  functools.partial(tile, masked=True), carry)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    # the statistics are a column (block_q, 1); the backward wants a row
+    lse = jnp.broadcast_to(m + jnp.log(l), (block_q, 128))
+    lse_ref[0, 0] = lse.T[:1]
+
+
+def _causal_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, kb_ref, vb_ref,
+                       *, scale, block_k):
+    """One query block of one query head: its dq, and its part of the
+    key-value head's dk and dv.  Scores are recomputed TRANSPOSED,
+    (block_k, block_q), as in `_attn_bwd_kernel`: lse and Δ = Σ g·o are
+    rows that broadcast over sublanes, and of the five products only
+    dq = (dsᵀ)ᵀ·k is one the MXU transposes."""
+    h, i = pl.program_id(2), pl.program_id(3)
+    block_q, d = q_ref.shape[1:]
+
+    @pl.when((h == 0) & (i == 0))
+    def _():
+        _causal_round_kv(k_ref, v_ref, kb_ref, vb_ref, block_k)
+        dk_ref[0] = jnp.zeros(dk_ref.shape[1:], dk_ref.dtype)
+        dv_ref[0] = jnp.zeros(dv_ref.shape[1:], dv_ref.dtype)
+
+    q = _mxu(q_ref[0].astype(jnp.float32), scale=scale)
+    g = _mxu(g_ref[0])
+    lse = lse_ref[0, 0]
+    delta = delta_ref[0, 0]
+    first = i * block_q
+
+    def tile(j, dq, masked):
+        keys = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k = kb_ref[keys, :]
+        s = jax.lax.dot_general(k, q, _NT,
+                                preferred_element_type=jnp.float32)
+        if masked:
+            shape = (block_k, block_q)
+            key = j * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            row = first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            s = jnp.where(row >= key, s, _CAUSAL_LOW)
+        p = jnp.exp(s - lse)
+        dp = jax.lax.dot_general(vb_ref[keys, :], g, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta)).astype(jnp.bfloat16)
+        dv_ref[0, keys, :] += jnp.dot(p.astype(jnp.bfloat16), g,
+                                      preferred_element_type=jnp.float32)
+        dk_ref[0, keys, :] += jnp.dot(ds, q,
+                                      preferred_element_type=jnp.float32)
+        return dq + jax.lax.dot_general(ds, k, _TN,
+                                        preferred_element_type=jnp.float32)
+
+    full, need = _causal_span(first, block_q, block_k)
+    dq = jax.lax.fori_loop(0, full, functools.partial(tile, masked=False),
+                           jnp.zeros((block_q, d), jnp.float32))
+    dq = jax.lax.fori_loop(full, need, functools.partial(tile, masked=True),
+                           dq)
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+
+
+def _causal_call(kernel, name, ins, outs, heads, kv, blocks, vmem):
+    """One kernel over the (B, Hkv, Hq/Hkv, T/block_q) grid.  `ins` and
+    `outs` are (array or shape, kind) pairs: "q" a query head's
+    (block_q, hd) block, "kv" a key-value head whole, "row" a query head's
+    (1, block_q) block of a (B, Hq, 1, T) array of row statistics.
+    `blocks` = (block_q, block_k) is the tests'; None: `_causal_blocks`."""
+    from jax.experimental.pallas import tpu as pltpu
+    b, t, width = ins[0][0].shape
+    d, r = width // heads, heads // kv
+    block_q, block_k = blocks or _causal_blocks(t)
+    specs = {
+        "q": pl.BlockSpec((1, block_q, d),
+                          lambda b, g, h, i: (b, i, g * r + h)),
+        "kv": pl.BlockSpec((1, t, d), lambda b, g, h, i: (b, 0, g)),
+        "row": pl.BlockSpec((1, 1, 1, block_q),
+                            lambda b, g, h, i: (b, g * r + h, 0, i)),
+    }
+    return pl.pallas_call(
+        functools.partial(kernel, scale=d ** -0.5, block_k=block_k),
+        out_shape=[a for a, _ in outs],
+        grid=(b, kv, r, t // block_q),
+        in_specs=[specs[kind] for _, kind in ins],
+        out_specs=[specs[kind] for _, kind in outs],
+        scratch_shapes=[pltpu.VMEM((t, d), jnp.bfloat16)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=vmem),
+        interpret=_interpret(),
+        name=name,
+    )(*(a for a, _ in ins))
+
+
+def _causal_vmem(t, d, whole_f32):
+    """The scoped VMEM a call may use: `whole_f32` double-buffered float32
+    (T, hd) blocks, the two bfloat16 heads, and 24 MiB for the per-step
+    blocks and a tile's temporaries."""
+    return t * d * (8 * whole_f32 + 4) + (24 << 20)
+
+
+def _causal_fwd_pallas(q, k, v, heads, kv, blocks):
+    """→ o (B, T, Hq·hd), lse (B, Hq, 1, T) float32."""
+    b, t, width = q.shape
+    d = width // heads
+    _count("hits", "causal_attention", d)
+    return _causal_call(
+        _causal_fwd_kernel, "mx_causal_attn_fwd",
+        ((q, "q"), (k, "kv"), (v, "kv")),
+        ((jax.ShapeDtypeStruct(q.shape, q.dtype), "q"),
+         (jax.ShapeDtypeStruct((b, heads, 1, t), jnp.float32), "row")),
+        heads, kv, blocks, _causal_vmem(t, d, 2))
+
+
+def _causal_bwd_pallas(q, k, v, o, lse, g, heads, kv, blocks):
+    """→ dq, dk, dv (dk and dv float32: sums over a group's heads)."""
+    b, t, width = q.shape
+    d = width // heads
+    delta = jnp.sum((g.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(b, t, heads, d), axis=-1)
+    delta = delta.transpose(0, 2, 1).reshape(b, heads, 1, t)
+    kv_sum = jax.ShapeDtypeStruct(k.shape, jnp.float32)
+    return _causal_call(
+        _causal_bwd_kernel, "mx_causal_attn_bwd",
+        ((q, "q"), (k, "kv"), (v, "kv"), (g, "q"), (lse, "row"),
+         (delta, "row")),
+        ((jax.ShapeDtypeStruct(q.shape, q.dtype), "q"), (kv_sum, "kv"),
+         (kv_sum, "kv")),
+        heads, kv, blocks, _causal_vmem(t, d, 4))
+
+
+def causal_attention_use_pallas(t, heads, kv, d):
+    """The routing decision of `nn.causal_gqa_attention`: one TPU (or the
+    tests' interpret switch), head_dim in lane tiles, whole groups, a
+    length in 128s whose K/V head VMEM holds whole.  A "no" counts one
+    fallback; the "yes" is counted where the forward kernel is emitted."""
+    ok = (_FORCE_INTERPRET or _pb.one_tpu()) and d % 128 == 0 and \
+        heads % kv == 0 and t % 128 == 0 and t * d <= _CAUSAL_MAX_HEAD
+    if not ok:
+        _count("fallbacks", "causal_attention", d)
+    return ok
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def causal_gqa_attention_fused(q, k, v, heads, kv, blocks=None):
+    """Causal softmax(q·kᵀ/√hd)·v of `heads` query heads over `kv`
+    key-value heads, read in place from the projections: q (B, T, Hq·hd),
+    k and v (B, T, Hkv·hd) → (B, T, Hq·hd).  `blocks` = (block_q,
+    block_k) is the tests' (default: `_causal_blocks`)."""
+    return _causal_fwd_pallas(q, k, v, heads, kv, blocks)[0]
+
+
+def _causal_vjp_fwd(q, k, v, heads, kv, blocks):
+    o, lse = _causal_fwd_pallas(q, k, v, heads, kv, blocks)
+    return o, (q, k, v, o, lse)
+
+
+def _causal_vjp_bwd(heads, kv, blocks, res, g):
+    q, k, v, o, lse = res
+    dq, dk, dv = _causal_bwd_pallas(q, k, v, o, lse, g, heads, kv, blocks)
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+causal_gqa_attention_fused.defvjp(_causal_vjp_fwd, _causal_vjp_bwd)

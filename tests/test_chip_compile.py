@@ -118,6 +118,36 @@ def test_self_attention_packed_fwd_and_grad(one_chip, compiled_kernels):
     _no_square(text, 512)
 
 
+def test_causal_gqa_attention_fwd_and_grad_at_the_cell_shape(one_chip,
+                                                            compiled_kernels):
+    """What `nemotron3-nano-train-8k` runs: 32 query heads over 2
+    key-value heads of 128 at T = 8192, routed from
+    `ops/nn.py::causal_gqa_attention`.  The program holds the named
+    kernels and no score tile: the two-scan composition it replaces kept
+    `f32[8,1,2,16,512,1024]` residuals, eight tiles stacked."""
+    from mxnet_tpu.ops import nn
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.float32,
+                              sharding=one_chip)
+    assert pallas_kernels.causal_attention_use_pallas(8192, 32, 2, 128)
+
+    def no_tile(text):
+        assert "512,1024]" not in text and "512,512]" not in text, \
+            "a (q_block, k_block) tile exists outside the kernels"
+        _no_square(text, 8192)
+
+    text = _compile(nn.causal_gqa_attention, q, kv, kv)
+    assert "mx_causal_attn_fwd" in text
+    no_tile(text)
+    text = _compile(jax.grad(
+        lambda a, b, c: jnp.sum(nn.causal_gqa_attention(a, b, c) ** 2),
+        argnums=(0, 1, 2)), q, kv, kv)
+    assert "mx_causal_attn_fwd" in text and "mx_causal_attn_bwd" in text
+    assert text.count("tpu_custom_call") >= 2
+    no_tile(text)
+
+
 def _stage_shape(stage, n=128):
     h, w, c = (int(t) for t in stage.split("x"))
     return (n, h, w, c)

@@ -110,6 +110,8 @@ def test_fused_step_loss_is_the_reference_loss_and_takes_the_blocked_route():
     assert routes == {"dispatch.ssm.xla_chunked": 4,
                       "dispatch.moe.sorted_slots": 4,
                       "dispatch.attention.causal.xla_blocked": 1,
+                      # head_dim 8 is no lane tile: the kernel says no
+                      "dispatch.pallas.fallbacks.causal_attention.8": 1,
                       "dispatch.loss.linear_blocked": 1}
 
 

@@ -18,6 +18,7 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.gluon import Trainer, nn
 from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
 from mxnet_tpu.ndarray import NDArray
+from mxnet_tpu.ops import nn as ops_nn
 from mxnet_tpu.ops import (pallas_attention, pallas_block, pallas_int8,
                            pallas_kernels)
 
@@ -143,6 +144,9 @@ _C = jnp.ones((8,), jnp.float32)
 _ROWS = jnp.ones((16, 128), jnp.float32)
 _QKV = jnp.ones((1, 2, 128, 128), jnp.float32)
 _Q8 = jnp.ones((1, 8, 8, 8), jnp.int8)
+_QC = jnp.ones((1, 128, 2 * 128), jnp.float32)  # (B, T, Hq·hd), two heads
+_KC = jnp.ones((1, 128, 128), jnp.float32)      # their one key-value head
+_LSE = jnp.ones((1, 2, 1, 128), jnp.float32)    # (B, Hq, 1, T) row statistics
 
 KERNEL_SITES = {
     "block.conv3x3": (lambda: pallas_block.conv3x3(_X, _W),
@@ -176,6 +180,14 @@ KERNEL_SITES = {
         lambda: pallas_attention._causal_attention_pallas(_QKV, _QKV, _QKV,
                                                           0.1),
         ["mx_flash_fwd"]),
+    "kernels.causal_attention": (
+        lambda: pallas_kernels._causal_fwd_pallas(_QC, _KC, _KC, 2, 1,
+                                                  (128, 128)),
+        ["mx_causal_attn_fwd"]),
+    "kernels.causal_attention_bwd": (
+        lambda: pallas_kernels._causal_bwd_pallas(
+            _QC, _KC, _KC, _QC, _LSE, _QC, 2, 1, (128, 128)),
+        ["mx_causal_attn_bwd"]),
     "int8.qconv3x3": (
         lambda: pallas_int8.qconv3x3_affine(_Q8, _W.astype(jnp.int8), _C, _C),
         ["mx_qconv3x3"]),
@@ -203,6 +215,10 @@ ROUTED = {
         x, x[0], x[0]).sum(), _ROWS, "fallbacks.layernorm.128"),
     "attention": (lambda q: pallas_kernels.attention_fused(q, q, q).sum(),
                   _QKV, "hits.attention.128"),
+    # (B, T, Hq, hd) through ops/nn.py, which asks the kernel first
+    "causal_attention": (lambda q: ops_nn.causal_gqa_attention(
+        q, q[:, :, :1], q[:, :, :1]).sum(), _QKV.transpose(0, 2, 1, 3),
+        "hits.causal_attention.128"),
 }
 
 
