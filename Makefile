@@ -148,13 +148,13 @@ ckpt-check:
 
 # Fused residual-block regression gate: interpret-mode parity of the
 # Pallas conv+BN+ReLU(+add) pipeline (fwd/dgrad/wgrad/dgamma) on all
-# three ResNet stage shapes, train and frozen BN, dispatch-table flip
-# forcing the other route with the cached executable invalidated, and
-# a fuse_step run with 0 retraces / 0 rebuilds / 1 dispatch per step
-# (see docs/pallas.md).
+# three ResNet stage shapes, train and frozen BN, the routing rule over
+# {stage} x {one TPU, CPU, four devices}, a fuse_step run with 0
+# retraces / 0 rebuilds / 1 dispatch per step, and the lone conv through
+# ops/nn.py (see docs/pallas.md).
 pallas-check:
-	JAX_PLATFORMS=cpu python -c "from mxnet_tpu.ops import pallas_block; \
-		raise SystemExit(pallas_block._selfcheck())"
+	JAX_PLATFORMS=cpu python -m pytest tests/test_pallas_block.py \
+		tests/test_pallas_conv.py -q -p no:cacheprovider
 
 # Data-feed regression gate: build a synthetic .rec, assert the turbo
 # scaled-decode backend is selected when available, pixel parity vs the
@@ -183,8 +183,8 @@ shard-check:
 # the fused residual-block route and hold it within tolerance of the
 # float reference with argmax agreement + live Pallas-stage hit
 # counters, serve it at precision=int8 with ZERO post-warmup retraces,
-# and flip MXNET_SERVE_PRECISION to prove the dispatch fingerprint
-# re-keys BOTH cache paths (see docs/quantization.md).
+# and re-register under MXNET_SERVE_PRECISION=int8 to see the engine
+# rebuild at int8 (see docs/quantization.md).
 int8-check:
 	JAX_PLATFORMS=cpu python -c "from mxnet_tpu import quantization; \
 		raise SystemExit(quantization._selfcheck())"
@@ -254,9 +254,8 @@ obs-check:
 # Autoregressive decode gate (docs/generate.md): continuous-batched
 # decode bit-for-bit vs unbatched greedy, ring wraparound + seek
 # (snapshot/restore) replay parity down to the cache bits, 0 retraces
-# after warmup, join-at-iteration-boundary observed through the
-# DecodeBatcher, and the flash-attention route flip re-keying BOTH
-# program-cache paths (prefill + step) without counting as a retrace.
+# after warmup, and join-at-iteration-boundary observed through the
+# DecodeBatcher.
 decode-check:
 	JAX_PLATFORMS=cpu python -m mxnet_tpu.generate
 

@@ -517,11 +517,7 @@ def generate_mode(rng, iters):
     """Autoregressive decode throughput (docs/generate.md): tokens/s at
     batch 1 and at the saturated top bucket through ONE donated step
     program, with the prefill-vs-decode µs split diffed out of the
-    telemetry histograms per leg.  The flash-attention leg re-runs the
-    batch-1 prefill with ``MXNET_TPU_PALLAS_ATTN=1`` — the fingerprint
-    flip compiles fresh programs — and only on a real TPU: interpret-
-    mode kernel timings are meaningless, so off-chip it is an explicit
-    skip with a reason, never a number."""
+    telemetry histograms per leg."""
     import jax
     from mxnet_tpu import generate as mxgen
     from mxnet_tpu import telemetry as tel
@@ -529,8 +525,8 @@ def generate_mode(rng, iters):
 
     # GPT-small body with a bench-sized vocab (per-token cost is the
     # layer stack, not the embedding table) and 6×128 heads: head dim
-    # 128 + the 512 prompt bucket put the prefill on a stage the
-    # flash-attention table actually routes ("512x128")
+    # 128 + the 512 prompt bucket put the prefill on shapes the causal
+    # attention kernels take on one TPU
     cfg = G.GPTConfig(vocab_size=8192, hidden=768, layers=12, heads=6,
                       intermediate=3072, max_len=1024)
     params = G.init_params(cfg, jax.random.PRNGKey(0))
@@ -567,27 +563,6 @@ def generate_mode(rng, iters):
            "programs": eng.stats()["programs"]}
     out["saturated_tokens_s"] = out["b8"]["tokens_s"]
 
-    if jax.devices()[0].platform != "tpu":
-        out["pallas_attn"] = {
-            "skipped": True,
-            "reason": "needs TPU: flash-attention prefill off-chip is "
-                      "interpret-mode and meaningless"}
-    else:
-        old = os.environ.get("MXNET_TPU_PALLAS_ATTN")
-        try:
-            os.environ["MXNET_TPU_PALLAS_ATTN"] = "1"
-            pal = leg(1)     # fingerprint flip → fresh prefill programs
-        finally:
-            if old is None:
-                os.environ.pop("MXNET_TPU_PALLAS_ATTN", None)
-            else:
-                os.environ["MXNET_TPU_PALLAS_ATTN"] = old
-        base = out["b1"]["prefill_us"]
-        out["pallas_attn"] = {
-            "prefill_us": pal["prefill_us"],
-            "xla_prefill_us": base,
-            "prefill_speedup": (round(base / pal["prefill_us"], 3)
-                                if base and pal["prefill_us"] else None)}
     print(f"[bench] generate: b1 {out['b1']['tokens_s']} tok/s, "
           f"b8 {out['saturated_tokens_s']} tok/s "
           f"(prefill {out['b1']['prefill_us']}us, "
@@ -666,24 +641,6 @@ def run_row(name):
         out = service_bench()
     elif name == "generate":
         out = generate_mode(rng, iters)
-    elif name == "pallas_block":
-        # fused residual-block A/B (ISSUE 8): only a chip measurement is
-        # meaningful — interpret-mode microseconds would commit nonsense
-        # routes, so off-TPU this row is an explicit skip, not a number
-        import jax
-        if jax.devices()[0].platform != "tpu":
-            out = {"skipped": True,
-                   "reason": "needs TPU: fused-block timings off-chip "
-                             "are interpret-mode and meaningless"}
-        else:
-            import jax.numpy as jnp
-            from benchmark.pallas_conv_ab import (SHAPES, ab_block,
-                                                  decisions_from)
-            legs = {}
-            for nm, xshape, cout in SHAPES:
-                legs[nm] = ab_block(nm, xshape, cout, max(iters, 20),
-                                    jnp.bfloat16)
-            out = {**legs, "decisions": decisions_from(legs)}
     else:
         raise SystemExit(f"unknown row {name!r}")
     # attach the row's runtime counters (engine spans, arena bytes, kvstore
@@ -1012,12 +969,8 @@ def main():
          {"JAX_PLATFORMS": "cpu"}),
         # autoregressive decode: tokens/s at batch 1 + the saturated
         # bucket through the donated ring-KV step program, prefill vs
-        # decode µs split; the flash-attention leg skips itself with a
-        # reason off-TPU (docs/generate.md)
+        # decode µs split (docs/generate.md)
         ("generate", [me, "--row", "generate"], 420, None),
-        # fused residual-block A/B per stage shape (skips itself with a
-        # reason off-TPU, so the artifact stays complete on CPU rigs)
-        ("pallas_block", [me, "--row", "pallas_block"], 420, None),
         ("int8", [os.path.join(here, "benchmark", "int8_score.py"),
                   "--iters", "20", "--batch", "128", "--serve"], 420, None),
     ]

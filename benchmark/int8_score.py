@@ -163,7 +163,7 @@ def main():
     import numpy as np
     import mxnet_tpu as mx
     from mxnet_tpu import amp, quantization as q
-    from mxnet_tpu.ops import pallas_int8 as pi8
+    from mxnet_tpu.ops import pallas_block, pallas_int8 as pi8
 
     platform = jax.devices()[0].platform
     auto_quick = platform != "tpu" and not args.quick
@@ -183,8 +183,8 @@ def main():
         args.classes = min(args.classes, 100)
         agreement_n = 64
     if platform == "tpu":
-        pallas_int8_info = {"active": pi8.int8_enabled(),
-                            "table": pi8.table()}
+        pallas_int8_info = {"active": pallas_block.one_tpu(),
+                            "table": pi8._DEFAULT_TABLE}
     else:
         pallas_int8_info = {
             "skipped": True,

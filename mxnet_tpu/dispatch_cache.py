@@ -157,11 +157,11 @@ def np_call_key(jfun, spec, kw):
     frozen arg spec + frozen kwargs.  None when uncacheable (fresh
     lambda target, array-valued kwargs/consts).
 
-    Ops whose lowering reads mutable routing state (the pallas dispatch
-    table — ops/nn.py convolution/residual_block) carry an
-    ``__mx_extra_key__`` callable, installed by ``cached_call``; its
-    result joins the key here too so the np-dispatcher path invalidates
-    on a flag/table flip exactly like the raw-kernel path."""
+    A callable whose lowering reads mutable state (the sharding plan of
+    parallel/train.py's step) carries an ``__mx_extra_key__`` callable,
+    set directly or installed by ``cached_call``; its result joins the
+    key here too so the np-dispatcher path invalidates on an edit
+    exactly like the raw-kernel path."""
     if not _stable_callable(jfun):
         return None
     xk = getattr(jfun, "__mx_extra_key__", None)
@@ -323,9 +323,8 @@ def cached_call(fun, extra_key=None):
     through to the plain call unchanged.
 
     `extra_key`: zero-arg callable whose (hashable) result joins the key
-    — for kernels whose routing reads mutable process state at call time
-    (the pallas-conv env flag), so flipping it cannot serve a stale
-    executable."""
+    — for kernels whose lowering reads mutable process state at call
+    time, so changing it cannot serve a stale executable."""
     if getattr(fun, "__mx_uncacheable__", False):
         return fun
     @functools.wraps(fun)

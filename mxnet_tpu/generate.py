@@ -18,11 +18,9 @@ attention window (ring overwrite), generation beyond ``cfg.max_len``
 is refused (position embeddings end there).
 
 Retrace discipline extends the PR 7 trace-time hook: programs are keyed
-by (kind, bucket, prompt-bucket, dispatch fingerprint, plan
-fingerprint) — the ``pallas_attention.attn_fingerprint()`` rides
-``pallas_block.dispatch_fingerprint()``, so flipping the
-flash-attention route compiles NEW prefill/step programs instead of
-serving stale traces.  A *retrace* is the same key traced twice: after
+by (kind, bucket, prompt-bucket, serve fingerprint, plan fingerprint),
+so a serve-mesh or plan edit compiles NEW prefill/step programs instead
+of serving stale traces.  A *retrace* is the same key traced twice: after
 :meth:`DecodeEngine.warmup` precompiles the ladder, any second trace of
 a warmed key is a shape leak and increments ``decode.retraces`` — gated
 at zero by ``make decode-check``.
@@ -209,8 +207,8 @@ class DecodeEngine:
 
     # ----------------------------------------------------------- plumbing
     def _fp(self) -> tuple:
-        from .ops import pallas_block as _pb
-        return (_pb.dispatch_fingerprint(),
+        from .parallel import sharding as _sharding
+        return (_sharding.serve_fingerprint(),
                 self.plan.fingerprint if self.plan is not None else "")
 
     def _gather(self, pvals):
@@ -234,9 +232,8 @@ class DecodeEngine:
     def _note_trace(self, key):
         """Trace-time side effect inside every decode program.  Unlike
         serve/engine.py's any-trace-after-warm rule, a FIRST trace of a
-        new key after warmup is a sanctioned rebuild (the dispatch
-        fingerprint in the key changed — e.g. a flash-attention table
-        flip); only a SECOND trace of the same key is a shape leak."""
+        new key after warmup is a sanctioned rebuild (the serve or plan
+        fingerprint in the key changed); only a SECOND trace of the same key is a shape leak."""
         with self._mu:
             n = self._trace_counts.get(key, 0) + 1
             self._trace_counts[key] = n
@@ -572,23 +569,6 @@ def _selfcheck(verbose: bool = True) -> int:
                        eng.retraces == 0))
     finally:
         bat.close()
-
-    # --------------------------- flash-attention route flip re-keys both
-    nprog = eng.stats()["programs"]
-    old = os.environ.get("MXNET_TPU_PALLAS_ATTN")
-    try:
-        os.environ["MXNET_TPU_PALLAS_ATTN"] = \
-            "0" if old == "1" else "1"
-        eng.generate(prompts, max_new=2)
-    finally:
-        if old is None:
-            os.environ.pop("MXNET_TPU_PALLAS_ATTN", None)
-        else:
-            os.environ["MXNET_TPU_PALLAS_ATTN"] = old
-    checks.append(("attn route flip re-keys prefill AND step programs",
-                   eng.stats()["programs"] >= nprog + 2))
-    checks.append(("route-flip rebuild is not counted as a retrace",
-                   eng.retraces == 0))
 
     snap_t = telemetry.summary()
     checks.append(("decode telemetry emitted",

@@ -9,6 +9,8 @@ single fused XLA computation.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Optional
 
 import jax.numpy as jnp
@@ -350,12 +352,34 @@ class BatchNorm(HybridBlock):
         return y
 
 
+class _Layerwise(threading.local):
+    on = False
+
+
+_layerwise = _Layerwise()
+
+
+@contextlib.contextmanager
+def _layerwise_forwards():
+    """While entered (this thread), blocks take their layer-by-layer
+    forwards: ``quantization.quantize_net`` hooks each layer's forward
+    to calibrate it, and the fused route would pass them by."""
+    was = _layerwise.on
+    _layerwise.on = True
+    try:
+        yield
+    finally:
+        _layerwise.on = was
+
+
 def fused_block_active() -> bool:
-    """True when the per-stage Pallas dispatch table routes at least one
-    stage to the fused residual-block pipeline (ops/pallas_block.py) —
-    the resnet blocks' cue to take the fused forward.  False (the CPU
+    """True when at least one stage routes to the fused residual-block
+    pipeline on this process (``pallas_block.block_active``) — the
+    resnet blocks' cue to take the fused forward.  False (the CPU
     default) keeps the legacy layer-by-layer path bit-for-bit, which is
     what trace/export (gluon2sym, ONNX, quantization) walk."""
+    if _layerwise.on:
+        return False
     from ...ops import pallas_block
     return pallas_block.block_active()
 
@@ -363,8 +387,8 @@ def fused_block_active() -> bool:
 def fused_conv_bn_relu(conv: "Conv2D", bn: "BatchNorm", x,
                        residual=None, relu: bool = True):
     """Run a Conv2D + BatchNorm (+ residual add) (+ ReLU) segment through
-    the fused ``residual_block`` op — ONE dispatched op (and, where the
-    committed A/B table says Pallas wins, one HBM round trip) instead of
+    the fused ``residual_block`` op — ONE dispatched op (and, on a stage
+    ``pallas_block.decide`` routes, one HBM round trip) instead of
     four.  The layers keep their parameters and running-stat writeback
     exactly as in the unfused path; segments the fused op cannot take
     (non-3×3/s1, grouped, biased, NCHW) fall back to the plain layer

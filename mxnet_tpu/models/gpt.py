@@ -9,12 +9,12 @@ cache donated across steps.
 Attention reuses the ``ops/attention.py`` interleaved selfatt
 projections — the qkv kernel is laid out per-head ``[q|k|v]`` exactly as
 ``_contrib_interleaved_matmul_selfatt_*`` expects — now with the causal
-mask those ops grew for this model.  The prefill pass is routed through
-``ops/pallas_attention.decide_attn``: Pallas online-softmax forward
-where the committed ``LxD`` table measured a win, the interleaved-op
-composition elsewhere.  The routing decision happens at trace time; the
-decode engine folds ``attn_fingerprint()`` into its program-cache keys
-so a table flip re-keys rather than serving a stale trace.
+mask those ops grew for this model.  Where
+``pallas_kernels.causal_attention_use_pallas`` says so (one TPU,
+head_dim in 128s, a prompt length in 128s) the prefill pass takes the
+causal kernel pair through ``ops.nn.causal_gqa_attention``; elsewhere
+the interleaved-op composition.  The decision is made at trace time
+from the shapes alone.
 
 Three entry points:
 - ``apply``: full causal forward → logits (training / reference).
@@ -116,13 +116,9 @@ def _layer_prefill(x, p, heads):
     qkv = _proj(h, p["qkv"])                       # (B, T, 3D) interleaved
     t5 = qkv.reshape(B, T, H, 3, hd)
     k, v = t5[:, :, :, 1], t5[:, :, :, 2]          # (B, T, H, hd)
-    from ..ops import pallas_attention as _pa
-    if _pa.decide_attn((B, H, T, hd), (B, H, T, hd), x.dtype) == "pallas":
-        ctx = _pa._causal_attention_pallas(
-            t5[:, :, :, 0].transpose(0, 2, 1, 3),
-            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-            1.0 / math.sqrt(hd))
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, D)
+    from ..ops import pallas_kernels as _pk
+    if _pk.causal_attention_use_pallas(T, H, H, hd):
+        ctx = _nn.causal_gqa_attention(t5[:, :, :, 0], k, v).reshape(B, T, D)
     else:
         qkv_t = qkv.transpose(1, 0, 2)             # (T, B, 3D)
         scores = _att.interleaved_matmul_selfatt_qk(qkv_t, H, causal=True)

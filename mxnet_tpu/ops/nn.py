@@ -23,27 +23,9 @@ from jax import lax
 # ----------------------------------------------------------------- helpers
 
 
-def _pallas_conv_enabled() -> bool:
-    import os
-    return os.environ.get("MXNET_TPU_PALLAS_CONV", "") == "1"
-
-
-def _pallas_conv():
-    from . import pallas_conv
-    return pallas_conv
-
-
 def _pallas_block():
     from . import pallas_block
     return pallas_block
-
-
-def _pallas_fingerprint():
-    """Hashable digest of the whole per-stage routing decision (flags +
-    A/B table) — the extra_key for every op whose lowering re-reads that
-    mutable state, so a flip/table edit can never serve a stale
-    executable (the old key only hashed the global env flag)."""
-    return _pallas_block().dispatch_fingerprint()
 
 
 def _pair(x, n=2):
@@ -220,16 +202,11 @@ def convolution(x, weight, bias=None, stride=1, pad=0, dilate=1, groups=1,
             and weight.shape[1] % 2 == 1 and max(weight.shape[:2]) >= 5
             and min(x.shape[1], x.shape[2]) >= max(weight.shape[:2])):
         out = _s2d_conv2d(x, weight, pad, _conv_pet(x))
-    elif (_pallas_conv_enabled() or _pallas_block().conv_wins(
-            x.shape, weight.shape, stride, pad, dilate, groups, x.dtype)) \
-            and _pallas_conv().eligible(
-                x.shape, weight.shape, stride, pad, dilate, groups,
-                dtype=x.dtype):
-        # hand-tiled implicit-GEMM path: MXNET_TPU_PALLAS_CONV=1 force-
-        # routes everything eligible (legacy A/B flag); otherwise the
-        # per-stage decision table routes only the stages the committed
-        # A/B measured as wins (ops/pallas_block.py)
-        out = _pallas_conv().conv3x3_s1(x, weight)
+    elif _pallas_block().conv_wins(x.shape, weight.shape, stride, pad,
+                                   dilate, groups, x.dtype):
+        # hand-tiled implicit GEMM on the stages whose forward routes
+        # (ops/pallas_block.py)
+        out = _pallas_block().conv3x3_s1(x, weight)
     else:
         dn = lax.conv_dimension_numbers(x.shape, weight.shape,
                                         ("NHWC", "HWIO", "NHWC"))
@@ -447,9 +424,9 @@ def residual_block(x, weight, gamma, beta, running_mean, running_var,
     won't fuse (see ops/pallas_block.py).
 
     Returns ``(out, new_mean, new_var)`` with the same running-stat EMA
-    contract as ``batch_norm``.  Routing is per-stage: the committed A/B
-    decision table sends each HxWxC stage to the Pallas pipeline only
-    where it measured a win, everything else to the reference
+    contract as ``batch_norm``.  Routing is per-stage
+    (``pallas_block.decide``): a routed HxWxC stage on one TPU takes the
+    Pallas pipeline, everything else the reference
     composition (conv → batch_norm → add → relu), which is numerically
     identical to the unfused layer path.
     """
@@ -790,9 +767,9 @@ def quantized_conv(x, qw, w_scale, bias=None, residual=None, *, in_t,
     """int8 conv (NHWC activation, pre-quantized HWIO int8 weights) with
     the dequant + bias (+ residual) (+ ReLU) epilogue.  3×3/s1/SAME
     single-group convs route through the Pallas int8 implicit-GEMM
-    (ops/pallas_int8.py) per the committed A/B table — the epilogue
+    (ops/pallas_int8.py) where ``decide_int8`` says so — the epilogue
     rides the int32 accumulator in VMEM, one HBM pass.  Everything else
-    (and table/eligibility fallbacks) composes the XLA int8 conv with
+    (and stage/eligibility fallbacks) composes the XLA int8 conv with
     ``preferred_element_type=int32`` and the identical epilogue math.
 
     ``bias`` is the per-channel shift — after BN folding this IS the
@@ -840,9 +817,8 @@ def quantized_conv(x, qw, w_scale, bias=None, residual=None, *, in_t,
 # (dispatch_cache.cached_call): array args are dynamic, everything else
 # keys the jitted kernel.  Tracer inputs (vjp backward, hybridize traces,
 # user jit) pass through untouched, so autograd and deferred compute see
-# the original functions.  `convolution` and `residual_block` key on the
-# full pallas dispatch fingerprint (env flags + per-stage A/B table) —
-# they are the kernels whose routing re-reads mutable state per call.
+# the original functions.  No op needs an extra key: a routing decision
+# reads shapes and `one_tpu()`, which cannot change inside a process.
 # Applied AFTER every definition so internal callers (`dense` →
 # `fully_connected`) trace the plain bodies, and numpy_extension's
 # import-time `_wrap1(...)` captures the cached versions.
@@ -861,13 +837,13 @@ masked_softmax = _cached_call(masked_softmax)
 masked_log_softmax = _cached_call(masked_log_softmax)
 fully_connected = _cached_call(fully_connected)
 dense = _cached_call(dense)
-convolution = _cached_call(convolution, extra_key=_pallas_fingerprint)
-quantized_conv = _cached_call(quantized_conv, extra_key=_pallas_fingerprint)
-quantized_dense = _cached_call(quantized_dense, extra_key=_pallas_fingerprint)
+convolution = _cached_call(convolution)
+quantized_conv = _cached_call(quantized_conv)
+quantized_dense = _cached_call(quantized_dense)
 conv_transpose = _cached_call(conv_transpose)
 pooling = _cached_call(pooling)
 batch_norm = _cached_call(batch_norm)
-residual_block = _cached_call(residual_block, extra_key=_pallas_fingerprint)
+residual_block = _cached_call(residual_block)
 layer_norm = _cached_call(layer_norm)
 rms_norm = _cached_call(rms_norm)
 instance_norm = _cached_call(instance_norm)

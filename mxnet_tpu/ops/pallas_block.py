@@ -1,7 +1,7 @@
 """Fused residual-block Pallas pipeline: conv + BN (+ add) (+ ReLU).
 
-ROADMAP item 2 (VERDICT r05 #2): the lone 3×3/s1 implicit-GEMM win in
-``ops/pallas_conv.py`` covered one conv; the ResNet hot loop spends its
+ROADMAP item 2 (VERDICT r05 #2): the lone 3×3/s1 implicit-GEMM conv
+(``conv3x3_s1`` below) covered one conv; the ResNet hot loop spends its
 HBM bandwidth on the *epilogue* — every conv output made four HBM round
 trips (conv write, BN read+write, add/ReLU read+write) before the next
 layer read it.  This module fuses the whole block tail into the conv
@@ -26,23 +26,22 @@ image's row-block compute.  ``bh`` comes from the per-stage tiling
 table (``_TILES``), which is how dgrad/wgrad stay competitive on the
 stage-2/3 shapes whose whole-image blocks blew the VMEM budget.
 
-Dispatch is a per-stage A/B table (``benchmark/results/
-pallas_block_ab.json``): each ``HxWxC`` stage routes fwd/bwd to Pallas
-only where the committed A/B measured a win — replacing the global
-MXNET_TPU_PALLAS_CONV flag.  ``dispatch_fingerprint()`` folds the
-flags + table into every dispatch-cache key so a flip can never serve
-a stale executable.  Env knobs (docs/env_var.md): MXNET_TPU_PALLAS_BLOCK
-(master), MXNET_TPU_PALLAS_STAGES (per-stage override),
-MXNET_TPU_PALLAS_TABLE (alternate table), MXNET_TPU_PALLAS_INTERPRET.
+Routing is one rule, in code: a call takes these kernels when its
+shapes and dtype pass ``eligible_block``, its ``HxWxC`` stage is one
+``_DEFAULT_TABLE`` routes, and this process drives exactly one TPU
+(``one_tpu()``).  ``decide()`` counts the answer
+(``dispatch.pallas.{hits,fallbacks}.<stage>``); nothing else can change
+it, so no cache keys on it.  ``MXNET_TPU_PALLAS_INTERPRET`` chooses no
+path: it runs the same kernels interpreted on a TPU, to debug a Mosaic
+fault.
 
-Interpret mode (CPU tests, ``make pallas-check``) runs the same kernels
-unmodified.
+Interpret mode (automatic off the TPU: the CPU's tests) runs the same
+kernels unmodified.
 """
 from __future__ import annotations
 
 import collections
 import functools
-import json
 import os
 
 import jax
@@ -50,10 +49,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-__all__ = ["interpret", "enabled", "one_tpu", "stage_key", "table", "decide",
-           "conv_wins", "dispatch_fingerprint", "eligible_block",
-           "conv3x3", "conv3x3_dgrad", "conv3x3_wgrad",
-           "residual_block_fused", "block_active"]
+__all__ = ["interpret", "one_tpu", "stage_key", "decide", "conv_wins",
+           "eligible_block", "conv3x3", "conv3x3_dgrad", "conv3x3_wgrad",
+           "conv3x3_s1", "residual_block_fused", "block_active"]
 
 
 def _tele():
@@ -81,8 +79,7 @@ def interpret() -> bool:
 
 
 # ------------------------------------------------------------ tiling table
-# Per-stage row-block heights, committed from the same A/B sweeps that
-# feed the dispatch table.  ``fwd`` rows ride the forward / dgrad / the
+# Per-stage row-block heights.  ``fwd`` rows ride the forward / dgrad / the
 # train-mode affine pass; ``wgrad`` rows block the cotangent stream of
 # the weight-grad accumulation.  Anything not listed falls back to the
 # largest divisor of H whose patch block fits the budget.
@@ -94,7 +91,7 @@ _TILES = {
 
 # Patch-matrix block budget: (bh·W, 9C) is the VMEM resident the MXU
 # streams from; 2 MiB keeps double-buffered fwd+wgrad under the 12 MiB
-# bound that pallas_conv measured against the 16 MiB scoped-vmem limit.
+# bound the lone-conv kernel measured against the 16 MiB scoped-vmem limit.
 _PATCH_BLOCK_BYTES = 2 * 1024 * 1024
 
 
@@ -113,141 +110,21 @@ def _pick_bh(H, W, C, itemsize, kind="fwd") -> int:
 
 
 # --------------------------------------------------------- dispatch table
-# Default decisions mirror the committed r05 conv A/B (stage1 fwd 15.2×
-# / fwd+bwd 1.15× for Pallas; stages 2/3 lose to the emitter on bwd):
-# route only where measured to win.  Overridden by the committed JSON
-# (re-run benchmark/pallas_conv_ab.py --block on a real chip) and then
-# by the MXNET_TPU_PALLAS_STAGES env.
+# Which stages route, forward and backward: the one constant ``decide``
+# and ``conv_wins`` read.  An A/B of a stage is one entry flipped here
+# and the ResNet cell read, parent against change.
 _DEFAULT_TABLE = {
     "56x56x64": {"fwd": "pallas", "bwd": "pallas"},
     "28x28x128": {"fwd": "xla", "bwd": "xla"},
     "14x14x256": {"fwd": "xla", "bwd": "xla"},
 }
 
-_table_cache = {"path": None, "mtime": None, "table": None}
-
-
-_DEFAULT_TABLE_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))),
-    "benchmark", "results", "pallas_block_ab.json")
-
-
-def _table_path() -> str:
-    return os.environ.get("MXNET_TPU_PALLAS_TABLE", "") or \
-        _DEFAULT_TABLE_PATH
-
-
-def _committed_table() -> dict:
-    """The decision table from the committed A/B JSON (mtime-cached), or
-    the built-in default when the artifact is absent/unreadable."""
-    path = _table_path()
-    try:
-        mtime = os.stat(path).st_mtime_ns
-    except OSError:
-        return dict(_DEFAULT_TABLE)
-    c = _table_cache
-    if c["path"] == path and c["mtime"] == mtime:
-        return c["table"]
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-        tab = {k: {"fwd": str(v.get("fwd", "xla")),
-                   "bwd": str(v.get("bwd", "xla"))}
-               for k, v in doc.get("decisions", {}).items()}
-    except (OSError, ValueError, AttributeError):
-        tab = dict(_DEFAULT_TABLE)
-    c.update(path=path, mtime=mtime, table=tab)
-    return tab
-
-
-def _stage_overrides() -> dict:
-    """MXNET_TPU_PALLAS_STAGES="56x56x64=pallas,28x28x128=fwd,..." —
-    values: pallas (fwd+bwd), fwd (fwd only), xla (neither)."""
-    out = {}
-    for part in os.environ.get("MXNET_TPU_PALLAS_STAGES", "").split(","):
-        if "=" in part:
-            k, v = part.split("=", 1)
-            v = v.strip()
-            if v == "pallas":
-                out[k.strip()] = {"fwd": "pallas", "bwd": "pallas"}
-            elif v == "fwd":
-                out[k.strip()] = {"fwd": "pallas", "bwd": "xla"}
-            elif v == "xla":
-                out[k.strip()] = {"fwd": "xla", "bwd": "xla"}
-    return out
-
-
-def table() -> dict:
-    """Effective per-stage route table: committed JSON ← env overrides."""
-    tab = dict(_committed_table())
-    tab.update(_stage_overrides())
-    return tab
-
-
-def enabled() -> bool:
-    """Master switch.  Default: route per table on one TPU only
-    (:func:`one_tpu`; interpret mode is a correctness tool, not a fast
-    path).  "1" forces routing on any platform (tests / pallas-check);
-    "0" disables outright."""
-    v = os.environ.get("MXNET_TPU_PALLAS_BLOCK", "")
-    if v == "0":
-        return False
-    if v == "1":
-        return True
-    return one_tpu()
-
 
 def block_active() -> bool:
     """True when at least one stage would route to Pallas — the gluon
     layer's cue to take the fused forward at all."""
-    return enabled() and any(e.get("fwd") == "pallas"
-                             for e in table().values())
-
-
-_fp_cache = {"key": None, "fp": None}
-
-
-def dispatch_fingerprint() -> tuple:
-    """Hashable digest of every mutable input to the routing decision.
-    Joined into dispatch-cache keys (cached_call extra_key AND the
-    np-dispatcher key via ``__mx_extra_key__``) so a flag flip or table
-    edit invalidates cached executables instead of serving the old
-    route.  The int8 route (pallas_int8), the causal-attention route
-    (pallas_attention), the serving precision knob, and the serving
-    sharding knobs (parallel.sharding.serve_fingerprint — mesh spec +
-    plan-file content) ride along so a precision, attention, or sharding
-    flip re-keys both cache paths too.
-
-    Runs on EVERY dispatch (extra_key hook), so the digest is memoised
-    on exactly its mutable inputs — the env knobs, the committed table
-    file's mtime, and the (themselves memoised) int8 + attn + serve
-    fingerprints — leaving the steady-state cost at a handful of env
-    reads and a few stats."""
-    from . import pallas_attention   # function-local: it imports us
-    from . import pallas_int8    # function-local: pallas_int8 imports us
-    from ..parallel import sharding as _sharding   # function-local: cycle
-    env = (os.environ.get("MXNET_TPU_PALLAS_CONV", ""),
-           os.environ.get("MXNET_TPU_PALLAS_BLOCK", ""),
-           os.environ.get("MXNET_TPU_PALLAS_INTERPRET", ""),
-           os.environ.get("MXNET_TPU_PALLAS_STAGES", ""),
-           os.environ.get("MXNET_TPU_PALLAS_TABLE", ""))
-    try:
-        mtime = os.stat(_table_path()).st_mtime_ns
-    except OSError:
-        mtime = -1
-    key = (env, mtime, pallas_int8.int8_fingerprint(),
-           pallas_attention.attn_fingerprint(),
-           _sharding.serve_fingerprint())
-    c = _fp_cache
-    if c["key"] == key:
-        return c["fp"]
-    tab = table()
-    fp = ("pallas", env[0], env[1], env[2],
-          tuple(sorted((k, v["fwd"], v["bwd"]) for k, v in tab.items())),
-          key[2], key[3], key[4])
-    c.update(key=key, fp=fp)
-    return fp
+    return one_tpu() and any(e["fwd"] == "pallas"
+                             for e in _DEFAULT_TABLE.values())
 
 
 def eligible_block(x_shape, w_shape, dtype, has_residual=False) -> bool:
@@ -276,6 +153,15 @@ def eligible_block(x_shape, w_shape, dtype, has_residual=False) -> bool:
 Route = collections.namedtuple("Route", "fwd bwd stage")
 
 
+def _routed(x_shape, w_shape, dtype, has_residual=False):
+    """The ``_DEFAULT_TABLE`` entry of a call the kernels can take
+    (``eligible_block``) on a stage whose forward routes, else None."""
+    if not eligible_block(x_shape, w_shape, dtype, has_residual):
+        return None
+    ent = _DEFAULT_TABLE.get(stage_key(*x_shape[1:]))
+    return ent if ent and ent["fwd"] == "pallas" else None
+
+
 def decide(x_shape, w_shape, dtype, has_residual=False) -> Route:
     """Per-stage routing decision for a 3×3/s1 residual block.  Emits
     the ``dispatch.pallas.{hits,fallbacks}.<stage>`` counters — these
@@ -283,26 +169,21 @@ def decide(x_shape, w_shape, dtype, has_residual=False) -> Route:
     fused step re-decides nothing, by design."""
     _, H, W, C = x_shape if len(x_shape) == 4 else (0, 0, 0, 0)
     stage = stage_key(H, W, C)
-    if not enabled():
+    if not one_tpu():
         return Route("xla", "xla", stage)
-    if not eligible_block(x_shape, w_shape, dtype, has_residual):
-        _tele().counter_add(f"dispatch.pallas.fallbacks.{stage}", 1)
-        return Route("xla", "xla", stage)
-    ent = table().get(stage)
-    if not ent or ent.get("fwd") != "pallas":
+    ent = _routed(x_shape, w_shape, dtype, has_residual)
+    if ent is None:
         _tele().counter_add(f"dispatch.pallas.fallbacks.{stage}", 1)
         return Route("xla", "xla", stage)
     _tele().counter_add(f"dispatch.pallas.hits.{stage}", 1)
-    return Route("pallas", ent.get("bwd", "xla"), stage)
+    return Route("pallas", ent["bwd"], stage)
 
 
 def conv_wins(x_shape, w_shape, stride, pad, dilate, groups, dtype) -> bool:
-    """Table-driven routing for the STANDALONE conv path in ops/nn.py:
-    does the committed A/B say Pallas wins this stage's forward?  (The
-    legacy MXNET_TPU_PALLAS_CONV=1 flag force-routes everything eligible
-    and bypasses this.)  Silent — the block counters belong to
-    ``decide``; lone-conv hits are visible in the A/B artifact."""
-    if not enabled():
+    """The same rule for the STANDALONE conv of ops/nn.py: a 3×3,
+    stride-1, SAME, undilated, ungrouped conv on a stage whose forward
+    routes.  Silent — the block counters belong to ``decide``."""
+    if not one_tpu():
         return False
     st = stride if isinstance(stride, (tuple, list)) else (stride, stride)
     pd = pad if isinstance(pad, (tuple, list)) else (pad, pad)
@@ -310,11 +191,7 @@ def conv_wins(x_shape, w_shape, stride, pad, dilate, groups, dtype) -> bool:
     if groups != 1 or tuple(st) != (1, 1) or tuple(pd) != (1, 1) \
             or tuple(dl) != (1, 1):
         return False
-    if not eligible_block(x_shape, w_shape, dtype):
-        return False
-    _, H, W, C = x_shape
-    ent = table().get(stage_key(H, W, C))
-    return bool(ent) and ent.get("fwd") == "pallas"
+    return _routed(x_shape, w_shape, dtype) is not None
 
 
 # ---------------------------------------------------------------- kernels
@@ -479,6 +356,27 @@ def conv3x3_wgrad(x, dy):
     return dw.reshape(3, 3, C, Cout)
 
 
+@jax.custom_vjp
+def conv3x3_s1(x, w):
+    """3×3 stride-1 SAME NHWC convolution (x (N, H, W, C), w HWIO) as
+    the row-blocked implicit GEMM, with its dgrad and wgrad kernels: the
+    lone conv ``ops/nn.py::convolution`` takes where ``conv_wins``."""
+    return conv3x3(x, w)
+
+
+def _conv3x3_s1_fwd(x, w):
+    return conv3x3(x, w), (x, w)
+
+
+def _conv3x3_s1_bwd(saved, dy):
+    x, w = saved
+    return (conv3x3_dgrad(w, dy).astype(x.dtype),
+            conv3x3_wgrad(x, dy).astype(w.dtype))
+
+
+conv3x3_s1.defvjp(_conv3x3_s1_fwd, _conv3x3_s1_bwd)
+
+
 def _conv_affine(x, w, scale, shift, res, relu):
     """Frozen-stats fused block: one kernel, one HBM round trip."""
     N, H, W, C = x.shape
@@ -585,7 +483,7 @@ def _fused_fwd(cfg, x, w, gamma, beta, mean, var, res):
 
 
 def _conv_bwd(cfg, x, w, dz):
-    """dgrad + wgrad, routed per the committed per-stage bwd decision."""
+    """dgrad + wgrad, routed per the stage's bwd entry."""
     if cfg.bwd == "pallas":
         dx = conv3x3_dgrad(w, dz).astype(x.dtype)
         dw = conv3x3_wgrad(x, dz).astype(w.dtype)
@@ -661,188 +559,8 @@ def residual_block_fused(x, w, gamma, beta, mean, var, residual=None, *,
 
     Returns ``(out, batch_mean, batch_var)`` in training mode and
     ``(out, mean, var)`` (the running stats, unchanged) when frozen.
-    ``bwd`` routes dgrad/wgrad per the committed per-stage decision.
+    ``bwd`` routes dgrad/wgrad (the stage's ``_DEFAULT_TABLE`` entry).
     """
     cfg = Cfg(float(eps), bool(frozen), bool(relu),
               residual is not None, str(bwd))
     return _fused(cfg, x, w, gamma, beta, mean, var, residual)
-
-
-# ----------------------------------------------------------------- gate
-def _selfcheck(verbose: bool = True) -> int:
-    """``make pallas-check`` gate (CPU, interpret mode): fused-block
-    fwd/dgrad/wgrad parity on all three stage shapes, per-stage dispatch
-    table honored with cache invalidation on a flip, and a residual
-    block trained via Trainer.fuse_step with Pallas routing on showing
-    0 retraces / 0 rebuilds / 1 dispatch per step."""
-    import time
-
-    import numpy as onp
-
-    os.environ["MXNET_TPU_PALLAS_BLOCK"] = "1"
-    os.environ["MXNET_TPU_PALLAS_STAGES"] = \
-        "56x56x64=pallas,28x28x128=pallas,14x14x256=pallas"
-    from .. import dispatch_cache, telemetry
-    from . import nn as _nn
-
-    checks = []
-    rs = onp.random.RandomState(0)
-    shapes = [(2, 56, 56, 64), (2, 28, 28, 128), (2, 14, 14, 256)]
-
-    def _ref(x, w, gamma, beta, mean, var, res, training):
-        dn = lax.conv_dimension_numbers(x.shape, w.shape,
-                                        ("NHWC", "HWIO", "NHWC"))
-        z = lax.conv_general_dilated(x, w, (1, 1), [(1, 1), (1, 1)],
-                                     dimension_numbers=dn,
-                                     preferred_element_type=jnp.float32
-                                     ).astype(x.dtype)
-        if training:
-            m = jnp.mean(z.astype(jnp.float32), axis=(0, 1, 2))
-            v = jnp.maximum(jnp.mean(
-                jnp.square(z.astype(jnp.float32)), axis=(0, 1, 2)) - m * m,
-                0.0)
-        else:
-            m, v = mean, var
-        y = ((z.astype(jnp.float32) - m) * lax.rsqrt(v + 1e-5)
-             * gamma.astype(jnp.float32) + beta.astype(jnp.float32))
-        if res is not None:
-            y = y + res.astype(jnp.float32)
-        return jnp.maximum(y, 0.0).astype(x.dtype)
-
-    for shape in shapes:
-        N, H, W, C = shape
-        stage = stage_key(H, W, C)
-        x = jnp.asarray(rs.randn(*shape), jnp.float32)
-        w = jnp.asarray(rs.randn(3, 3, C, C) * 0.05, jnp.float32)
-        res = jnp.asarray(rs.randn(N, H, W, C), jnp.float32)
-        gamma = jnp.asarray(rs.rand(C) + 0.5, jnp.float32)
-        beta = jnp.asarray(rs.randn(C) * 0.1, jnp.float32)
-        mean = jnp.zeros(C, jnp.float32)
-        var = jnp.ones(C, jnp.float32)
-
-        t0 = time.perf_counter()
-        out, bm, bv = residual_block_fused(x, w, gamma, beta, mean, var,
-                                           res, frozen=False, bwd="pallas")
-        jax.block_until_ready(out)
-        telemetry.observe("dispatch.pallas.kernel_us",
-                          (time.perf_counter() - t0) * 1e6)
-        ref = _ref(x, w, gamma, beta, mean, var, res, training=True)
-        checks.append((f"fwd parity (train, {stage})",
-                       bool(jnp.allclose(out, ref, atol=1e-3, rtol=1e-3))))
-
-        def loss_p(a, b, g):
-            return jnp.sum(jnp.square(residual_block_fused(
-                a, b, g, beta, mean, var, res,
-                frozen=False, bwd="pallas")[0]))
-
-        def loss_r(a, b, g):
-            return jnp.sum(jnp.square(_ref(a, b, g, beta, mean, var, res,
-                                           training=True)))
-
-        gp = jax.grad(loss_p, argnums=(0, 1, 2))(x, w, gamma)
-        gr = jax.grad(loss_r, argnums=(0, 1, 2))(x, w, gamma)
-        for nm, a, b in zip(("dgrad", "wgrad", "dgamma"), gp, gr):
-            scl = float(jnp.max(jnp.abs(b))) or 1.0
-            checks.append(
-                (f"{nm} parity ({stage})",
-                 bool(jnp.allclose(a, b, atol=2e-2 * scl, rtol=2e-3))))
-
-        outf, _, _ = residual_block_fused(x, w, gamma, beta, mean, var,
-                                          None, frozen=True, relu=False)
-        reff = _ref(x, w, gamma, beta, mean, var, None, training=False)
-        # frozen ref includes the trailing relu; compare pre-relu by
-        # rerunning fused with relu on
-        outf2, _, _ = residual_block_fused(x, w, gamma, beta, mean, var,
-                                           None, frozen=True, relu=True)
-        checks.append((f"frozen fwd parity ({stage})",
-                       bool(jnp.allclose(outf2, reff, atol=1e-3,
-                                         rtol=1e-3))))
-        checks.append((f"frozen relu=False differs ({stage})",
-                       not bool(jnp.allclose(outf, outf2))))
-
-    # -------- dispatch-table flip honored, cache invalidated ----------
-    x = jnp.asarray(rs.randn(1, 14, 14, 256), jnp.float32)
-    w = jnp.asarray(rs.randn(3, 3, 256, 256) * 0.05, jnp.float32)
-    r1 = decide(x.shape, w.shape, x.dtype)
-    fp1 = dispatch_fingerprint()
-    g = jnp.asarray(rs.rand(256), jnp.float32)
-    b = jnp.zeros(256, jnp.float32)
-    m = jnp.zeros(256, jnp.float32)
-    v = jnp.ones(256, jnp.float32)
-    _nn.residual_block(x, w, g, b, m, v)            # populate cache, route 1
-    d0 = dispatch_cache.stats()
-    os.environ["MXNET_TPU_PALLAS_STAGES"] = \
-        "56x56x64=pallas,28x28x128=pallas,14x14x256=xla"
-    r2 = decide(x.shape, w.shape, x.dtype)
-    fp2 = dispatch_fingerprint()
-    _nn.residual_block(x, w, g, b, m, v)            # flipped: must re-key
-    d1 = dispatch_cache.stats()
-    checks.append(("table flip forces the other route",
-                   r1.fwd == "pallas" and r2.fwd == "xla"))
-    checks.append(("flip changes the dispatch fingerprint", fp1 != fp2))
-    checks.append(("flipped route recompiles (no stale executable)",
-                   d1["misses"] > d0["misses"]))
-    os.environ["MXNET_TPU_PALLAS_STAGES"] = \
-        "56x56x64=pallas,28x28x128=pallas,14x14x256=pallas"
-
-    # -------- fuse_step: 0 retraces, 0 rebuilds, 1 dispatch/step ------
-    from ..gluon import Trainer, nn as gnn
-    from ..models.resnet import BasicBlockV1
-
-    class _Head(gnn.HybridBlock):
-        def __init__(self):
-            super().__init__()
-            self.block = BasicBlockV1(64, 1)
-            self.flat = gnn.Flatten()
-            self.out = gnn.Dense(4)
-
-        def forward(self, xx):
-            return self.out(self.flat(self.block(xx)))
-
-    from ..gluon.loss import SoftmaxCrossEntropyLoss
-    from ..ndarray import NDArray
-    net = _Head()
-    net.initialize()
-    net.hybridize()
-    tr = Trainer(net.collect_params(), "sgd", {"learning_rate": 0.01})
-    step = tr.fuse_step(SoftmaxCrossEntropyLoss())
-    xb = NDArray(jnp.asarray(rs.randn(2, 56, 56, 64), jnp.float32))
-    yb = NDArray(jnp.asarray(rs.randint(0, 4, (2,)), jnp.int32))
-    for _ in range(2):
-        step(xb, yb)
-    step.sync()
-    base = telemetry.summary()
-    steps = 4
-    for _ in range(steps):
-        step(xb, yb)
-    step.sync()
-    cur = telemetry.summary()
-
-    def delta(name):
-        return cur.get(name, 0) - base.get(name, 0)
-
-    hits = sum(d for k, d in
-               ((k, cur.get(k, 0) - base.get(k, 0)) for k in cur)
-               if k.startswith("dispatch.pallas.hits."))
-    checks.append(("fuse_step fused path active", bool(step.fused)))
-    checks.append(("fuse_step 0 retraces", delta("fused.retraces") == 0))
-    checks.append(("fuse_step 0 rebuilds", delta("fused.rebuilds") == 0))
-    checks.append(("fuse_step 1 dispatch/step",
-                   delta("fused.dispatches") == steps))
-    checks.append(("steady state makes no new routing decisions",
-                   hits == 0))
-
-    ok = True
-    for name, passed in checks:
-        ok = ok and passed
-        if verbose:
-            print(f"  [{'ok' if passed else 'FAIL'}] {name}")
-    if verbose:
-        print(f"pallas-check: {'PASS' if ok else 'FAIL'} "
-              f"({len(checks)} checks)")
-    return 0 if ok else 1
-
-
-if __name__ == "__main__":
-    import sys
-    sys.exit(_selfcheck())

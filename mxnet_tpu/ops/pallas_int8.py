@@ -25,21 +25,15 @@ the row coordinate so Pallas double-buffers the next image's DMA), and
 preferred_element_type=int32)`` with the identical epilogue math, so
 both routes agree bit-for-bit up to f32 rounding.
 
-Routing mirrors pallas_block: a committed per-stage decision table
-(``benchmark/results/pallas_int8_ab.json``, written by
-``benchmark/pallas_conv_ab.py --int8 --commit-table`` on a real chip)
-behind the ``MXNET_TPU_PALLAS_INT8`` master switch, with the whole
-routing state digested into :func:`int8_fingerprint` — joined into
-``pallas_block.dispatch_fingerprint()`` and from there into every
-dispatch-cache key (cached_call extra_key + ``__mx_extra_key__``), so a
-precision or table flip re-keys both cache paths instead of serving a
-stale executable.
+Routing is pallas_block's rule: a quantized 3×3/s1 conv takes the
+kernel when its shapes pass ``eligible_int8``, its stage is one
+``_DEFAULT_TABLE`` routes, and the process drives exactly one TPU
+(``pallas_block.one_tpu()``); ``decide_int8`` counts the answer
+(``quant.int8.{hits,fallbacks}.<stage>``).
 """
 from __future__ import annotations
 
 import functools
-import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -48,8 +42,8 @@ from jax.experimental import pallas as pl
 
 from . import pallas_block as pb
 
-__all__ = ["int8_enabled", "eligible_int8", "decide_int8", "table",
-           "int8_fingerprint", "qconv3x3_affine", "qconv3x3_xla"]
+__all__ = ["eligible_int8", "decide_int8", "qconv3x3_affine",
+           "qconv3x3_xla"]
 
 
 def _tele():
@@ -57,96 +51,14 @@ def _tele():
     return telemetry
 
 
-# Default decisions pending a chip A/B run (benchmark/pallas_conv_ab.py
-# --int8 --commit-table): int8 patches are ¼ the bf16 bytes and the
-# epilogue rides the int32 accumulator, so every profiled stage is
-# routed until real measurements say otherwise.
+# No chip measurement has chosen between the routes yet (ROADMAP W4):
+# int8 patches are ¼ the bf16 bytes and the epilogue rides the int32
+# accumulator, so every profiled stage is routed.
 _DEFAULT_TABLE = {
     "56x56x64": {"fwd": "pallas"},
     "28x28x128": {"fwd": "pallas"},
     "14x14x256": {"fwd": "pallas"},
 }
-
-_table_cache = {"path": None, "mtime": None, "table": None}
-
-
-_DEFAULT_TABLE_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))),
-    "benchmark", "results", "pallas_int8_ab.json")
-
-
-def _table_path() -> str:
-    return os.environ.get("MXNET_TPU_PALLAS_INT8_TABLE", "") or \
-        _DEFAULT_TABLE_PATH
-
-
-def table() -> dict:
-    """Per-stage int8 route table from the committed A/B JSON
-    (mtime-cached), or the built-in default when absent."""
-    path = _table_path()
-    try:
-        mtime = os.stat(path).st_mtime_ns
-    except OSError:
-        return dict(_DEFAULT_TABLE)
-    c = _table_cache
-    if c["path"] == path and c["mtime"] == mtime:
-        return c["table"]
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-        tab = {k: {"fwd": str(v.get("fwd", "xla"))}
-               for k, v in doc.get("decisions", {}).items()}
-    except (OSError, ValueError, AttributeError):
-        tab = dict(_DEFAULT_TABLE)
-    c.update(path=path, mtime=mtime, table=tab)
-    return tab
-
-
-def int8_enabled() -> bool:
-    """Master switch for the int8 Pallas route.  Default: table-driven
-    on one TPU only (``pallas_block.one_tpu``; interpret mode is a
-    correctness tool, not a fast path);
-    ``MXNET_TPU_PALLAS_INT8=1`` forces routing on any platform (tests /
-    ``make int8-check``); ``0`` disables outright — every quantized conv
-    takes the XLA int8 composition."""
-    v = os.environ.get("MXNET_TPU_PALLAS_INT8", "")
-    if v == "0":
-        return False
-    if v == "1":
-        return True
-    return pb.one_tpu()
-
-
-_fp_cache = {"key": None, "fp": None}
-
-
-def int8_fingerprint() -> tuple:
-    """Hashable digest of the mutable int8 routing state — the
-    MXNET_TPU_PALLAS_INT8 / table knobs plus the serving precision
-    (MXNET_SERVE_PRECISION).  Folded into
-    ``pallas_block.dispatch_fingerprint()`` and therefore into every
-    cached-call extra_key and np-dispatcher ``__mx_extra_key__`` key, so
-    ANY precision flip re-keys both cache paths.
-
-    This runs on EVERY dispatch (it rides the extra_key hook), so the
-    digest is memoised on exactly its mutable inputs — the three env
-    knobs plus the table file's mtime — leaving the steady-state cost
-    at three env reads and one stat."""
-    env = (os.environ.get("MXNET_TPU_PALLAS_INT8", ""),
-           os.environ.get("MXNET_TPU_PALLAS_INT8_TABLE", ""),
-           os.environ.get("MXNET_SERVE_PRECISION", ""))
-    try:
-        mtime = os.stat(_table_path()).st_mtime_ns
-    except OSError:
-        mtime = -1
-    c = _fp_cache
-    if c["key"] == (env, mtime):
-        return c["fp"]
-    fp = ("int8", *env,
-          tuple(sorted((k, v["fwd"]) for k, v in table().items())))
-    c.update(key=(env, mtime), fp=fp)
-    return fp
 
 
 def eligible_int8(x_shape, w_shape, has_residual=False) -> bool:
@@ -181,13 +93,11 @@ def decide_int8(x_shape, w_shape, has_residual=False) -> str:
     stays flat just like ``dispatch.pallas.*``."""
     _, H, W, C = x_shape if len(x_shape) == 4 else (0, 0, 0, 0)
     stage = pb.stage_key(H, W, C)
-    if not int8_enabled():
-        return "xla"            # int8 route off is the normal quiet state
-    if not eligible_int8(x_shape, w_shape, has_residual):
-        _tele().counter_add(f"quant.int8.fallbacks.{stage}", 1)
-        return "xla"
-    ent = table().get(stage)
-    if not ent or ent.get("fwd") != "pallas":
+    if not pb.one_tpu():
+        return "xla"            # off one TPU is the normal quiet state
+    ent = _DEFAULT_TABLE.get(stage)
+    if not eligible_int8(x_shape, w_shape, has_residual) \
+            or not ent or ent["fwd"] != "pallas":
         _tele().counter_add(f"quant.int8.fallbacks.{stage}", 1)
         return "xla"
     _tele().counter_add(f"quant.int8.hits.{stage}", 1)
@@ -250,7 +160,7 @@ def qconv3x3_xla(qx, qw, scale, shift, res=None, relu=True,
     """XLA fallback composition with identical math: int8 conv through
     ``lax.conv_general_dilated(preferred_element_type=int32)`` + the
     same f32 epilogue — the parity reference for the Pallas kernel and
-    the route taken when the table/eligibility says no."""
+    the route taken when the stage or eligibility says no."""
     dn = lax.conv_dimension_numbers(qx.shape, qw.shape,
                                     ("NHWC", "HWIO", "NHWC"))
     acc = lax.conv_general_dilated(

@@ -380,11 +380,10 @@ def serve_fingerprint() -> tuple:
     """Hashable digest of the serving tier's sharding knobs — the mesh
     spec (``MXNET_SERVE_MESH``) and the plan file named by
     ``MXNET_SERVE_SHARDING_PLAN`` (its content fingerprint, so an
-    in-place edit re-keys, not just a rename).  Chained into
-    ``pallas_block.dispatch_fingerprint()`` exactly like the int8 and
-    attention fingerprints, so a plan or mesh edit invalidates BOTH
-    dispatch-cache paths (cached_call extra_key and np_call_key) instead
-    of serving an executable compiled for the old layout.  Memoised on
+    in-place edit re-keys, not just a rename).  The serving engines
+    (``serve.InferenceEngine``, ``generate.DecodeEngine``) put it in
+    their program keys, so a plan or mesh edit compiles a new program
+    instead of serving one compiled for the old layout.  Memoised on
     the env values + plan-file mtime; steady-state cost is two env reads
     and one stat."""
     env = (os.environ.get(SERVE_MESH_ENV, ""),

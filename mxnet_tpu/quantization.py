@@ -30,8 +30,9 @@ Three calibration sources feed the activation thresholds:
 
 The quantized twins route through ``ops/nn.py``'s ``quantized_dense`` /
 ``quantized_conv`` cached-call kernels (MXU int8×int8→int32, fused
-dequant epilogue, Pallas int8 fast path per ``ops/pallas_int8.py``'s
-committed table), and ``QuantizedConv2D.fused_forward`` slots into the
+dequant epilogue, Pallas int8 fast path where
+``pallas_int8.decide_int8`` routes), and
+``QuantizedConv2D.fused_forward`` slots into the
 ``fused_conv_bn_relu`` residual-block route so quantized
 BasicBlock/Bottleneck forwards keep the single-pass epilogue.
 """
@@ -529,6 +530,9 @@ def _fold_batchnorm(net):
     return net
 
 
+# the calibration hooks ride the per-layer python forwards, which the
+# fused residual-block route (fused_conv_bn_relu) would pass by
+@_gnn._layerwise_forwards()
 def quantize_net(net, calib_data=None, calib_mode="naive",
                  quantized_dtype="int8", exclude_layers=None,
                  fold_bn=True, thresholds=None, logger=None):
@@ -570,15 +574,6 @@ def quantize_net(net, calib_data=None, calib_mode="naive",
             blk._active = False
             if hasattr(blk, "_clear_cache"):
                 blk._clear_cache()
-
-    # the fused residual-block route (fused_conv_bn_relu) likewise
-    # bypasses the per-layer python forwards the calibration hooks ride —
-    # force it off for the rewrite; the env flip re-keys the dispatch
-    # cache on both sides via the pallas fingerprint, so nothing stale
-    # survives the restore
-    import os
-    prev_block_env = os.environ.get("MXNET_TPU_PALLAS_BLOCK")
-    os.environ["MXNET_TPU_PALLAS_BLOCK"] = "0"
 
     try:
         if fold_bn:
@@ -639,10 +634,6 @@ def quantize_net(net, calib_data=None, calib_mode="naive",
                       else QuantizedConv2D(child, t))
             _replace(parent, child, qblock)
     finally:
-        if prev_block_env is None:
-            os.environ.pop("MXNET_TPU_PALLAS_BLOCK", None)
-        else:
-            os.environ["MXNET_TPU_PALLAS_BLOCK"] = prev_block_env
         for blk in hybrid_state:
             blk._active = True
             if hasattr(blk, "_clear_cache"):
@@ -657,34 +648,26 @@ def _selfcheck():     # pragma: no cover - exercised by `make int8-check`
 
     1. int8 Pallas implicit-GEMM vs XLA int8 fallback parity, with and
        without the residual+ReLU epilogue;
-    2. quantize a small seeded fused-residual net (BasicBlockV1 route,
-       forced through the int8 Pallas kernel by a temp committed table):
+    2. quantize a small seeded fused-residual net (BasicBlockV1 on the
+       routed ``14x14x256`` stage, ``one_tpu`` held true off the chip):
        quantized-vs-float within tolerance, argmax agreement ≥ 0.9, and
        the ``quant.int8.hits.<stage>`` counter moved;
     3. serving engine at ``precision="int8"``: ladder outputs sane, 0
-       post-warmup retraces;
-    4. a precision flip re-keys BOTH cache paths: the dispatch
-       fingerprint changes, a keyed quantized op re-dispatch counts a
-       cache miss, and re-registering counts a fresh
-       ``serve.precision.builds.*``.
+       post-warmup retraces, and re-registering under
+       ``MXNET_SERVE_PRECISION=int8`` counts a fresh
+       ``serve.precision.builds.int8``.
     """
-    import json
     import os
-    import tempfile
 
     import mxnet_tpu as mx
-    from . import dispatch_cache as _dc
     from . import telemetry as _tele
     from .ops import pallas_block as _pb
     from .ops import pallas_int8 as _pi8
     from .models.resnet import BasicBlockV1
     from .serve import ModelRegistry
 
-    saved = {k: os.environ.get(k) for k in
-             ("MXNET_TPU_PALLAS_INT8", "MXNET_TPU_PALLAS_INT8_TABLE",
-              "MXNET_TPU_PALLAS_BLOCK", "MXNET_SERVE_PRECISION")}
-    os.environ["MXNET_TPU_PALLAS_INT8"] = "1"
-    os.environ.pop("MXNET_SERVE_PRECISION", None)
+    saved_precision = os.environ.pop("MXNET_SERVE_PRECISION", None)
+    one_tpu, _pb.one_tpu = _pb.one_tpu, lambda: True
     rng = onp.random.RandomState(0)
     try:
         # (1) kernel parity: pallas interpret vs XLA composition
@@ -706,90 +689,68 @@ def _selfcheck():     # pragma: no cover - exercised by `make int8-check`
         print("int8-check: pallas vs xla parity ok")
 
         # (2) quantized fused-residual net, routed through the kernel
-        with tempfile.TemporaryDirectory() as td:
-            tab = os.path.join(td, "int8_ab.json")
-            with open(tab, "w") as f:
-                json.dump({"decisions": {"16x16x8": {"fwd": "pallas"}}}, f)
-            os.environ["MXNET_TPU_PALLAS_INT8_TABLE"] = tab
-            os.environ["MXNET_TPU_PALLAS_BLOCK"] = "1"
-            mx.seed(0)
-            net = _gnn.HybridSequential()
-            net.add(_gnn.Conv2D(8, 3, padding=1), _gnn.BatchNorm(),
-                    _gnn.Activation("relu"))
-            net.add(BasicBlockV1(8, stride=1))
-            net.add(_gnn.Flatten(), _gnn.Dense(10))
-            net.initialize()
-            calib = [NDArray(jnp.asarray(
-                rng.rand(4, 16, 16, 3).astype("float32")))
-                for _ in range(2)]
-            xt = NDArray(jnp.asarray(
-                rng.rand(16, 16, 16, 3).astype("float32")))
-            ref = net(xt).asnumpy()
-            quantize_net(net, calib_data=calib, calib_mode="naive")
-            blocks = [c for _, c, _ in _walk(net)]
-            assert any(isinstance(b, QuantizedConv2D) for b in blocks)
-            h0 = _tele.raw_snapshot()["counters"].get(
-                "quant.int8.hits.16x16x8", 0)
-            out = net(xt).asnumpy()
-            h1 = _tele.raw_snapshot()["counters"].get(
-                "quant.int8.hits.16x16x8", 0)
-            assert h1 > h0, "fused route never hit the int8 pallas kernel"
-            rel = onp.abs(out - ref).mean() / (onp.abs(ref).mean() + 1e-9)
-            assert rel < 0.1, f"quantized-vs-float rel err {rel}"
-            agree = (out.argmax(1) == ref.argmax(1)).mean()
-            assert agree >= 0.9, f"argmax agreement {agree}"
-            print(f"int8-check: fused quantized net ok "
-                  f"(rel={rel:.4f}, agree={agree:.2f}, "
-                  f"pallas hits +{h1 - h0})")
+        mx.seed(0)
+        net = _gnn.HybridSequential()
+        net.add(_gnn.Conv2D(256, 3, padding=1), _gnn.BatchNorm(),
+                _gnn.Activation("relu"))
+        net.add(BasicBlockV1(256, stride=1))
+        net.add(_gnn.Flatten(), _gnn.Dense(10))
+        net.initialize()
+        calib = [NDArray(jnp.asarray(
+            rng.rand(2, 14, 14, 3).astype("float32")))
+            for _ in range(2)]
+        xt = NDArray(jnp.asarray(
+            rng.rand(16, 14, 14, 3).astype("float32")))
+        ref = net(xt).asnumpy()
+        quantize_net(net, calib_data=calib, calib_mode="naive")
+        blocks = [c for _, c, _ in _walk(net)]
+        assert any(isinstance(b, QuantizedConv2D) for b in blocks)
+        h0 = _tele.raw_snapshot()["counters"].get(
+            "quant.int8.hits.14x14x256", 0)
+        out = net(xt).asnumpy()
+        h1 = _tele.raw_snapshot()["counters"].get(
+            "quant.int8.hits.14x14x256", 0)
+        assert h1 > h0, "fused route never hit the int8 pallas kernel"
+        rel = onp.abs(out - ref).mean() / (onp.abs(ref).mean() + 1e-9)
+        assert rel < 0.1, f"quantized-vs-float rel err {rel}"
+        agree = (out.argmax(1) == ref.argmax(1)).mean()
+        assert agree >= 0.9, f"argmax agreement {agree}"
+        print(f"int8-check: fused quantized net ok "
+              f"(rel={rel:.4f}, agree={agree:.2f}, "
+              f"pallas hits +{h1 - h0})")
 
-            # (3) serving engine at precision=int8: 0 post-warmup retraces
-            mx.seed(1)
-            srv = _gnn.HybridSequential()
-            srv.add(_gnn.Dense(16, activation="relu"), _gnn.Dense(4))
-            srv.initialize()
-            srv(NDArray(jnp.zeros((1, 8), jnp.float32)))
-            with ModelRegistry(buckets=(1, 2)) as reg:
-                entry = reg.register("m", srv, item_shape=(8,),
-                                     precision="int8")
-                assert entry.engine.precision == "int8"
-                for n in (1, 2, 1, 2):
-                    y = reg.predict("m", onp.asarray(
-                        rng.rand(n, 8), onp.float32))[0]
-                    assert onp.asarray(y).shape == (n, 4)
-                st = entry.engine.stats()
-                assert st["precision"] == "int8"
-                assert st["retraces"] == 0, st
-                print("int8-check: int8 serving ok (0 retraces)")
+        # (3) serving engine at precision=int8: 0 post-warmup retraces
+        mx.seed(1)
+        srv = _gnn.HybridSequential()
+        srv.add(_gnn.Dense(16, activation="relu"), _gnn.Dense(4))
+        srv.initialize()
+        srv(NDArray(jnp.zeros((1, 8), jnp.float32)))
+        with ModelRegistry(buckets=(1, 2)) as reg:
+            entry = reg.register("m", srv, item_shape=(8,),
+                                 precision="int8")
+            assert entry.engine.precision == "int8"
+            for n in (1, 2, 1, 2):
+                y = reg.predict("m", onp.asarray(
+                    rng.rand(n, 8), onp.float32))[0]
+                assert onp.asarray(y).shape == (n, 4)
+            st = entry.engine.stats()
+            assert st["precision"] == "int8"
+            assert st["retraces"] == 0, st
+            print("int8-check: int8 serving ok (0 retraces)")
 
-                # (4) precision flip re-keys both cache paths
-                fp0 = _pb.dispatch_fingerprint()
-                qd = next(b for b in [c for _, c, _ in _walk(net)]
-                          if isinstance(b, QuantizedDense))
-                feat = NDArray(jnp.asarray(
-                    rng.rand(4, int(qd._qw.shape[0]))
-                    .astype("float32")))
-                qd(feat)                      # key established
-                m0 = _dc.stats()["misses"]
-                qd(feat)                      # steady state: cache hit
-                assert _dc.stats()["misses"] == m0, "unstable int8 key"
-                os.environ["MXNET_SERVE_PRECISION"] = "int8"
-                fp1 = _pb.dispatch_fingerprint()
-                assert fp0 != fp1, "precision flip left fingerprint"
-                qd(feat)                      # re-keyed: counted miss
-                assert _dc.stats()["misses"] > m0, \
-                    "precision flip did not re-key the np dispatch path"
-                b0 = _tele.raw_snapshot()["counters"].get(
-                    "serve.precision.builds.int8", 0)
-                reg.register("m", srv, item_shape=(8,))  # env default now
-                b1 = _tele.raw_snapshot()["counters"].get(
-                    "serve.precision.builds.int8", 0)
-                assert b1 > b0, "re-register did not rebuild at int8"
-            print("int8-check: precision flip re-keys both cache paths")
+            os.environ["MXNET_SERVE_PRECISION"] = "int8"
+            b0 = _tele.raw_snapshot()["counters"].get(
+                "serve.precision.builds.int8", 0)
+            reg.register("m", srv, item_shape=(8,))  # env default now
+            b1 = _tele.raw_snapshot()["counters"].get(
+                "serve.precision.builds.int8", 0)
+            assert b1 > b0, "re-register did not rebuild at int8"
+        print("int8-check: the env default builds at int8")
     finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+        _pb.one_tpu = one_tpu
+        if saved_precision is None:
+            os.environ.pop("MXNET_SERVE_PRECISION", None)
+        else:
+            os.environ["MXNET_SERVE_PRECISION"] = saved_precision
     print("quantization selfcheck ok")
     return 0

@@ -26,8 +26,8 @@ simulated per-device HBM budget (``MXNET_SERVE_HBM_BUDGET``) refuses
 models whose per-device parameter bytes exceed it.
 
 Retrace discipline follows generate.py's DecodeEngine: programs are
-keyed by (bucket, plan fingerprint, ``dispatch_fingerprint()``), so a
-sharding-plan edit or pallas route flip compiles a NEW program (a
+keyed by (bucket, plan fingerprint, precision, ``serve_fingerprint()``),
+so a sharding-plan or serve-mesh edit compiles a NEW program (a
 counted ``serve.rebuilds``) instead of serving a stale executable;
 after :meth:`warmup` a SECOND trace of a warmed key is a shape leak and
 increments ``serve.retraces`` — gated at zero by ``make serve-check``.
@@ -65,10 +65,8 @@ class HBMBudgetExceeded(RuntimeError):
 
 def resolve_precision(precision: Optional[str] = None) -> str:
     """Resolve the serving precision: explicit argument (per-model
-    override) > ``MXNET_SERVE_PRECISION`` env default > fp32.  The
-    resolved value also rides the pallas dispatch fingerprint
-    (``pallas_int8.int8_fingerprint``), so flipping the env var re-keys
-    both dispatch-cache paths instead of serving stale executables."""
+    override) > ``MXNET_SERVE_PRECISION`` env default > fp32.  An engine
+    resolves it once, at construction, and keys its programs on it."""
     p = str(precision or os.environ.get("MXNET_SERVE_PRECISION", "")
             or "fp32").lower()
     p = {"float32": "fp32", "bfloat16": "bf16"}.get(p, p)
@@ -261,18 +259,18 @@ class InferenceEngine:
 
     # ----------------------------------------------------------- programs
     def _fp(self) -> tuple:
-        """Program-cache key tail: the resolved plan's fingerprint (an
-        explicitly-passed plan never touches env, so it must key here)
-        plus the global dispatch fingerprint (pallas routes, precision,
-        and the env-resolved serve mesh/plan via serve_fingerprint)."""
-        from ..ops import pallas_block as _pb
+        """Program-cache key tail, all of it resolved by this layer: the
+        plan's fingerprint (an explicitly-passed plan never touches
+        env), the engine's precision, and the env-resolved serve
+        mesh/plan (``sharding.serve_fingerprint``)."""
+        from ..parallel import sharding as _sharding
         return (self.plan.fingerprint if self.plan is not None else "",
-                _pb.dispatch_fingerprint())
+                self.precision, _sharding.serve_fingerprint())
 
     def _note_trace(self, key):
         """Trace-time side effect inside every bucket program.  Like
         DecodeEngine: after warmup a FIRST trace of a NEW key is a
-        sanctioned rebuild (the plan or dispatch fingerprint changed —
+        sanctioned rebuild (the plan or serve fingerprint changed —
         counted ``serve.rebuilds``); only a SECOND trace of the same key
         is a shape leak (``serve.retraces``, gated at 0)."""
         with self._mu:
@@ -322,7 +320,7 @@ class InferenceEngine:
     def warmup(self):
         """Precompile every bucket program with a zero batch and block
         until done.  After this, a second trace of any warmed key counts
-        as a retrace (a NEW key — plan/route fingerprint flip — counts
+        as a retrace (a NEW key — plan/serve fingerprint flip — counts
         as a rebuild instead)."""
         import warnings
 

@@ -28,9 +28,10 @@ def test_serve_phase_toy():
 
 
 def test_kernel_phase_toy(monkeypatch):
-    """Forced routing, interpret mode: the parity phase the chip run opens
-    with, on the stage the default table routes."""
-    monkeypatch.setenv("MXNET_TPU_PALLAS_BLOCK", "1")
+    """Interpret mode, as if on one TPU: the parity phase the chip run
+    opens with, on the stage the default table routes."""
+    from mxnet_tpu.ops import pallas_block
+    monkeypatch.setattr(pallas_block, "one_tpu", lambda: True)
     chip_smoke.kernel_phase(batch=1)
 
 

@@ -180,3 +180,38 @@ def test_batcher_streams_and_evicts(small):
     snap = telemetry.summary()
     assert snap.get("decode.evictions", 0) >= 1
     assert snap.get("decode.joins", 0) >= 2
+
+
+@pytest.mark.parametrize("hd", [128, 64])
+def test_prefill_route_follows_the_head_dim(hd, monkeypatch):
+    """On one TPU a prefill at head_dim 128 takes the causal kernel pair
+    (one ``mx_causal_attn_fwd`` a layer, counted once though gpt.py and
+    ops/nn.py both ask) and agrees with the interleaved composition; at
+    head_dim 64 it counts no kernel and stays on the composition."""
+    from mxnet_tpu.ops import pallas_block
+    cfg = gpt.GPTConfig(vocab_size=61, hidden=2 * hd, layers=1, heads=2,
+                        intermediate=64, max_len=128)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(3))
+    tokens = jnp.asarray(
+        onp.random.RandomState(3).randint(0, 61, (1, 128)), jnp.int32)
+
+    # a fresh function each time: jit caches a trace by the function
+    ref = jax.jit(lambda t: gpt.prefill(params, cfg, t))(tokens)
+    monkeypatch.setattr(pallas_block, "one_tpu", lambda: True)
+    text = str(jax.make_jaxpr(lambda t: gpt.prefill(params, cfg, t))(tokens))
+    telemetry.reset()
+    got = jax.jit(lambda t: gpt.prefill(params, cfg, t))(tokens)
+    causal = {k: v for k, v in telemetry.raw_snapshot()["counters"].items()
+              if "causal_attention" in k and v}
+    if hd == 128:
+        assert "mx_causal_attn_fwd" in text
+        assert causal == {"dispatch.pallas.hits.causal_attention.128": 1}
+    else:
+        assert "mx_causal_attn" not in text
+        assert causal == {"dispatch.pallas.fallbacks.causal_attention.64": 1}
+    # the kernels round their MXU operands to bfloat16, as the chip's
+    # default precision does to the composition's float32 products
+    tol = 3e-2 if hd == 128 else 2e-4
+    for a, b in zip(got, ref):                  # logits, k, v
+        onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
+                                    rtol=tol, atol=tol)

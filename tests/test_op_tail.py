@@ -147,6 +147,35 @@ def test_attention_interleaved_and_sldwin():
     assert cx.shape == (2, 6, H, D)
 
 
+def test_interleaved_selfatt_causal_parity():
+    """ops/attention.py: interleaved qkv scores with causal=True +
+    softmax + valatt == an explicit-mask attention over the
+    de-interleaved heads."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as att
+    L, B, H, D = 16, 2, 2, 8
+    rs = onp.random.RandomState(11)
+    qkv = jnp.asarray(rs.randn(L, B, H * 3 * D), jnp.float32)
+    scores = att.interleaved_matmul_selfatt_qk(qkv, H, causal=True)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    got = att.interleaved_matmul_selfatt_valatt(
+        qkv, probs.astype(qkv.dtype), H)          # (L, B, H*D)
+
+    t5 = qkv.reshape(L, B, H, 3, D).transpose(1, 2, 0, 3, 4)  # (B,H,L,3,D)
+    q, k, v = t5[..., 0, :], t5[..., 1, :], t5[..., 2, :]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / float(D) ** 0.5
+    s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s, -1e30)
+    ref = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+    ref = ref.transpose(2, 0, 1, 3).reshape(L, B, H * D)
+    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(ref),
+                                rtol=1e-5, atol=1e-5)
+
+    # masked scores really are the finite sentinel, not -inf (a true
+    # -inf NaNs fully-masked lanes through inf - inf compositions)
+    assert onp.isfinite(onp.asarray(scores)).all()
+
+
 def test_boxes_encode_decode_matching():
     # bounding_box.cc documented example
     s = nd.array(onp.array([[0.5, 0.6], [0.1, 0.2], [0.3, 0.4]],

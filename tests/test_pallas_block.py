@@ -2,8 +2,9 @@
 layer-by-layer XLA composition — forward, dgrad/wgrad/dgamma, BN train
 vs frozen, residual vs none, per-stage dispatch, and a fuse_step run
 with zero steady-state retraces.  Runs the SAME kernels in interpret
-mode on CPU; the real-chip A/B lives in benchmark/pallas_conv_ab.py
---block."""
+mode on CPU; a route is taken here by patching the one seam,
+``pallas_block.one_tpu``.  Chip numbers: the ``resnet50-train-ring``
+cell (PERF_LEDGER.jsonl)."""
 import numpy as onp
 import pytest
 
@@ -21,13 +22,10 @@ STAGES = [
     ((1, 14, 14, 256), "14x14x256"),
 ]
 
-ALL_PALLAS = "56x56x64=pallas,28x28x128=pallas,14x14x256=pallas"
-
 
 @pytest.fixture
 def pallas_on(monkeypatch):
-    monkeypatch.setenv("MXNET_TPU_PALLAS_BLOCK", "1")
-    monkeypatch.setenv("MXNET_TPU_PALLAS_STAGES", ALL_PALLAS)
+    monkeypatch.setattr(pb, "one_tpu", lambda: True)
 
 
 def _ref(x, w, gamma, beta, mean, var, res=None, *, training=True,
@@ -176,53 +174,66 @@ def test_residual_and_relu_optional(pallas_on):
     assert bool(jnp.all(jnp.isfinite(gx)))
 
 
-def test_per_stage_dispatch_and_fingerprint(monkeypatch):
-    """The per-stage table (committed JSON ← env overrides) drives
-    decide(); a flip changes the dispatch fingerprint so cached
-    executables for the old route can never be served."""
-    monkeypatch.setenv("MXNET_TPU_PALLAS_BLOCK", "1")
-    monkeypatch.setenv("MXNET_TPU_PALLAS_STAGES", ALL_PALLAS)
-    r1 = pb.decide((1, 14, 14, 256), (3, 3, 256, 256), jnp.float32)
-    assert (r1.fwd, r1.bwd, r1.stage) == ("pallas", "pallas", "14x14x256")
-    fp1 = pb.dispatch_fingerprint()
-
-    monkeypatch.setenv("MXNET_TPU_PALLAS_STAGES",
-                       "56x56x64=fwd,14x14x256=xla")
+def test_per_stage_dispatch(pallas_on, monkeypatch):
+    """``_DEFAULT_TABLE`` drives decide(): the routed stage goes forward
+    and backward through Pallas, the others and every ineligible shape
+    take XLA, and off one TPU nothing routes."""
+    r1 = pb.decide((1, 56, 56, 64), (3, 3, 64, 64), jnp.float32)
+    assert (r1.fwd, r1.bwd, r1.stage) == ("pallas", "pallas", "56x56x64")
     r2 = pb.decide((1, 14, 14, 256), (3, 3, 256, 256), jnp.float32)
-    assert (r2.fwd, r2.bwd) == ("xla", "xla")
-    r3 = pb.decide((1, 56, 56, 64), (3, 3, 64, 64), jnp.float32)
-    assert (r3.fwd, r3.bwd) == ("pallas", "xla")   # fwd-only override
-    assert pb.dispatch_fingerprint() != fp1
+    assert (r2.fwd, r2.bwd, r2.stage) == ("xla", "xla", "14x14x256")
+    assert pb.block_active()
 
-    # master kill switch beats any table
-    monkeypatch.setenv("MXNET_TPU_PALLAS_BLOCK", "0")
+    # ineligible shapes fall back regardless of the table (5×5 filter)
+    assert not pb.eligible_block((1, 56, 56, 64), (5, 5, 64, 64),
+                                 jnp.float32)
+    assert pb.decide((1, 56, 56, 64), (5, 5, 64, 64), jnp.float32).fwd == "xla"
+
+    monkeypatch.setattr(pb, "one_tpu", lambda: False)
     r4 = pb.decide((1, 56, 56, 64), (3, 3, 64, 64), jnp.float32)
     assert r4.fwd == "xla" and not pb.block_active()
 
-    # ineligible shapes fall back regardless of the table (5×5 filter)
-    monkeypatch.setenv("MXNET_TPU_PALLAS_BLOCK", "1")
-    assert not pb.eligible_block((1, 56, 56, 64), (5, 5, 64, 64),
-                                 jnp.float32)
+
+class _Dev:
+    def __init__(self, platform):
+        self.platform = platform
 
 
-def test_route_flip_invalidates_dispatch_cache(monkeypatch):
-    """ops/nn.py residual_block keyed on the dispatch fingerprint: the
-    same call after a table flip is a cache MISS (recompiled on the new
-    route), and both routes agree numerically."""
-    monkeypatch.setenv("MXNET_TPU_PALLAS_BLOCK", "1")
-    monkeypatch.setenv("MXNET_TPU_PALLAS_STAGES", "14x14x256=pallas")
-    from mxnet_tpu import dispatch_cache
-    from mxnet_tpu.ops import nn as onn
-    x, w, _, gamma, beta, mean, var = _data((1, 14, 14, 256), seed=5,
-                                            res=False)
-    out_p = onn.residual_block(x, w, gamma, beta, mean, var)[0]
-    d0 = dispatch_cache.stats()
-    monkeypatch.setenv("MXNET_TPU_PALLAS_STAGES", "14x14x256=xla")
-    out_x = onn.residual_block(x, w, gamma, beta, mean, var)[0]
-    d1 = dispatch_cache.stats()
-    assert d1["misses"] > d0["misses"], "stale executable served"
-    onp.testing.assert_allclose(onp.asarray(out_p), onp.asarray(out_x),
-                                atol=1e-3, rtol=1e-3)
+_HOSTS = {"one-tpu": [_Dev("tpu")], "cpu": [_Dev("cpu")],
+          "four-tpus": [_Dev("tpu")] * 4}
+
+
+@pytest.mark.parametrize("host", sorted(_HOSTS))
+@pytest.mark.parametrize("shape,stage", STAGES)
+def test_shapes_and_one_tpu_decide(shape, stage, host, monkeypatch):
+    """The whole rule: a stage's constant entry, on exactly one TPU.
+    ``decide``, ``conv_wins`` and the int8 decision agree with it and
+    count what they answered; nothing else is consulted."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops import pallas_int8
+    monkeypatch.setattr(jax, "devices", lambda *a: _HOSTS[host])
+    c = shape[-1]
+    w = (3, 3, c, c)
+    block = host == "one-tpu" and pb._DEFAULT_TABLE[stage]["fwd"] == "pallas"
+    int8 = host == "one-tpu" and \
+        pallas_int8._DEFAULT_TABLE[stage]["fwd"] == "pallas"
+    telemetry.reset()
+    route = pb.decide(shape, w, jnp.bfloat16, has_residual=True)
+    assert route.stage == stage
+    assert route.fwd == ("pallas" if block else "xla")
+    assert route.bwd == (pb._DEFAULT_TABLE[stage]["bwd"] if block else "xla")
+    assert pb.conv_wins(shape, w, 1, 1, 1, 1, jnp.bfloat16) is block
+    assert not pb.conv_wins(shape, w, 2, 1, 1, 1, jnp.bfloat16)   # stride 2
+    assert not pb.conv_wins(shape, w, 1, 1, 1, 2, jnp.bfloat16)   # grouped
+    assert pallas_int8.decide_int8(shape, w, True) == \
+        ("pallas" if int8 else "xla")
+    counters = {k: v for k, v in telemetry.raw_snapshot()["counters"].items()
+                if k.startswith(("dispatch.pallas.", "quant.int8.")) and v}
+    want = {}
+    if host == "one-tpu":
+        want[f"dispatch.pallas.{'hits' if block else 'fallbacks'}.{stage}"] = 1
+        want[f"quant.int8.{'hits' if int8 else 'fallbacks'}.{stage}"] = 1
+    assert counters == want
 
 
 def test_fuse_step_zero_retraces(pallas_on):
@@ -282,20 +293,36 @@ def test_default_routes_need_exactly_one_tpu(monkeypatch):
     """Default-on only where this process drives exactly one TPU: a
     Mosaic kernel cannot be partitioned by GSPMD, so on a multi-chip
     host (any program may be partitioned) every route answers XLA."""
-    from mxnet_tpu.ops import pallas_attention, pallas_int8, pallas_kernels
+    from mxnet_tpu.ops import pallas_int8, pallas_kernels
 
-    class Dev:
-        def __init__(self, platform):
-            self.platform = platform
-
-    for name in ("MXNET_TPU_PALLAS_BLOCK", "MXNET_TPU_PALLAS_INT8",
-                 "MXNET_TPU_PALLAS_ATTN"):
-        monkeypatch.delenv(name, raising=False)
-    gates = (pb.enabled, pallas_int8.int8_enabled,
-             pallas_attention.attn_enabled,
+    gates = (pb.block_active,
+             lambda: pallas_int8.decide_int8(
+                 (1, 14, 14, 256), (3, 3, 256, 256)) == "pallas",
              lambda: pallas_kernels._use_pallas(128))
-    for devs, want in (([Dev("tpu")], True), ([Dev("tpu")] * 4, False),
-                       ([Dev("cpu")], False)):
-        monkeypatch.setattr(jax, "devices", lambda *a, _d=devs: _d)
+    for host, want in (("one-tpu", True), ("four-tpus", False),
+                       ("cpu", False)):
+        monkeypatch.setattr(jax, "devices", lambda *a, _h=host: _HOSTS[_h])
         assert pb.one_tpu() is want
-        assert [g() for g in gates] == [want] * len(gates), devs
+        assert [g() for g in gates] == [want] * len(gates), host
+
+
+def test_no_environment_name_chooses_a_kernel():
+    """The one ``MXNET_TPU_PALLAS_*`` name the program knows is
+    ``…_INTERPRET``, which chooses no path (the same kernels,
+    interpreted): no file of the program, the row orchestrator, the
+    smoke script or the Makefile reads or sets another."""
+    import os
+    import re
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    files = [os.path.join(repo, f)
+             for f in ("bench.py", "chip_smoke.py", "Makefile")]
+    for root, _, names in os.walk(os.path.join(repo, "mxnet_tpu")):
+        files += [os.path.join(root, n) for n in names if n.endswith(".py")]
+    found = {}
+    for path in files:
+        with open(path, encoding="utf-8") as f:
+            names = set(re.findall(r"MXNET_TPU_PALLAS_[A-Z0-9_]+", f.read()))
+        if names - {"MXNET_TPU_PALLAS_INTERPRET"}:
+            found[os.path.relpath(path, repo)] = sorted(names)
+    assert not found
+    assert len(files) > 100

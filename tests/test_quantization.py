@@ -235,42 +235,74 @@ def test_int8_pallas_vs_xla_parity():
         assert np.abs(a - b).max() < 1e-4, kw
 
 
-def test_quantize_net_fused_block_route(monkeypatch, tmp_path):
+def _fusable_net(channels=256, seed=9):
+    """A stem and one BasicBlockV1 on the ``14x14x256`` stage, which the
+    int8 table routes, and its (2, 14, 14, 3) input."""
+    from mxnet_tpu.models.resnet import BasicBlockV1
+    mx.seed(seed)
+    net = nn.HybridSequential()
+    net.add(nn.Conv2D(channels, 3, padding=1), nn.BatchNorm(),
+            nn.Activation("relu"), BasicBlockV1(channels, stride=1))
+    net.initialize()
+    x = mx.np.array(np.random.RandomState(seed).rand(2, 14, 14, 3)
+                    .astype("float32"))
+    net(x)                          # materialize + settle running stats
+    return net, x
+
+
+def test_quantize_net_fused_block_route(monkeypatch):
     """The fused residual-block route survives quantization: the
     QuantizedConv2D twins carry fused_forward, the routed stage fires
     the int8 Pallas kernel (interpret mode), and accuracy holds."""
-    import json as _json
     from mxnet_tpu import telemetry
-    from mxnet_tpu.models.resnet import BasicBlockV1
+    from mxnet_tpu.ops import pallas_block
 
-    table = tmp_path / "int8_ab.json"
-    table.write_text(_json.dumps(
-        {"decisions": {"16x16x8": {"fwd": "pallas"}}}))
-    monkeypatch.setenv("MXNET_TPU_PALLAS_INT8_TABLE", str(table))
-    monkeypatch.setenv("MXNET_TPU_PALLAS_INT8", "1")
-    monkeypatch.setenv("MXNET_TPU_PALLAS_BLOCK", "1")
-
-    mx.seed(9)
-    net = nn.HybridSequential()
-    net.add(nn.Conv2D(8, 3, padding=1), nn.BatchNorm(),
-            nn.Activation("relu"), BasicBlockV1(8, stride=1))
-    net.initialize()
-    rng = np.random.RandomState(9)
-    x = mx.np.array(rng.rand(2, 16, 16, 3).astype("float32"))
-    net(x)                          # materialize + settle running stats
+    monkeypatch.setattr(pallas_block, "one_tpu", lambda: True)
+    net, x = _fusable_net()
     ref = net(x).asnumpy()
     hits0 = telemetry.raw_snapshot()["counters"].get(
-        "quant.int8.hits.16x16x8", 0)
+        "quant.int8.hits.14x14x256", 0)
     q.quantize_net(net, calib_data=[x], calib_mode="naive")
     got = net(x).asnumpy()
     rel = np.abs(got - ref).mean() / (np.abs(ref).mean() + 1e-9)
     assert rel < 0.1, rel
     hits1 = telemetry.raw_snapshot()["counters"].get(
-        "quant.int8.hits.16x16x8", 0)
+        "quant.int8.hits.14x14x256", 0)
     assert hits1 > hits0            # the Pallas int8 route actually fired
     twins = [b for _, b, _ in q._walk(net)
              if isinstance(b, q.QuantizedConv2D)]
     assert twins and all(hasattr(b, "fused_forward") for b in twins)
+
+
+def test_quantize_net_calibrates_layerwise_and_leaves_the_environment(
+        monkeypatch):
+    """Where the blocks would fuse (BatchNorm kept, so conv + BN is one
+    fused op that passes ``Conv2D.forward`` by), the rewrite still runs
+    every layer's own forward: each quantizable layer is calibrated —
+    and the program's environment is not how it says so: ``os.environ``
+    is byte-identical after."""
+    import os
+    from mxnet_tpu.gluon import nn as gnn
+    from mxnet_tpu.ops import pallas_block
+
+    monkeypatch.setattr(pallas_block, "one_tpu", lambda: True)
+    net, x = _fusable_net(seed=10)
+    assert gnn.fused_block_active()
+    sites = [p for _, c, p in q._walk(net) if isinstance(c, nn.Conv2D)]
+    assert len(sites) == 3
+    seen = []
+    real = q._Collector.add
+    monkeypatch.setattr(q._Collector, "add",
+                        lambda self, path, arr: (seen.append(path),
+                                                 real(self, path, arr))[1])
+    before = dict(os.environ)
+    q.quantize_net(net, calib_data=[x], calib_mode="naive", fold_bn=False)
+    assert dict(os.environ) == before
+    assert sorted(set(seen)) == sorted(sites)
+    assert gnn.fused_block_active()         # the context was left
+    twins = [b for _, b, _ in q._walk(net)
+             if isinstance(b, q.QuantizedConv2D)]
+    assert len(twins) == 3 and all(b._in_t > 0 for b in twins)
 
 
 def test_serve_precision_resolution(monkeypatch):
@@ -317,16 +349,50 @@ def test_serve_int8_routing_and_admission():
 
 
 def test_precision_flip_rekeys_dispatch(monkeypatch):
-    """MXNET_SERVE_PRECISION is digested into the shared dispatch
-    fingerprint, so a precision flip re-keys every cached-call path."""
-    from mxnet_tpu.ops import pallas_block as pb
+    """An engine keys its programs on the precision it resolved itself:
+    two engines built under different ``MXNET_SERVE_PRECISION`` differ
+    in ``_fp()``, and nothing below the serving layer reads the name."""
+    from mxnet_tpu.serve.engine import InferenceEngine
+
+    def engine():
+        mx.seed(12)
+        net = nn.HybridSequential()
+        net.add(nn.Dense(8, activation="relu"), nn.Dense(3))
+        net.initialize()
+        net(mx.np.array(np.zeros((1, 6), np.float32)))
+        return InferenceEngine(net, (6,), buckets=(1,))
+
     monkeypatch.delenv("MXNET_SERVE_PRECISION", raising=False)
-    fp0 = pb.dispatch_fingerprint()
+    fp32 = engine()
     monkeypatch.setenv("MXNET_SERVE_PRECISION", "int8")
-    fp1 = pb.dispatch_fingerprint()
-    assert fp0 != fp1
+    int8 = engine()
+    assert (fp32.precision, int8.precision) == ("fp32", "int8")
+    assert fp32._fp() != int8._fp()
     monkeypatch.delenv("MXNET_SERVE_PRECISION")
-    assert pb.dispatch_fingerprint() == fp0
+    assert fp32._fp() == engine()._fp()
+
+
+def test_eager_convolution_is_one_cache_entry_with_no_extra_key():
+    """A routing decision cannot change inside a process, so the ops it
+    is made in key on their arguments alone, like ``softmax``."""
+    import jax.numpy as jnp
+    from mxnet_tpu import dispatch_cache
+    from mxnet_tpu.ops import nn as ops_nn
+    for op in (ops_nn.convolution, ops_nn.residual_block,
+               ops_nn.quantized_conv, ops_nn.quantized_dense,
+               ops_nn.softmax):
+        assert not hasattr(op, "__mx_extra_key__"), op
+    rng = np.random.RandomState(13)
+    x = jnp.asarray(rng.randn(1, 6, 6, 5).astype(np.float32))
+    w = jnp.asarray(rng.randn(3, 3, 5, 7).astype(np.float32))
+    dispatch_cache.clear()
+    a = ops_nn.convolution(x, w, stride=1, pad=1)
+    n, d0 = dispatch_cache.cache_len(), dispatch_cache.stats()
+    b = ops_nn.convolution(x, w, stride=1, pad=1)
+    d1 = dispatch_cache.stats()
+    assert n == 1 and dispatch_cache.cache_len() == 1
+    assert d1["misses"] == d0["misses"] and d1["hits"] == d0["hits"] + 1
+    assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_quantize_net_folds_bn_and_keeps_argmax():

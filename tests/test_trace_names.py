@@ -19,7 +19,7 @@ from mxnet_tpu.gluon import Trainer, nn
 from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
 from mxnet_tpu.ndarray import NDArray
 from mxnet_tpu.ops import nn as ops_nn
-from mxnet_tpu.ops import (pallas_attention, pallas_block, pallas_int8,
+from mxnet_tpu.ops import (pallas_block, pallas_int8,
                            pallas_kernels)
 
 
@@ -176,10 +176,6 @@ KERNEL_SITES = {
         lambda: pallas_kernels._attn_bwd_pallas(
             _QKV[0], _QKV[0], _QKV[0], _QKV[0], 0.1, d=128),
         ["mx_attn_bwd"]),
-    "attention.causal": (
-        lambda: pallas_attention._causal_attention_pallas(_QKV, _QKV, _QKV,
-                                                          0.1),
-        ["mx_flash_fwd"]),
     "kernels.causal_attention": (
         lambda: pallas_kernels._causal_fwd_pallas(_QC, _KC, _KC, 2, 1,
                                                   (128, 128)),
