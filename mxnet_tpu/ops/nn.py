@@ -926,10 +926,21 @@ def ssd_chunked(x, dt, a, b, c, d=None, chunk=128):
     temporaries are (B, T/chunk, H, chunk, chunk) and (B, T/chunk, H, P, N):
     linear in T.  Decays are exponentials of differences of an inclusive
     cumulative sum taken where the difference is not positive, so none
-    exceeds 1."""
-    _count_route("ssm.xla_chunked")
+    exceeds 1.
+
+    Where `pallas_kernels.ssd_use_pallas` says so (one TPU, ``chunk`` 128
+    dividing T, N in 128s, whole groups whose heads fill 128-lane blocks)
+    the same quantity is one forward and one backward Pallas kernel that
+    read x, b and c in place, carry the state from chunk to chunk in VMEM
+    and keep every (chunk, chunk) tile there."""
+    from . import pallas_kernels as _pk
     B, T, H, P = x.shape
     G, N = b.shape[2:]
+    if _pk.ssd_use_pallas(T, H, G, P, N, chunk):
+        return _pk.ssd_fused(
+            x.reshape(B, T, H * P), dt, a, b.reshape(B, T, G * N),
+            c.reshape(B, T, G * N), d, H, G).reshape(x.shape)
+    _count_route("ssm.xla_chunked")
     q = chunk if T % chunk == 0 else T
     nc, r = T // q, H // G
     f32 = jnp.float32

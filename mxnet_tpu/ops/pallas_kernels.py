@@ -740,3 +740,369 @@ def _causal_vjp_bwd(heads, kv, blocks, res, g):
 
 
 causal_gqa_attention_fused.defvjp(_causal_vjp_fwd, _causal_vjp_bwd)
+
+
+# ------------------------------------ Mamba-2's chunked scan (SSD, trainable)
+#
+# Per head S_t = exp(dt_t a) S_{t-1} + dt_t x_t b_tᵀ, y_t = S_t c_t + d x_t,
+# by chunks of `chunk` steps: inside a chunk y is a product with the
+# (chunk, chunk) tile m[t, s] = (c_t·b_s) exp(cum_t − cum_s) dt_s, s ≤ t;
+# across chunks the state is carried.  The grid is (B, G, T/chunk), chunks
+# sequential: the state entering a chunk is a VMEM scratch, so neither a
+# decay tile nor a chunk's end state nor the chunk-by-chunk carry product
+# of the composition in `ops/nn.py::ssd_chunked` reaches HBM.  The backward
+# walks the chunks in reverse with the state's cotangent as its scratch and
+# recomputes the tiles; the one residual the forward emits for it is the
+# state entering every chunk, rounded as the MXU reads it.
+#
+# Arrays stay as the mixer holds them: x, y and their cotangents
+# (B, T, H·P), b and c (B, T, G·N); a group's H/G heads are adjacent lanes
+# and a grid step takes all of them (c·bᵀ is made once for the group, db
+# and dc are summed over its heads in the step).  Heads narrower than a
+# 128-lane block share one and are told apart by lane masks, as in the
+# attention kernels above; the state is held transposed, (N, H/G·P), so
+# every product of a block is 128 lanes wide.  The per-step scalars — the
+# inclusive cumulative sum of dt·a inside each chunk and dt, a few MB that
+# XLA makes exactly in float32 — come packed twice: `col` (B, G, T, 2·H/G)
+# with time on sublanes and `row` (B, G, 2·H/G, T) with time on lanes, so
+# no tile needs a relayout.
+#
+# Precision: HBM arrays, the carried state, cum, every exponential and m
+# before it enters the MXU are float32; MXU operands are rounded to
+# bfloat16 — what XLA's DEFAULT precision does to the composition's
+# einsums.  Every decay is the exponential of a difference that is not
+# positive (above the diagonal: of `_SSD_LOW`, which is exactly 0).
+
+_SSD_CHUNK = 128             # the chunk the route takes (the (chunk, chunk)
+#                              tile is one MXU pass deep)
+_SSD_MAX_LANES = 1024        # H/G·P of a group: its blocks and the
+#                              (N, H/G·P) state stay a few MiB of VMEM
+_SSD_LOW = -1e30             # a masked exponent
+
+
+def _ssd_lane_block(p):
+    """→ (lanes of a block, heads sharing it)."""
+    return (128, 128 // p) if p < 128 else (p, 1)
+
+
+def _per_head(masks, vals):
+    """One array whose lanes hold, for every head of a block, that head's
+    value (a (rows, 1) column or a full tile)."""
+    out = vals[-1]
+    for keep, v in zip(masks[-2::-1], vals[-2::-1]):
+        out = jnp.where(keep, v, out)
+    return out
+
+
+def _head_sums(masks, tile):
+    """→ per head the (rows, 1) sums over that head's lanes."""
+    return [jnp.sum(tile if keep is None else jnp.where(keep, tile, 0.0),
+                    axis=1, keepdims=True) for keep in masks]
+
+
+def _ssd_fwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, y_ref,
+                    *rest, p):
+    """One chunk of one group: y, the state carried on, and (where the
+    call has an output for it) the state that entered, for the backward."""
+    *s_ref, st_ref = rest
+    f32 = jnp.float32
+    q, lanes = x_ref.shape[1:]
+    width, per = _ssd_lane_block(p)
+    r = lanes // p
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        st_ref[...] = jnp.zeros(st_ref.shape, f32)
+
+    b = b_ref[0].astype(f32)
+    cq = _mxu(c_ref[0])
+    cb = jax.lax.dot_general(cq, _mxu(b), _NT, preferred_element_type=f32)
+    bt = _mxu(b.T)
+    col, row = col_ref[0, 0], row_ref[0, 0]
+    below = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= \
+        jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)          # s <= t
+    masks = _head_masks(width, per)
+    for blk in range(lanes // width):
+        at = slice(blk * width, (blk + 1) * width)
+        x = x_ref[0, :, at].astype(f32)
+        xq = _mxu(x)
+        st = st_ref[:, at]
+        sq = _mxu(st)
+        if s_ref:
+            s_ref[0][0, 0, :, at] = sq
+        inside, grown, to_end, kept = [], [], [], []
+        for h in range(blk * per, (blk + 1) * per):
+            a_t, dt_t = col[:, h:h + 1], col[:, r + h:r + h + 1]
+            a_s, dt_s = row[h:h + 1, :], row[r + h:r + h + 1, :]
+            last = a_t[q - 1:q]
+            m = cb * jnp.exp(jnp.where(below, a_t - a_s, _SSD_LOW)) * dt_s
+            inside.append(jnp.dot(_mxu(m), xq, preferred_element_type=f32))
+            grown.append(jnp.exp(a_t))
+            to_end.append(jnp.exp(last - a_t) * dt_t)
+            kept.append(jnp.exp(last))
+        y = _per_head(masks, inside) + \
+            jnp.dot(cq, sq, preferred_element_type=f32) * \
+            _per_head(masks, grown) + d_ref[:, at] * x
+        y_ref[0, :, at] = y.astype(y_ref.dtype)
+        st_ref[:, at] = _per_head(masks, kept) * st + jnp.dot(
+            bt, _mxu(x * _per_head(masks, to_end)),
+            preferred_element_type=f32)
+
+
+def _ssd_bwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, g_ref, s_ref,
+                    d_ref, dx_ref, db_ref, dc_ref, gcol_ref, grow_ref, dd_ref,
+                    dst_ref, *, p):
+    """One chunk of one group, chunks in reverse: dx, the group's db and dc,
+    and per step and head the cotangents of cum and of dt — `gcol` holds
+    (d cum | d dt) with time on sublanes, `grow` the part of d cum that a
+    sum over sublanes leaves with time on lanes; XLA adds the two and takes
+    the reverse cumulative sum; `dd` sums g·x over the chunks, a lane a
+    column.  Tiles are recomputed TRANSPOSED, [s, t], so that dx = mᵀ·g and
+    db = dcbᵀ·c are plain products and the sums over t are sums over lanes;
+    dc = (dcbᵀ)ᵀ·b is the one the MXU transposes."""
+    f32 = jnp.float32
+    q, lanes = x_ref.shape[1:]
+    width, per = _ssd_lane_block(p)
+    r = lanes // p
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dst_ref[...] = jnp.zeros(dst_ref.shape, f32)
+        dd_ref[...] = jnp.zeros(dd_ref.shape, f32)
+
+    b, c = b_ref[0].astype(f32), c_ref[0].astype(f32)
+    bq, cq = _mxu(b), _mxu(c)
+    ct = _mxu(c.T)
+    cbt = jax.lax.dot_general(bq, cq, _NT, preferred_element_type=f32)
+    col, row = col_ref[0, 0], row_ref[0, 0]
+    above = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1) >= \
+        jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)          # t >= s
+    ends = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
+    masks = _head_masks(width, per)
+    dcbt = jnp.zeros((q, q), f32)
+    db = jnp.zeros(b.shape, f32)
+    dc = jnp.zeros(c.shape, f32)
+    d_cum, d_dt, d_cum_row = [], [], []
+    for blk in range(lanes // width):
+        at = slice(blk * width, (blk + 1) * width)
+        x = x_ref[0, :, at].astype(f32)
+        g = g_ref[0, :, at].astype(f32)
+        gq = _mxu(g)
+        sq = s_ref[0, 0, :, at]
+        ds = dst_ref[:, at]
+        dsq = _mxu(ds)
+        inside, direct, dts, grown, to_end, kept = [], [], [], [], [], []
+        for h, keep in zip(range(blk * per, (blk + 1) * per), masks):
+            a_s, dt_s = col[:, h:h + 1], col[:, r + h:r + h + 1]
+            a_t = row[h:h + 1, :]
+            last = a_s[q - 1:q]
+            decay = jnp.exp(jnp.where(above, a_t - a_s, _SSD_LOW))
+            inside.append(jnp.dot(_mxu(cbt * decay * dt_s), gq,
+                                  preferred_element_type=f32))
+            dm = jax.lax.dot_general(_mxu(x, keep), gq, _NT,
+                                     preferred_element_type=f32) * decay
+            dcbt += dm * dt_s
+            z = dm * cbt
+            direct.append(jnp.sum(z, axis=1, keepdims=True))
+            d_cum_row.append(jnp.sum(z * dt_s, axis=0, keepdims=True))
+            dts.append(dt_s)
+            grown.append(jnp.exp(a_s))
+            to_end.append(jnp.exp(last - a_s))
+            kept.append(jnp.exp(last))
+        grown, to_end = _per_head(masks, grown), _per_head(masks, to_end)
+        kept, dt = _per_head(masks, kept), _per_head(masks, dts)
+        # the state leaving the chunk: S' = kept·S + Σ_s to_end_s dt_s x_s b_sᵀ
+        reach = jnp.dot(bq, dsq, preferred_element_type=f32) * to_end
+        left = _head_sums(masks, x * reach)         # d dt_s through S'
+        db += jax.lax.dot_general(_mxu(x * to_end * dt), dsq, _NT,
+                                  preferred_element_type=f32)
+        # the state entering it: y_t += exp(cum_t) S c_t
+        g_grown = g * grown
+        gq_grown = _mxu(g_grown)
+        entered = _head_sums(masks, g_grown * jnp.dot(
+            cq, sq, preferred_element_type=f32))    # d cum_t through exp(cum_t)
+        dc += jax.lax.dot_general(gq_grown, sq, _NT,
+                                  preferred_element_type=f32)
+        held = [jnp.sum(v, axis=0, keepdims=True) for v in
+                _head_sums(masks, ds * sq.astype(f32) * kept)]
+        dst_ref[:, at] = kept * ds + jnp.dot(ct, gq_grown,
+                                             preferred_element_type=f32)
+        dx = _per_head(masks, inside) + reach * dt + d_ref[:, at] * g
+        dx_ref[0, :, at] = dx.astype(dx_ref.dtype)
+        dd_ref[0, :, at] += jnp.sum(g * x, axis=0, keepdims=True)
+        for j in range(per):
+            through = (direct[j] + left[j]) * dts[j]
+            d_cum.append(
+                entered[j] - through + jnp.where(
+                    ends, held[j] + jnp.sum(left[j] * dts[j], axis=0,
+                                            keepdims=True), 0.0))
+            d_dt.append(direct[j] + left[j])
+    db_ref[0] = db + jnp.dot(_mxu(dcbt), cq, preferred_element_type=f32)
+    dc_ref[0] = dc + jax.lax.dot_general(_mxu(dcbt), bq, _TN,
+                                         preferred_element_type=f32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 2 * r), 1)
+    gcol = jnp.zeros((q, 2 * r), f32)
+    for i, v in enumerate(d_cum + d_dt):
+        gcol = jnp.where(lane == i, v, gcol)
+    gcol_ref[0, 0] = gcol
+    sub = jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0)
+    grow = jnp.zeros((r, q), f32)
+    for i, v in enumerate(d_cum_row):
+        grow = jnp.where(sub == i, v, grow)
+    grow_ref[0, 0] = grow
+
+
+def _ssd_call(kernel, name, ins, outs, heads, groups, chunk, reverse):
+    """One kernel over the (B, G, T/chunk) grid, chunk k (or, `reverse`,
+    the k-th from the end) of group g a step.  `ins` and `outs` are
+    (array or shape, kind) pairs: "x" a group's heads (chunk, H/G·P) of a
+    (B, T, H·P) array, "n" its (chunk, N) of a (B, T, G·N) array, "col" /
+    "row" the packed scalars ("grow": the cum half of a "row"), "d" the
+    group's lanes of a (1, H·P) array,
+    "s" the (N, H/G·P) state of a (B, T/chunk, N, H·P) array, "dd" the
+    group's lanes of a (B, 1, H·P) sum over the chunks."""
+    from jax.experimental.pallas import tpu as pltpu
+    bsz, t, hp = ins[0][0].shape
+    n = ins[1][0].shape[2] // groups
+    lanes, r = hp // groups, heads // groups
+    nc = t // chunk
+
+    def at(k):
+        return nc - 1 - k if reverse else k
+
+    specs = {
+        "x": pl.BlockSpec((1, chunk, lanes), lambda b, g, k: (b, at(k), g)),
+        "n": pl.BlockSpec((1, chunk, n), lambda b, g, k: (b, at(k), g)),
+        "col": pl.BlockSpec((1, 1, chunk, 2 * r),
+                            lambda b, g, k: (b, g, at(k), 0)),
+        "row": pl.BlockSpec((1, 1, 2 * r, chunk),
+                            lambda b, g, k: (b, g, 0, at(k))),
+        "grow": pl.BlockSpec((1, 1, r, chunk),
+                             lambda b, g, k: (b, g, 0, at(k))),
+        "d": pl.BlockSpec((1, lanes), lambda b, g, k: (0, g)),
+        "s": pl.BlockSpec((1, 1, n, lanes), lambda b, g, k: (b, at(k), 0, g)),
+        "dd": pl.BlockSpec((1, 1, lanes), lambda b, g, k: (b, 0, g)),
+    }
+    return pl.pallas_call(
+        kernel,
+        out_shape=[a for a, _ in outs],
+        grid=(bsz, groups, nc),
+        in_specs=[specs[kind] for _, kind in ins],
+        out_specs=[specs[kind] for _, kind in outs],
+        scratch_shapes=[pltpu.VMEM((n, lanes), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name=name,
+    )(*(a for a, _ in ins))
+
+
+def _ssd_scalars(dt, a, groups, chunk):
+    """→ col (B, G, T, 2·H/G) = (cum | dt) and row, its transpose: cum the
+    inclusive cumulative sum of dt·a inside each chunk, float32."""
+    bsz, t, h = dt.shape
+    dt = dt.astype(jnp.float32)
+    cum = jnp.cumsum((dt * a.astype(jnp.float32))
+                     .reshape(bsz, t // chunk, chunk, h), axis=2)
+    col = jnp.concatenate([cum.reshape(bsz, t, groups, h // groups),
+                           dt.reshape(bsz, t, groups, h // groups)], axis=-1)
+    col = col.transpose(0, 2, 1, 3)
+    return col, col.transpose(0, 1, 3, 2)
+
+
+def _ssd_d_lanes(d, heads, p):
+    """d (H,) → (1, H·P), a head's value on each of its lanes (no d: 0)."""
+    d = jnp.zeros((heads,), jnp.float32) if d is None else d
+    return jnp.repeat(d.astype(jnp.float32), p)[None]
+
+
+def _ssd_fwd_pallas(x, dt, a, b, c, d, heads, groups, chunk, emit=False):
+    """→ [y (B, T, H·P)], with `emit` also the bfloat16 states entering
+    the chunks (B, T/chunk, N, H·P), and the packed scalars."""
+    bsz, t, hp = x.shape
+    n = b.shape[2] // groups
+    _count("hits", "ssd", n)
+    col, row = _ssd_scalars(dt, a, groups, chunk)
+    ins = [(x, "x"), (b, "n"), (c, "n"), (col, "col"), (row, "row"),
+           (_ssd_d_lanes(d, heads, hp // heads), "d")]
+    outs = [(jax.ShapeDtypeStruct(x.shape, x.dtype), "x")]
+    if emit:
+        outs.append((jax.ShapeDtypeStruct((bsz, t // chunk, n, hp),
+                                          jnp.bfloat16), "s"))
+    return _ssd_call(
+        functools.partial(_ssd_fwd_kernel, p=hp // heads), "mx_ssd_fwd", ins, outs, heads, groups, chunk, False), col, row
+
+
+def _ssd_bwd_pallas(x, b, c, d, col, row, states, g, heads, groups, chunk):
+    """→ dx, db, dc (float32: sums over a group's heads), gcol, grow and
+    the per-lane partial sums (B, 1, H·P) of d's cotangent."""
+    bsz, t, hp = x.shape
+    f32 = jnp.float32
+    ins = [(x, "x"), (b, "n"), (c, "n"), (col, "col"), (row, "row"),
+           (g, "x"), (states, "s"),
+           (_ssd_d_lanes(d, heads, hp // heads), "d")]
+    outs = [(jax.ShapeDtypeStruct(x.shape, x.dtype), "x"),
+            (jax.ShapeDtypeStruct(b.shape, f32), "n"),
+            (jax.ShapeDtypeStruct(c.shape, f32), "n"),
+            (jax.ShapeDtypeStruct(col.shape, f32), "col"),
+            (jax.ShapeDtypeStruct(row.shape[:2] + (heads // groups, t), f32),
+             "grow"),
+            (jax.ShapeDtypeStruct((bsz, 1, hp), f32), "dd")]
+    return _ssd_call(
+        functools.partial(_ssd_bwd_kernel, p=hp // heads), "mx_ssd_bwd", ins, outs, heads, groups, chunk, True)
+
+
+def ssd_use_pallas(t, heads, groups, p, n, chunk):
+    """The routing decision of `nn.ssd_chunked`: one TPU (or the tests'
+    interpret switch), chunks of `_SSD_CHUNK` steps dividing T, a state
+    size in lane tiles, whole groups whose heads fill 128-lane blocks (a
+    head one or more blocks, or a whole share of one) and fit
+    `_SSD_MAX_LANES`.  A "no" counts one fallback; the "yes" is counted
+    where the forward kernel is emitted."""
+    lanes = heads // groups * p
+    ok = (_FORCE_INTERPRET or _pb.one_tpu()) and chunk == _SSD_CHUNK and \
+        t % chunk == 0 and n % 128 == 0 and heads % groups == 0 and \
+        lanes % 128 == 0 and lanes <= _SSD_MAX_LANES and \
+        (p % 128 == 0 or 128 % p == 0)
+    if not ok:
+        _count("fallbacks", "ssd", n)
+    return ok
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def ssd_fused(x, dt, a, b, c, d, heads, groups, chunk=_SSD_CHUNK):
+    """Mamba-2's scan of `heads` heads in `groups` groups, read in place
+    from the mixer's arrays: x (B, T, H·P), dt (B, T, H), a (H,), b and c
+    (B, T, G·N), d (H,) or None → y (B, T, H·P).  `chunk` other than
+    `_SSD_CHUNK` is the tests'."""
+    return _ssd_fwd_pallas(x, dt, a, b, c, d, heads, groups, chunk)[0][0]
+
+
+def _ssd_vjp_fwd(x, dt, a, b, c, d, heads, groups, chunk):
+    (y, states), col, row = _ssd_fwd_pallas(x, dt, a, b, c, d, heads, groups,
+                                            chunk, emit=True)
+    return y, (x, dt, a, b, c, d, col, row, states)
+
+
+def _ssd_vjp_bwd(heads, groups, chunk, res, g):
+    x, dt, a, b, c, d, col, row, states = res
+    dx, db, dc, gcol, grow, dd = _ssd_bwd_pallas(
+        x, b, c, d, col, row, states, g, heads, groups, chunk)
+    bsz, t, h = dt.shape
+    r = h // groups
+    # cum is an inclusive sum of dt·a inside a chunk: its cotangent reaches
+    # dt_t·a from every later step of the chunk
+    d_cum = (gcol[..., :r] + grow.transpose(0, 1, 3, 2)) \
+        .transpose(0, 2, 1, 3).reshape(bsz, t // chunk, chunk, h)
+    d_dta = jnp.flip(jnp.cumsum(jnp.flip(d_cum, 2), axis=2), 2) \
+        .reshape(bsz, t, h)
+    d_dt = gcol[..., r:].transpose(0, 2, 1, 3).reshape(bsz, t, h) + \
+        d_dta * a.astype(jnp.float32)
+    d_a = jnp.sum(d_dta * dt.astype(jnp.float32), axis=(0, 1))
+    d_d = None if d is None else \
+        dd.reshape(bsz, h, -1).sum(axis=(0, 2)).astype(d.dtype)
+    return (dx, d_dt.astype(dt.dtype), d_a.astype(a.dtype),
+            db.astype(b.dtype), dc.astype(c.dtype), d_d)
+
+
+ssd_fused.defvjp(_ssd_vjp_fwd, _ssd_vjp_bwd)

@@ -140,6 +140,36 @@ def test_causal_gqa_attention_fwd_and_grad_at_the_cell_shape(one_chip,
     no_tile(text)
 
 
+def test_ssd_fwd_and_grad_at_the_cell_shape(one_chip, compiled_kernels):
+    """What `nemotron3-nano-train-8k` runs in each of its four Mamba-2
+    layers: 64 heads of 64 in 8 groups, state 128, T = 8192 in chunks of
+    128, routed from `ops/nn.py::ssd_chunked`.  The program holds the named
+    kernels under the default scoped VMEM limit and none of the
+    composition's (…, 128, 128) decay tiles or (…, 64, 128) chunk states
+    in float32."""
+    from mxnet_tpu.ops import nn
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    args = (arg(1, 8192, 64, 64), arg(1, 8192, 64), arg(64),
+            arg(1, 8192, 8, 128), arg(1, 8192, 8, 128), arg(64))
+    assert pallas_kernels.ssd_use_pallas(8192, 64, 8, 64, 128, 128)
+
+    def no_tile(text):
+        for shape in ("f32[1,64,8,8,128,128]", "f32[1,64,8,8,64,128]"):
+            assert shape not in text, f"{shape} exists outside the kernels"
+
+    text = _compile(nn.ssd_chunked, *args)
+    assert "mx_ssd_fwd" in text
+    no_tile(text)
+    text = _compile(jax.grad(lambda *a: jnp.sum(nn.ssd_chunked(*a) ** 2),
+                             argnums=tuple(range(6))), *args)
+    assert "mx_ssd_fwd" in text and "mx_ssd_bwd" in text
+    assert text.count("tpu_custom_call") >= 2
+    no_tile(text)
+
+
 def _stage_shape(stage, n=128):
     h, w, c = (int(t) for t in stage.split("x"))
     return (n, h, w, c)

@@ -8,6 +8,7 @@ another worker takes them)."""
 import importlib.util
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +113,8 @@ def test_fused_step_loss_is_the_reference_loss_and_takes_the_blocked_route():
                       "dispatch.attention.causal.xla_blocked": 1,
                       # head_dim 8 is no lane tile: the kernel says no
                       "dispatch.pallas.fallbacks.causal_attention.8": 1,
+                      # and so does the scan's for a state of 8
+                      "dispatch.pallas.fallbacks.ssd.8": 4,
                       "dispatch.loss.linear_blocked": 1}
 
 
@@ -155,6 +158,79 @@ def test_hlo_text_names_the_blocks_scopes():
                   "moe.route", "moe.experts", "moe.shared", "layers/2/",
                   "attn.core", "mx.loss", "mx.opt"):
         assert scope in text, scope
+
+
+# ------------------------------------------- the scan's kernels in the step
+# sizes the route takes (`pallas_kernels.ssd_use_pallas`): chunks of 128
+# steps, state 128, a group's two heads of 64 one 128-lane block
+SCAN = dict(mamba_num_heads=4, mamba_head_dim=64, n_groups=2,
+            ssm_state_size=128, chunk_size=128)
+
+
+def _scan_model(pattern):
+    mx.seed(5)
+    net = nemotron_h_tiny(pattern, **SCAN)
+    net.initialize()
+    net.hybridize()
+    ids = onp.random.RandomState(5).randint(0, 64, (1, 129)).astype("int32")
+    return net, mx.np.array(ids[:, :-1]), mx.np.array(ids[:, 1:])
+
+
+def _loss_and_grads(pattern):
+    net, x, y = _scan_model(pattern)
+    with autograd.record():
+        l = SoftmaxCrossEntropyLoss()(net(x), y)
+    l.backward()
+    return float(l.mean().asnumpy()), {
+        n: p.grad()._data for n, p in net.collect_params().items()
+        if p.grad_req != "null"}
+
+
+def test_on_one_tpu_every_mamba_layer_takes_the_scan_kernels(monkeypatch):
+    """Under the interpret switch (what `one_tpu()` is on the chip) a step
+    counts the forward kernel twice a Mamba-2 layer (its forward and its
+    recomputation, each traced once), the composition not at all, and the
+    compiled program names both kernels under the layer's `ssm.scan` scope:
+    what keeps `ssm_ms.train` reading their time."""
+    from mxnet_tpu.ops import pallas_kernels
+    monkeypatch.setattr(pallas_kernels, "_FORCE_INTERPRET", True)
+    net, x, y = _scan_model("MEMEM*EME")
+    step = Trainer(net.collect_params(), "adam",
+                   {"learning_rate": 1e-3}).fuse_step(SoftmaxCrossEntropyLoss())
+    c0 = dict(telemetry.raw_snapshot()["counters"])
+    first = float(step(x, y).asnumpy())
+    c1 = dict(telemetry.raw_snapshot()["counters"])
+    assert onp.isfinite(first)
+    routes = {k: c1[k] - c0.get(k, 0) for k in c1
+              if (".ssd." in k or ".ssm." in k) and c1[k] != c0.get(k, 0)}
+    assert routes == {"dispatch.pallas.hits.ssd.128": 8}
+    text = step.hlo_text(x, y)
+    # …/layers/0/mixer/ssm.scan/mx_ssd_fwd, and in the backward
+    # …/layers/0/checkpoint/[rematted_computation/]mixer/ssm.scan/mx_ssd_*
+    for i in (0, 2, 4, 7):
+        for kernel in ("mx_ssd_fwd", "mx_ssd_bwd"):
+            assert re.search(rf'op_name="[^"]*layers/{i}/[^"]*mixer/'
+                             rf'ssm\.scan/{kernel}', text), (i, kernel)
+
+
+def test_the_kernel_route_gives_the_composition_s_loss_and_gradients(
+        monkeypatch):
+    """Two Mamba-2 layers around an expert layer, the same seeded weights
+    on both routes.  The kernels round their MXU operands to bfloat16 as
+    the chip's default precision does; the CPU's composition keeps
+    float32."""
+    from mxnet_tpu.ops import pallas_kernels
+    want_loss, want = _loss_and_grads("MEM")
+    monkeypatch.setattr(pallas_kernels, "_FORCE_INTERPRET", True)
+    telemetry.reset()
+    got_loss, got = _loss_and_grads("MEM")
+    counters = telemetry.raw_snapshot()["counters"]
+    assert counters["dispatch.pallas.hits.ssd.128"] >= 2
+    assert not counters.get("dispatch.ssm.xla_chunked")
+    assert abs(got_loss - want_loss) < 1e-3 * abs(want_loss)
+    assert set(got) == set(want)
+    for name in want:
+        assert _err(got[name], want[name]) < 3e-2, name
 
 
 # --------------------------------------------------------------- the builder

@@ -148,6 +148,11 @@ _QC = jnp.ones((1, 128, 2 * 128), jnp.float32)  # (B, T, Hq·hd), two heads
 _KC = jnp.ones((1, 128, 128), jnp.float32)      # their one key-value head
 _LSE = jnp.ones((1, 2, 1, 128), jnp.float32)    # (B, Hq, 1, T) row statistics
 
+_DT = jnp.ones((1, 128, 2), jnp.float32)        # (B, T, H): two heads of 64
+_A = -jnp.ones((2,), jnp.float32)
+_SSD_COL = jnp.ones((1, 1, 128, 4), jnp.float32)    # (B, G, T, cum | dt)
+_SSD_STATES = jnp.ones((1, 1, 128, 128), jnp.bfloat16)  # (B, T/128, N, H·P)
+
 KERNEL_SITES = {
     "block.conv3x3": (lambda: pallas_block.conv3x3(_X, _W),
                       ["mx_block_conv"]),
@@ -187,6 +192,15 @@ KERNEL_SITES = {
     "int8.qconv3x3": (
         lambda: pallas_int8.qconv3x3_affine(_Q8, _W.astype(jnp.int8), _C, _C),
         ["mx_qconv3x3"]),
+    "kernels.ssd": (
+        lambda: pallas_kernels._ssd_fwd_pallas(_KC, _DT, _A, _KC, _KC, _A, 2,
+                                               1, 128, emit=True),
+        ["mx_ssd_fwd"]),
+    "kernels.ssd_bwd": (
+        lambda: pallas_kernels._ssd_bwd_pallas(
+            _KC, _KC, _KC, _A, _SSD_COL, _SSD_COL.transpose(0, 1, 3, 2),
+            _SSD_STATES, _KC, 2, 1, 128),
+        ["mx_ssd_bwd"]),
 }
 
 
@@ -215,6 +229,10 @@ ROUTED = {
     "causal_attention": (lambda q: ops_nn.causal_gqa_attention(
         q, q[:, :, :1], q[:, :, :1]).sum(), _QKV.transpose(0, 2, 1, 3),
         "hits.causal_attention.128"),
+    # b and c (B, T, G, N) of two heads of 64 through ops/nn.py
+    "ssd": (lambda bc: ops_nn.ssd_chunked(
+        jnp.ones((1, 128, 2, 64)), _DT, _A, bc, bc).sum(),
+        _QKV[:, :1].transpose(0, 2, 1, 3), "hits.ssd.128"),
 }
 
 
