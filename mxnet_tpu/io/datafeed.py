@@ -73,6 +73,11 @@ class DataFeed:
         native loader's layout); ``"NHWC"`` adds a device-side
         transpose so DataFeed can sit behind the NHWC ImageRecordIter
         contract.
+
+    Per-channel ``mean`` / ``std`` of C entries find their axis in a 4-D
+    batch by its length: axis 1 (NCHW, also when both fit), else the last
+    one - a loader that hands decoded channel-last batches over - and such
+    a batch is left in its layout.
     """
 
     def __init__(self, source, depth=None, device=None, mean=None,
@@ -215,11 +220,18 @@ class DataFeed:
         import jax
         import jax.numpy as jnp
         norm, layout = self._norm, self._layout
-        ndim = key[2]
+        ndim, shape = key[2], key[3]
+        # where (C,) constants find C channels: axis 1 unless only the last
+        # axis has that length (a channel-last source)
+        per_channel = [v.shape[0] for v in (norm or {}).values()
+                       if getattr(v, "ndim", 0) == 1]
+        channel_last = ndim == 4 and bool(per_channel) and all(
+            c != shape[1] and c == shape[-1] for c in per_channel)
 
         def _norm_shape(v):
-            # per-channel constants broadcast over NCHW: (C,) → (C,1,1)
-            if v is None or v.ndim == 0 or ndim != 4:
+            # per-channel constants broadcast over NCHW: (C,) → (C,1,1);
+            # over a channel-last source they broadcast as they are
+            if v is None or v.ndim == 0 or ndim != 4 or channel_last:
                 return v
             return v.reshape(v.shape[0], *([1] * (ndim - 2)))
 
@@ -235,7 +247,7 @@ class DataFeed:
                 y = y - mean
             if std is not None:
                 y = y / std
-            if layout == "NHWC" and y.ndim == 4:
+            if layout == "NHWC" and y.ndim == 4 and not channel_last:
                 y = jnp.transpose(y, (0, 2, 3, 1))
             return y
 
@@ -367,6 +379,7 @@ class DataFeed:
                 staged = self._stage(item)
             _telemetry.observe("datafeed.wait_us",
                                (time.perf_counter() - t0) * 1e6)
+            self._wait_resident(staged)
             with self._lock:
                 self._stats["consumed"] += 1
             return staged
@@ -390,9 +403,34 @@ class DataFeed:
             if err is not None:
                 raise err
             raise StopIteration
+        self._wait_resident(item)
         with self._lock:
             self._stats["consumed"] += 1
         return item
+
+    def _wait_resident(self, item):
+        """A batch is staged once its copy is *enqueued*; it is handed over
+        once the copy (and the cast/normalise program behind it) has
+        landed.  What that takes is the loop's wait for the host link and
+        counts as such: otherwise it would show only as device idle time in
+        the step that reads the batch.  A batch that has landed (the fast
+        path) records nothing."""
+        from . import DataBatch
+        from ..ndarray import NDArray
+        arrays = (item.data + (item.label or []) if isinstance(item, DataBatch)
+                  else item if isinstance(item, (tuple, list)) else [item])
+        late = [a._data for a in arrays if isinstance(a, NDArray)
+                and not a._data.is_ready()]
+        if not late:
+            return
+        import jax
+        t0 = time.perf_counter()
+        with _telemetry.span("datafeed.wait", mode="copy"):
+            jax.block_until_ready(late)
+        waited = time.perf_counter() - t0
+        _telemetry.observe("datafeed.wait_us", waited * 1e6)
+        with self._lock:
+            self._stats["consumer_wait_s"] += waited
 
     next = __next__
 

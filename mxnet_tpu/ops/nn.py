@@ -1048,27 +1048,47 @@ def causal_gqa_attention(q, k, v, q_block=512, k_block=1024):
 def linear_cross_entropy(h, weight, labels, block=1024):
     """``-log softmax(h @ weight.T)[labels]`` per row of ``h`` without the
     whole product: rows are taken ``block`` at a time and each block's
-    logits are recomputed in the backward pass, so HBM never holds more
-    than (block, V) of them.  ``h`` (..., D), ``weight`` (V, D), ``labels``
-    (...,) -> (...,) float32."""
+    logits are made again in the backward pass, so HBM never holds more
+    than (block, V) of them.  The backward pass keeps each row's
+    log-sum-exp from the forward one (so the forward loop runs first and
+    nothing but the weight as it was is read by either).  ``h`` (..., D),
+    ``weight`` (V, D), ``labels`` (...,) -> (...,) float32."""
     _count_route("loss.linear_blocked")
     lead = labels.shape
     h2 = h.reshape(-1, h.shape[-1])
-    y2 = labels.reshape(-1)
+    y2 = labels.reshape(-1).astype(jnp.int32)
     n = h2.shape[0]
     blk = block if n % block == 0 else n
 
-    @jax.checkpoint
-    def rows(args):
-        h_b, y_b = args
-        z = jnp.matmul(h_b, weight.T, preferred_element_type=jnp.float32)
+    def logits(h_b, w):
+        return jnp.matmul(h_b, w.T, preferred_element_type=jnp.float32)
+
+    def forward(h_b, w, y_b):
+        z = logits(h_b, w)
         m = jnp.max(z, axis=-1)
         lse = m + jnp.log(jnp.sum(jnp.exp(z - m[:, None]), axis=-1))
-        return lse - jnp.take_along_axis(
-            z, y_b[:, None].astype(jnp.int32), axis=-1)[:, 0]
+        return lse - jnp.take_along_axis(z, y_b[:, None], axis=-1)[:, 0], lse
 
-    out = lax.map(rows, (h2.reshape(n // blk, blk, -1),
-                         y2.reshape(n // blk, blk)))
+    def backward(res, g):
+        h_b, w, y_b, lse = res
+        p = jnp.exp(logits(h_b, w) - lse[:, None])
+        hot = y_b[:, None] == jnp.arange(p.shape[-1], dtype=jnp.int32)
+        dz = (g[:, None] * (p - hot)).astype(h_b.dtype)
+        return (jnp.matmul(dz, w).astype(h_b.dtype),
+                jnp.matmul(dz.T, h_b).astype(w.dtype), None)
+
+    @jax.custom_vjp
+    def rows(h_b, w, y_b):
+        return forward(h_b, w, y_b)[0]
+
+    def rows_fwd(h_b, w, y_b):
+        out, lse = forward(h_b, w, y_b)
+        return out, (h_b, w, y_b, lse)
+
+    rows.defvjp(rows_fwd, backward)
+
+    out = lax.map(lambda b: rows(b[0], weight, b[1]),
+                  (h2.reshape(n // blk, blk, -1), y2.reshape(n // blk, blk)))
     return out.reshape(lead)
 
 
@@ -1091,3 +1111,118 @@ def claim_product(product):
     if held is not None and held[0] is product:
         return held[1], held[2]
     return None
+
+
+# ------------------------------------------ gated delta rule (KDA), SwiGLU
+# Kimi Linear's gated delta-rule linear attention (arXiv:2510.26692) and the
+# gated expert activation of models/solar_open2.py, appended after every
+# line a kernel of another model is traced through.
+def swiglu(h):
+    """``silu(gate) * up`` of a fused product ``h = x [W_gate | W_up]``:
+    the last axis holds the gate half, then the up half."""
+    gate, up = jnp.split(h, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+_ACTIVATIONS["swiglu"] = swiglu
+
+KDA_SUB = 16      # rows of a chunk whose pairwise decays are made explicitly
+
+
+def kda_chunked(q, k, v, g, beta, chunk=64):
+    """Kimi delta attention by chunks: per head, with a state ``S`` (dk, dv)
+    that starts at zero, ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t))
+    S_{t-1} + beta_t k_t v_t^T`` and ``o_t = S_t^T q_t``.
+
+    ``q`` and ``k`` (B, T, H, dk), ``v`` (B, T, H, dv), ``g`` (B, T, H, dk)
+    the log-decay of every channel (not positive), ``beta`` (B, T, H) ->
+    (B, T, H, dv).  The caller normalises and scales ``q`` and ``k``.
+
+    With ``u_t = beta_t (v_t - (Diag(exp g_t) S_{t-1})^T k_t)`` the update
+    is ``S_t = Diag(exp g_t) S_{t-1} + k_t u_t^T``, so inside a chunk of
+    ``chunk`` steps (``G`` the inclusive running sum of ``g``, ``S_0`` the
+    entering state) the ``u`` solve the unit lower triangular system
+    ``(I + A) U = beta (V - (K exp G) S_0)`` with ``A[t, s] = beta_t sum_c
+    k_t[c] k_s[c] exp(G_t[c] - G_s[c])`` for s < t (the WY / UT form), and
+    ``O = (Q exp G) S_0 + A_qk U`` with the same pairwise decays between
+    ``q_t`` and ``k_s``, s <= t.  ``(I + A)^{-1}`` is applied to ``beta V``
+    and ``beta K exp G`` for all chunks at once (one triangular solve);
+    only ``U = U~ - W S_0``, the output and the state's update run in a
+    ``lax.scan`` over the chunks.
+
+    The decay is per channel, so ``A`` is no product of two factors that
+    stay finite (``exp(-G)`` overflows under a strong decay).  As
+    ``fla``'s ``chunk_kda`` does, a chunk is cut in sub-blocks of
+    ``KDA_SUB`` rows: between two sub-blocks both factors are taken from
+    the later one's first row, where each exponent is <= 0; inside a
+    sub-block the (KDA_SUB, KDA_SUB, dk) decays are made one by one.  No
+    exponent is ever positive.  The largest temporary is (B, H, T, KDA_SUB,
+    dk), linear in T.  A ``T`` that ``chunk`` does not divide is padded with
+    steps that change nothing (``k = 0``, ``beta = 0``, ``g = 0``) and cut
+    back; a ``chunk`` that ``KDA_SUB`` does not divide is one sub-block."""
+    _count_route("kda.xla_chunked")
+    B, T, H, dk = k.shape
+    dv = v.shape[-1]
+    f32, out_dtype = jnp.float32, v.dtype
+    pad = -T % chunk
+    if pad:
+        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    nc = (T + pad) // chunk
+    sub = KDA_SUB if chunk % KDA_SUB == 0 else chunk
+    n = chunk // sub
+
+    def chunks(a):                      # (B, T, H, X) -> (B, H, nc, chunk, X)
+        return a.astype(f32).reshape(B, nc, chunk, H, -1) \
+            .transpose(0, 3, 1, 2, 4)
+    q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
+    beta = chunks(beta[..., None])                        # (B,H,nc,chunk,1)
+    G = jnp.cumsum(g, axis=3)
+    blocks = lambda a: a.reshape(B, H, nc, n, sub, a.shape[-1])
+    Gs, ks = blocks(G), blocks(k)
+    rows = jnp.stack([ks, blocks(q)])                     # (2,B,H,nc,n,sub,dk)
+    # a later sub-block i against an earlier one j, both factors taken from
+    # the first row of i
+    first = Gs[..., :1, :]                                # (B,H,nc,n,1,dk)
+    earlier = jnp.tril(jnp.ones((n, n), bool), -1)[:, :, None, None]
+    cols = ks[:, :, :, None] * jnp.exp(jnp.where(
+        earlier, first[:, :, :, :, None] - Gs[:, :, :, None], -jnp.inf))
+    pair = jnp.einsum("xbhcitd,bhcijsd->xbhcitjs",
+                      rows * jnp.exp(Gs - first), cols,
+                      preferred_element_type=f32)
+    # inside a sub-block, s <= t: every channel's decay by itself
+    upto = jnp.tril(jnp.ones((sub, sub), bool))[:, :, None]
+    decay = jnp.exp(jnp.where(
+        upto, Gs[..., :, None, :] - Gs[..., None, :, :], -jnp.inf))
+    own = jnp.einsum("xbhcitd,bhcitsd->xbhcits", rows,
+                     decay * ks[..., None, :, :],
+                     preferred_element_type=f32)
+    pair = pair + own[..., None, :] * jnp.eye(n, dtype=f32)[:, None, :, None]
+    a_kk, a_qk = pair.reshape(2, B, H, nc, chunk, chunk)
+    a_kk = beta * jnp.tril(a_kk, -1)
+    eg = jnp.exp(G)
+    solved = lax.linalg.triangular_solve(
+        a_kk, beta * jnp.concatenate([v, k * eg], axis=-1), left_side=True,
+        lower=True, unit_diagonal=True)
+    u0, w = solved[..., :dv], solved[..., dv:]
+    g_end = eg[..., -1, :]                                # (B,H,nc,dk)
+    k_end = k * jnp.exp(G[..., -1:, :] - G)
+
+    def step(S, xs):
+        u0, w, qg, a_qk, k_end, g_end = xs
+        u = u0 - jnp.einsum("bhtk,bhkv->bhtv", w, S,
+                            preferred_element_type=f32)
+        o = jnp.einsum("bhtk,bhkv->bhtv", qg, S, preferred_element_type=f32) \
+            + jnp.einsum("bhts,bhsv->bhtv", a_qk, u,
+                         preferred_element_type=f32)
+        S = g_end[..., None] * S + jnp.einsum(
+            "bhtk,bhtv->bhkv", k_end, u, preferred_element_type=f32)
+        return S, o
+
+    by_chunk = lambda a: jnp.moveaxis(a, 2, 0)
+    _, o = lax.scan(step, jnp.zeros((B, H, dk, dv), f32),
+                    tuple(by_chunk(a) for a in
+                          (u0, w, q * eg, a_qk, k_end, g_end)))
+    o = o.transpose(1, 0, 3, 2, 4).reshape(B, T + pad, H, dv)
+    return o[:, :T].astype(out_dtype)

@@ -185,34 +185,32 @@ def moe_topk_held(x, router_w, bias, up, down, held, top_k, scaling=1.0,
             pos = jnp.where(live, start + j, 0)
             tok = jnp.where(live, token[pos], S)
             w = jnp.where(live, w_sorted[pos], 0)
-
-        def product(e_up, e_down, tok, w):
-            with jax.named_scope("moe.dispatch"):
-                xs = x.at[tok].get(mode="fill", fill_value=0)
-            with jax.named_scope("moe.experts"):
-                h = act(jnp.matmul(xs, e_up))
-                return jnp.matmul(h.astype(x.dtype), e_down) * w[:, None]
-        # recomputed in the backward pass: one slot's rows live at a time
-        out = jax.checkpoint(product)(e_up, e_down, tok, w)
+            xs = x.at[tok].get(mode="fill", fill_value=0)
+        with jax.named_scope("moe.experts"):
+            h = act(jnp.matmul(xs, e_up))
+            out = jnp.matmul(h.astype(x.dtype), e_down) * w[:, None]
         with jax.named_scope("moe.combine"):
             return y.at[tok].add(out.astype(y.dtype), mode="drop")
 
-    def further(e_up, e_down, size, start):
-        """What an expert's rows past its first slot add."""
+    def expert(y, e_up, e_down, size, start):
+        """``y`` and what one expert adds: its first slot always, a further
+        one while it has rows."""
         def more(y, r):
             return lax.cond(r * rows < size, slot, lambda y, *_: y,
-                            y, e_up, e_down, size, start, r), None
-        return lax.scan(more, jnp.zeros_like(x),
-                        jnp.arange(1, -(-S // rows), dtype=jnp.int32))[0]
+                            y, e_up, e_down, size, start, r)
 
-    def expert(y, held_e):
-        e_up, e_down, size, start = held_e
-        y = slot(y, e_up, e_down, size, start, 0)
-        # the backward pass keeps the expert's weights, not each slot's copy
-        y = lax.cond(rows < size,
-                     lambda y: y + jax.checkpoint(further)(*held_e),
-                     lambda y: y, y)
-        return y, None
+        def further(y):
+            return lax.scan(lambda y, r: (jax.checkpoint(more)(y, r), None),
+                            y, jnp.arange(1, -(-S // rows), dtype=jnp.int32))[0]
 
-    y, _ = lax.scan(expert, jnp.zeros_like(x), (up, down, sizes, starts))
+        return lax.cond(rows < size, further, lambda y: y,
+                        slot(y, e_up, e_down, size, start, 0))
+
+    # Recomputed in the backward pass an expert at a time, and inside it a
+    # further slot at a time: one slot's rows live at once, and what a
+    # ``cond`` keeps for its branch (x, the expert's weights) is kept for
+    # one expert, not stacked by the scan around it.
+    y, _ = lax.scan(lambda y, held_e: (jax.checkpoint(expert)(y, *held_e),
+                                       None),
+                    jnp.zeros_like(x), (up, down, sizes, starts))
     return y, load

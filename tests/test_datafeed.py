@@ -268,3 +268,90 @@ def test_native_decode_worker_scaling(tmp_path):
 
     r1, r2 = epoch_rate(1), epoch_rate(2)
     assert r2 >= 1.6 * r1, f"2w={r2:.0f}/s vs 1w={r1:.0f}/s"
+
+
+# ------------------------------------------------ channel-last sources (PR 34)
+@pytest.mark.parametrize("depth", [0, 2])
+def test_channel_last_source_is_normalised_over_its_last_axis(depth):
+    """uint8 NHWC batches as a decoded-image loader hands them over:
+    the per-channel constants find the last axis by its length and nothing
+    is transposed; labels pass through as they are."""
+    from mxnet_tpu.io import DataFeed
+    rs = onp.random.RandomState(3)
+    batches = [(rs.randint(0, 256, (2, 6, 5, 3), dtype=onp.uint8),
+                rs.randint(0, 10, (2,), dtype=onp.int32)) for _ in range(3)]
+    mean, std = [0.485, 0.456, 0.406], [0.229, 0.224, 0.225]
+    with DataFeed(iter(batches), depth=depth, scale=1 / 255, mean=mean,
+                  std=std) as feed:
+        got = list(feed)
+        stats = feed.stats()
+    assert len(got) == 3
+    for (x, y), (gx, gy) in zip(batches, got):
+        want = (x.astype("float32") / 255 - onp.asarray(mean, "float32")) \
+            / onp.asarray(std, "float32")
+        assert gx.shape == (2, 6, 5, 3) and str(gx.dtype) == "float32"
+        assert onp.allclose(gx.asnumpy(), want, atol=1e-5)
+        assert str(gy.dtype) == "int32" and (gy.asnumpy() == y).all()
+    # the wire carried uint8 images and int32 labels, nothing wider
+    assert stats["h2d_bytes"] == 3 * (2 * 6 * 5 * 3 + 2 * 4)
+
+
+def test_per_channel_constants_find_their_axis_by_its_length():
+    from mxnet_tpu.io import DataFeed
+    # the default wire is untouched: (C,) constants over NCHW, also when the
+    # last axis has C entries too
+    for shape in ((1, 3, 2, 2), (1, 3, 2, 3)):
+        x = onp.full(shape, 255, onp.uint8)
+        with DataFeed(iter([(x, onp.zeros((1,), onp.int32))]), depth=0,
+                      scale=1 / 255, mean=[0.0, 0.5, 1.0]) as feed:
+            gx, _ = next(feed)
+        assert gx.shape == shape
+        assert onp.allclose(gx.asnumpy()[0, :, 0, 0], [1.0, 0.5, 0.0],
+                            atol=1e-6)
+    # channel-last, and asked for NHWC: it is NHWC already
+    x = onp.full((1, 2, 2, 3), 255, onp.uint8)
+    with DataFeed(iter([(x, onp.zeros((1,), onp.int32))]), depth=0,
+                  scale=1 / 255, mean=[0.0, 0.5, 1.0], layout="NHWC") as feed:
+        gx, _ = next(feed)
+    assert gx.shape == (1, 2, 2, 3)
+    assert onp.allclose(gx.asnumpy()[0, 0, 0], [1.0, 0.5, 0.0], atol=1e-6)
+    # constants that fit neither axis are refused when the batch is staged
+    with DataFeed(iter([(onp.zeros((1, 2, 2, 4), onp.uint8),
+                         onp.zeros((1,), onp.int32))]), depth=0,
+                  mean=[0.0, 0.5, 1.0]) as feed:
+        with pytest.raises((TypeError, ValueError)):
+            next(feed)
+
+
+def test_a_batch_is_handed_over_once_its_copy_has_landed(monkeypatch):
+    """A staged batch whose copy is still in flight is waited for inside a
+    ``datafeed.wait`` span and the time counts in ``consumer_wait_s``; one
+    that has landed records nothing (the fast path)."""
+    from mxnet_tpu.io import DataFeed
+    from mxnet_tpu.ndarray import NDArray
+
+    class InFlight:
+        def __init__(self, lands_after):
+            self.lands_after, self.blocked = lands_after, 0
+
+        def is_ready(self):
+            return self.lands_after == 0
+
+        def block_until_ready(self):
+            self.blocked += 1
+            time.sleep(self.lands_after)
+            self.lands_after = 0
+            return self
+
+    late, landed = InFlight(0.03), InFlight(0)
+    x = onp.zeros((2, 3), onp.float32)
+    with DataFeed(iter([(x, x)] * 2), depth=0) as feed:
+        monkeypatch.setattr(
+            feed, "_stage", lambda item: (NDArray(late), NDArray(landed)))
+        next(feed)
+        waited = feed.stats()["consumer_wait_s"]
+        assert late.blocked == 1 and landed.blocked == 0
+        assert waited >= 0.03
+        next(feed)                     # both have landed now: nothing more
+        assert late.blocked == 1
+        assert feed.stats()["consumer_wait_s"] == waited

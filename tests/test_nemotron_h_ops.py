@@ -275,3 +275,66 @@ def test_the_work_of_the_routed_experts_does_not_follow_the_routing():
         assert int(load.sum()) == 48 * 3
     hlo = fn.lower(rw, jnp.zeros(16)).compile().as_text()
     assert "ragged" not in hlo and "f32[32,16]" in hlo
+
+
+def _shapes_of(jaxpr, found=None):
+    """Every array shape a (closed) jaxpr makes, inner jaxprs included."""
+    found = set() if found is None else found
+    for eqn in jaxpr.eqns:
+        found.update(tuple(v.aval.shape) for v in eqn.outvars
+                     if hasattr(v.aval, "shape"))
+        for p in eqn.params.values():
+            for q in (p if isinstance(p, (tuple, list)) else (p,)):
+                inner = getattr(q, "jaxpr", q)
+                if hasattr(inner, "eqns"):
+                    _shapes_of(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("slot_rows", [16, 48])
+def test_the_backward_pass_stacks_no_copy_of_x_over_the_held_experts(
+        slot_rows):
+    """What a ``cond`` keeps for its branch (x, an expert's weights) lives
+    for one expert, and for one further slot, at a time: no array of
+    (experts held, tokens, width) or (further slots, tokens, width), and no
+    per-slot stack of an expert's weights, in the gradient's program - they
+    were 1.6 GB of a step at 8192 tokens (PERF.md section 4, PR 34).  The
+    gradients are those of the plain sum over the experts."""
+    x, rw, up, down = _expert_layer(e=16)
+    bias = jnp.zeros(16).at[5].set(9.0).at[6].set(8.0).at[7].set(7.0)
+    args = (x, up[4:8], down[4:8])
+
+    def held(x, up, down):
+        return moe_topk_held(x, jnp.zeros((16, 16)), bias, up, down, (4, 4),
+                             3, 2.5, act=ops.relu2,
+                             slot_rows=slot_rows)[0].sum()
+
+    def plain(x, up, down):
+        return sum(2.5 / 3 * (ops.relu2(x @ up[e]) @ down[e]).sum()
+                   for e in (1, 2, 3))
+    s, d = x.shape
+    slots = -(-s // slot_rows) - 1
+    shapes = _shapes_of(jax.make_jaxpr(jax.grad(held, (0, 1, 2)))(*args).jaxpr)
+    assert (4, s, d) not in shapes
+    assert (slots, s, d) not in shapes
+    assert (slots,) + up.shape[1:] not in shapes
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(held, (0, 1, 2))(*args)
+        want = jax.grad(plain, (0, 1, 2))(*args)
+    for g, w in zip(got, want):
+        assert _err(g, w) < 1e-5
+
+
+def test_the_blocked_loss_keeps_its_log_sum_exp_for_the_backward_pass():
+    """The backward loop reads the forward loop's log-sum-exp (one number a
+    row) and takes no maximum again: the forward loop cannot be scheduled
+    after the weight's update, which cost a copy of the head's weight (0.4
+    GB in the Solar-Open2 step; PERF.md section 4, PR 34)."""
+    h = jax.random.normal(jax.random.PRNGKey(0), (64, 16))
+    w = jax.random.normal(jax.random.PRNGKey(1), (40, 16))
+    y = jax.random.randint(jax.random.PRNGKey(2), (64,), 0, 40)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda h, w: ops.linear_cross_entropy(h, w, y, block=16).sum(),
+        (0, 1)))(h, w))
+    assert text.count("reduce_max") == 1
+    assert "f32[4,16]" in text                     # the rows' log-sum-exp
