@@ -1106,3 +1106,84 @@ def _ssd_vjp_bwd(heads, groups, chunk, res, g):
 
 
 ssd_fused.defvjp(_ssd_vjp_fwd, _ssd_vjp_bwd)
+
+
+# ----------------------------------------- rows of a sorted slot, in place
+# `parallel/moe.py::moe_topk_held` adds the rows a held expert's slot made
+# into the tokens they came from.  Within a slot the live rows' tokens are
+# sorted and unique, so no two of them meet: each target row is read, added
+# to and written back by its own DMA, the (S, D) array staying in HBM.  XLA's
+# scatter-add walks the rows one after another (0.69 ms for 2 304 rows of
+# 2 688 float32 of which 535 are live, flags or not: PERF.md section 6,
+# PR 35); gathering a slot's rows it does at the HBM rate, so there is no
+# gather kernel.
+#
+# A DMA moves whole (8, 128) tiles, so a row must lie on a dimension that is
+# not tiled: the target is held as (S, D / 128, 128) -- a row is D / 128
+# sublanes of one tile column -- while the loop runs, and reshaped once
+# after it.
+_ROWS_VMEM = 10 * 2 ** 20    # the slot rows' tile twice and the target rows'
+
+
+def rows_use_pallas(rows, d, dtype):
+    """The routing decision of `moe_topk_held`'s row moves: one TPU (or the
+    tests' interpret switch), float32 rows in whole 128-lane blocks, a slot
+    in whole tiles of 128 rows.  Counted either way, once a traced layer:
+    ``dispatch.pallas.hits.moe_rows.<D>`` / ``...fallbacks.moe_rows.<D>``."""
+    ok = (_FORCE_INTERPRET or _pb.one_tpu()) and d % 128 == 0 and \
+        rows % 128 == 0 and jnp.dtype(dtype) == jnp.float32
+    _count("hits" if ok else "fallbacks", "moe_rows", d)
+    return ok
+
+
+def _rows_scatter_add_kernel(tok_ref, live_ref, upd_ref, _, y_ref, buf, sem,
+                             *, tile):
+    from jax.experimental.pallas import tpu as pltpu
+    base = pl.program_id(0) * tile
+    n = jnp.clip(live_ref[0] - base, 0, tile)   # live rows lead the slot
+
+    def each(read, wait):
+        def body(j, carry):
+            there, here = y_ref.at[tok_ref[base + j]], buf.at[j]
+            copy = pltpu.make_async_copy(there, here, sem) if read else \
+                pltpu.make_async_copy(here, there, sem)
+            copy.wait() if wait else copy.start()
+            return carry
+        jax.lax.fori_loop(0, n, body, 0)
+
+    @pl.when(n > 0)                  # a tile wholly past `live` moves nothing
+    def _():
+        each(True, False)            # every target row in flight at once,
+        each(True, True)
+        buf[...] += upd_ref[...].reshape(buf.shape)
+        each(False, False)           # and back: no two are the same row
+        each(False, True)
+
+
+def rows_scatter_add(y3, tok, live, upd):
+    """``y3`` (S, D/128, 128) with ``upd``'s (rows, D) first ``live`` rows
+    added at rows ``tok[:live]`` -- which must be unique (sorted besides,
+    they are read in order) -- in place: ``y3`` is aliased to the result.
+    ``tok`` (rows,) and ``live`` () int32; rows past ``live`` are not
+    read."""
+    from jax.experimental.pallas import tpu as pltpu
+    s, c, lanes = y3.shape
+    rows, d = upd.shape
+    padded = -(-c // 8) * 8 * lanes * y3.dtype.itemsize
+    tile = 256 if rows % 256 == 0 and 3 * 256 * padded <= _ROWS_VMEM else 128
+    return pl.pallas_call(
+        functools.partial(_rows_scatter_add_kernel, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(rows // tile,),
+            in_specs=[pl.BlockSpec((tile, d), lambda i, *_: (i, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((tile, c, lanes), y3.dtype),
+                            pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct(y3.shape, y3.dtype),
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+        name="mx_rows_scatter_add",
+    )(tok, jnp.reshape(live, (1,)).astype(jnp.int32), upd, y3)

@@ -11,12 +11,15 @@ All functions are per-shard bodies for use inside ``shard_map``.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..ops import pallas_kernels
 
 __all__ = ["init_moe_params", "moe_ffn"]
 
@@ -142,6 +145,12 @@ def moe_topk_held(x, router_w, bias, up, down, held, top_k, scaling=1.0,
     ceil(S / slot_rows) slots.  ``slot_rows`` defaults to ``SLOT_SHARES``
     times an expert's even share S * top_k / E, in whole tiles of 256 rows.
 
+    The loop over the held experts is differentiated by hand
+    (``_held_experts``): the backward pass walks the same sorted slots, so
+    nothing is kept per expert or per slot, and a row is moved once, in
+    place, forward and backward.  The router's gradient flows through the
+    sorted pairs' weights; the routing itself is outside the loop.
+
     Returns ``(y, load)``: (S, D) and the tokens routed to each of the E
     experts (int32), held or not."""
     from .. import telemetry
@@ -170,47 +179,153 @@ def moe_topk_held(x, router_w, bias, up, down, held, top_k, scaling=1.0,
         local = idx.reshape(-1) - first
         local = jnp.where((local >= 0) & (local < count), local, count)
         order = jnp.argsort(local, stable=True)     # held pairs first
-        token = (order // top_k).astype(jnp.int32)
-        w_sorted = weight.reshape(-1)[order]
         sizes = lax.dynamic_slice(load, (first,), (count,))
         starts = jnp.cumsum(sizes) - sizes
+        # a slot reads ``rows`` sorted pairs in one slice: room for the last
+        # expert's, which a slice that ran past the end would shift
+        token = jnp.pad((order // top_k).astype(jnp.int32), (0, rows))
+        w_sorted = jnp.pad(weight.reshape(-1)[order], (0, rows))
+    kernel = pallas_kernels.rows_use_pallas(rows, D, x.dtype)
+    return _held_experts(rows, act, kernel, x, up, down, w_sorted, token,
+                         sizes, starts), load
 
-    def slot(y, e_up, e_down, size, start, r):
-        """``y`` and what rows [r * rows, (r + 1) * rows) of one expert
-        add: a row past ``size`` is token S, which is none -- it reads
-        zeros and is written nowhere."""
+
+# Every row a held expert reads or writes is moved once, in place, by an
+# operation told that no two rows of a slot collide: within an expert the
+# sorted pairs' tokens ascend (``order`` is a stable sort by expert, and
+# ``top_k`` picks no expert twice for a token), and dead row j is given the
+# index S + j -- past the end, so it reads the fill and is written nowhere,
+# and sorted and unique with the live ones.  The gathers are told both.  The
+# scatter-adds are told ``unique_indices`` alone: told its indices are
+# sorted, XLA's TPU scatter takes another path that is slower (0.73 -> 0.90
+# ms a slot at Nemotron's shape, 0.46 -> 0.96 at Solar's) and rounds (the
+# gradients came out 1e-3 off; PERF.md section 6, PR 35).  On one TPU the
+# adds are ``pallas_kernels.rows_scatter_add``'s instead.
+
+
+def _slot_pairs(token, w_sorted, size, start, r, rows, S):
+    """Rows [r * rows, (r + 1) * rows) of one expert's sorted pairs: their
+    tokens (S + j for a row past ``size``), their weights (0 there), where
+    they lie among the sorted pairs, and which are live (they lead)."""
+    j = r * rows + jnp.arange(rows, dtype=jnp.int32)
+    live = j < size
+    at = start + r * rows
+    tok = jnp.where(live, lax.dynamic_slice(token, (at,), (rows,)), S + j)
+    w = jnp.where(live, lax.dynamic_slice(w_sorted, (at,), (rows,)), 0)
+    return tok, w, at, live
+
+
+def _slot_rows(a, tok):
+    return a.at[tok].get(mode="fill", fill_value=0, indices_are_sorted=True,
+                         unique_indices=True)
+
+
+def _no_rows(x, kernel):
+    """Zeros for the slots' rows to be added into: as the kernel holds them
+    (``pallas_kernels.rows_scatter_add``) or as ``x``."""
+    S, D = x.shape
+    return jnp.zeros((S, D // 128, 128) if kernel else (S, D), x.dtype)
+
+
+def _as_rows_of(x, y, kernel):
+    """The sum of the slots' rows as an array like ``x``.  From the kernel's
+    form that is one relayout; behind a barrier, so that it is one copy here
+    and not a strided read inside whatever consumes the result."""
+    return lax.optimization_barrier(y.reshape(x.shape)) if kernel else y
+
+
+def _add_rows(y, tok, live, upd, kernel):
+    if kernel:
+        return pallas_kernels.rows_scatter_add(
+            y, tok, live.sum(dtype=jnp.int32), upd.astype(y.dtype))
+    return y.at[tok].add(upd.astype(y.dtype), mode="drop",
+                         unique_indices=True)
+
+
+def _slot_products(act, xs, e_up, e_down, w):
+    h = act(jnp.matmul(xs, e_up))
+    return jnp.matmul(h.astype(xs.dtype), e_down) * w[:, None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_experts(rows, act, kernel, x, up, down, w_sorted, token, sizes,
+                  starts):
+    """What the held experts add, (S, D): a scan over them with the result
+    as its carry; each is given its first slot always and a further one
+    while it has rows (a loop of as many trips, not differentiated).
+    ``kernel``: the rows are added by ``mx_rows_scatter_add``."""
+    S = x.shape[0]
+
+    def slot(r, y, e_up, e_down, size, start):
+        tok, w, _, live = _slot_pairs(token, w_sorted, size, start, r, rows,
+                                      S)
         with jax.named_scope("moe.dispatch"):
-            j = r * rows + jnp.arange(rows, dtype=jnp.int32)
-            live = j < size
-            pos = jnp.where(live, start + j, 0)
-            tok = jnp.where(live, token[pos], S)
-            w = jnp.where(live, w_sorted[pos], 0)
-            xs = x.at[tok].get(mode="fill", fill_value=0)
+            xs = _slot_rows(x, tok)
         with jax.named_scope("moe.experts"):
-            h = act(jnp.matmul(xs, e_up))
-            out = jnp.matmul(h.astype(x.dtype), e_down) * w[:, None]
+            out = _slot_products(act, xs, e_up, e_down, w)
         with jax.named_scope("moe.combine"):
-            return y.at[tok].add(out.astype(y.dtype), mode="drop")
+            return _add_rows(y, tok, live, out, kernel)
 
-    def expert(y, e_up, e_down, size, start):
-        """``y`` and what one expert adds: its first slot always, a further
-        one while it has rows."""
-        def more(y, r):
-            return lax.cond(r * rows < size, slot, lambda y, *_: y,
-                            y, e_up, e_down, size, start, r)
+    def expert(y, held_e):
+        slots = -(-held_e[2] // rows)       # the first always, more if sent
+        return lax.fori_loop(1, slots, lambda r, y: slot(r, y, *held_e),
+                             slot(0, y, *held_e)), None
 
-        def further(y):
-            return lax.scan(lambda y, r: (jax.checkpoint(more)(y, r), None),
-                            y, jnp.arange(1, -(-S // rows), dtype=jnp.int32))[0]
+    with jax.named_scope("moe.combine"):
+        y = _no_rows(x, kernel)
+    y, _ = lax.scan(expert, y, (up, down, sizes, starts))
+    with jax.named_scope("moe.combine"):
+        return _as_rows_of(x, y, kernel)
 
-        return lax.cond(rows < size, further, lambda y: y,
-                        slot(y, e_up, e_down, size, start, 0))
 
-    # Recomputed in the backward pass an expert at a time, and inside it a
-    # further slot at a time: one slot's rows live at once, and what a
-    # ``cond`` keeps for its branch (x, the expert's weights) is kept for
-    # one expert, not stacked by the scan around it.
-    y, _ = lax.scan(lambda y, held_e: (jax.checkpoint(expert)(y, *held_e),
-                                       None),
-                    jnp.zeros_like(x), (up, down, sizes, starts))
-    return y, load
+def _held_experts_fwd(rows, act, kernel, *args):
+    # nothing per expert or per slot is kept: the backward pass gathers a
+    # slot's rows again
+    return _held_experts(rows, act, kernel, *args), args
+
+
+def _held_experts_bwd(rows, act, kernel, res, g):
+    """By hand over the sorted slots: a scan over the held experts carrying
+    x's cotangent (S, D) and the sorted weights'; a slot gathers its rows of
+    ``g`` and of ``x``, takes ``jax.vjp`` of its products (``act`` is any
+    callable), and adds its rows' cotangent in place on the carry."""
+    x, up, down, w_sorted, token, sizes, starts = res
+    S = x.shape[0]
+
+    def slot(r, dx, dws, e_up, e_down, size, start):
+        tok, w, at, live = _slot_pairs(token, w_sorted, size, start, r, rows,
+                                       S)
+        with jax.named_scope("moe.combine"):
+            dout = _slot_rows(g, tok)
+        with jax.named_scope("moe.dispatch"):
+            xs = _slot_rows(x, tok)
+        with jax.named_scope("moe.experts"):
+            dxs, d_up, d_down, dw = jax.vjp(
+                functools.partial(_slot_products, act), xs, e_up, e_down,
+                w)[1](dout)
+        with jax.named_scope("moe.dispatch"):
+            dx = _add_rows(dx, tok, live, dxs, kernel)
+            # the slot's pairs are contiguous among the sorted ones
+            dws = lax.dynamic_update_slice(dws, jnp.where(
+                live, dw, lax.dynamic_slice(dws, (at,), (rows,))), (at,))
+        return dx, dws, d_up, d_down
+
+    def expert(carry, held_e):
+        def more(r, c):
+            dx, dws, d_up, d_down = slot(r, *c[:2], *held_e)
+            return dx, dws, c[2] + d_up, c[3] + d_down
+
+        dx, dws, d_up, d_down = lax.fori_loop(
+            1, -(-held_e[2] // rows), more, slot(0, *carry, *held_e))
+        return (dx, dws), (d_up, d_down)
+
+    with jax.named_scope("moe.dispatch"):
+        none = _no_rows(x, kernel), jnp.zeros_like(w_sorted)
+    (dx, dws), (d_up, d_down) = lax.scan(expert, none,
+                                         (up, down, sizes, starts))
+    with jax.named_scope("moe.dispatch"):
+        return (_as_rows_of(x, dx, kernel), d_up, d_down, dws, None, None,
+                None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)

@@ -170,6 +170,41 @@ def test_ssd_fwd_and_grad_at_the_cell_shape(one_chip, compiled_kernels):
     no_tile(text)
 
 
+@pytest.mark.parametrize("s,d,f,fu,e,k,rows,act", [
+    (8192, 2688, 1856, 1856, 128, 6, None, "relu2"),     # Nemotron's cell
+    (4096, 4096, 1280, 2560, 320, 8, 1024, "swiglu"),    # Solar-Open2's
+])
+def test_routed_experts_rows_at_the_cell_shapes(one_chip, compiled_kernels,
+                                                s, d, f, fu, e, k, rows,
+                                                act):
+    """`moe_topk_held` of both language-model cells, 8 held experts, forward
+    and gradient: the slots' rows are added by `mx_rows_scatter_add` (first
+    slot and the loop of further ones, forward and backward) under the
+    default scoped VMEM limit, into an (S, D / 128, 128) carry, and no XLA
+    scatter over an (S, D) array is left."""
+    from mxnet_tpu.ops import nn
+    from mxnet_tpu.parallel.moe import moe_topk_held
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def held(x, rw, up, down, gy):
+        y, _ = moe_topk_held(x, rw, jnp.zeros((e,)), up, down, (0, 8), k,
+                             2.5, slot_rows=rows,
+                             act=getattr(nn, act))
+        return jnp.sum(jnp.tanh(y) * gy)
+
+    slot = rows or 2304
+    assert pallas_kernels.rows_use_pallas(slot, d, jnp.float32)
+    text = _compile(jax.grad(held, (0, 1, 2, 3)), arg(s, d), arg(e, d),
+                    arg(8, d, fu), arg(8, f, d), arg(s, d))
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert "mx_rows_scatter_add" in text
+    assert f"f32[{s},{d // 128},128]" in text
+    assert not [line for line in text.splitlines()
+                if " scatter(" in line and f"= f32[{s},{d}]" in line]
+
+
 def _stage_shape(stage, n=128):
     h, w, c = (int(t) for t in stage.split("x"))
     return (n, h, w, c)

@@ -200,6 +200,41 @@ def test_relu2_experts_are_unchanged():
     assert _err(y, want) < 1e-5 and int(load.sum()) == 48 * 3
 
 
+@pytest.mark.parametrize("slot_rows", [16, 20, 48, None])
+def test_swiglu_experts_hand_backward_is_the_plain_sum_s_gradient(slot_rows):
+    """The fused gate-up product and the activation that splits it through
+    the backward pass written by hand: `act` is any callable there (its
+    `jax.vjp` is taken a slot), so the gradients of x, the router weight,
+    gate-up and down are the reference's - with every token sent to three
+    of the four held experts, so further slots run."""
+    rs = onp.random.RandomState(9)
+    draw = lambda *shape: jnp.asarray(rs.randn(*shape) * 0.3, jnp.float32)
+    x, rw, gy = draw(48, D), draw(16, D), draw(48, D)
+    up, down = draw(4, D, 2 * F), draw(4, F, D)
+    bias = jnp.zeros((16,)).at[5].set(9.0).at[6].set(8.0).at[7].set(7.0)
+    cfg = {"num_experts_per_tok": 3, "norm_topk_prob": True,
+           "routed_scaling_factor": 1.5, "experts_held": (4, 4)}
+
+    def held(x, rw, up, down):
+        y, _ = moe_topk_held(x, rw, bias, up, down, (4, 4), 3, 1.5, True,
+                             slot_rows=slot_rows, act=N.swiglu)
+        return jnp.sum(y * gy)
+
+    def plain(x, rw, up, down):
+        w = {"router_weight": rw, "correction_bias": bias,
+             "experts_up": up, "experts_down": down,
+             "shared_up.weight": jnp.zeros((2, D)),
+             "shared_down.weight": jnp.zeros((D, 1))}
+        return jnp.sum(ref.experts(x, w, cfg) * gy)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(held, (0, 1, 2, 3)))(x, rw, up, down)
+        want = jax.grad(plain, (0, 1, 2, 3))(x, rw, up, down)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(w).max()) > 0
+        assert _err(g, w) < 1e-5
+
+
 def test_slot_rows_at_the_cell_s_sizes():
     """8192 tokens, top-8 of 320: six even shares of 204.8 tokens in whole
     tiles of 256 rows are 1280 rows a held expert."""

@@ -325,6 +325,123 @@ def test_the_backward_pass_stacks_no_copy_of_x_over_the_held_experts(
         assert _err(g, w) < 1e-5
 
 
+ROUTINGS = {
+    # every token chooses experts 5, 6, 7: expert 4 is sent nothing, each of
+    # the others all 48 tokens (further slots unless a slot holds 48)
+    "three_experts": jnp.zeros(16).at[5].set(9.0).at[6].set(8.0).at[7].set(7.0),
+    # expert 5 is sent every token (exactly a slot of 48), expert 4 none,
+    # the others what the router gives them
+    "one_full_one_empty": jnp.zeros(16).at[5].set(9.0).at[4].set(-9.0),
+    "as_routed": jnp.zeros(16),
+}
+
+
+def _held_and_plain(bias, slot_rows):
+    """A share of 4 of 16 experts, 48 tokens 12 wide, through
+    ``moe_topk_held`` and the plain reference told the same share (its
+    shared expert's weights zero), both as a scalar function of x, the
+    router weight, up and down."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    x = jax.random.normal(ks[0], (48, 12))
+    rw = jax.random.normal(ks[1], (16, 12)) * 0.5
+    up = jax.random.normal(ks[2], (4, 12, 8)) * 0.3
+    down = jax.random.normal(ks[3], (4, 8, 12)) * 0.3
+    gy = jax.random.normal(ks[4], (48, 12))
+    cfg = dict(num_experts_per_tok=3, routed_scaling_factor=2.5,
+               norm_topk_prob=True, experts_held=(4, 4))
+
+    def held(x, rw, up, down):
+        y, _ = moe_topk_held(x, rw, bias, up, down, (4, 4), 3, 2.5,
+                             act=ops.relu2, slot_rows=slot_rows)
+        return jnp.sum(y * gy)
+
+    def plain(x, rw, up, down):
+        w = {"router_weight": rw, "correction_bias": bias,
+             "experts_up": up, "experts_down": down,
+             "shared_up.weight": jnp.zeros((2, 12)),
+             "shared_down.weight": jnp.zeros((12, 2))}
+        return jnp.sum(ref.experts(x, w, cfg) * gy)
+
+    return held, plain, (x, rw, up, down)
+
+
+@pytest.mark.parametrize("slot_rows", [16, 20, 48, None])
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_the_hand_backward_is_the_plain_sum_s_gradient(routing, slot_rows):
+    """x, the router weight (through the sorted pairs' weights), up and
+    down: the backward pass written by hand over the sorted slots gives the
+    gradients autodiff gives the plain sum over the held experts, whatever
+    the slots hold: nothing, exactly their rows, or several slots' worth."""
+    held, plain, args = _held_and_plain(ROUTINGS[routing], slot_rows)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(held, (0, 1, 2, 3)))(*args)
+        want = jax.value_and_grad(plain, (0, 1, 2, 3))(*args)
+    assert abs(float(got[0]) - float(want[0])) < 1e-4 * abs(float(want[0]))
+    for g, w in zip(got[1], want[1]):
+        assert float(jnp.abs(w).max()) > 0
+        assert _err(g, w) < 1e-5
+
+
+def _eqns_of(jaxpr):
+    """Every equation of a jaxpr, inner jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield jaxpr, eqn
+        for p in eqn.params.values():
+            for q in (p if isinstance(p, (tuple, list)) else (p,)):
+                inner = getattr(q, "jaxpr", q)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns_of(inner)
+
+
+def _row_moves(fn, args, shape):
+    """``(gathers, scatter-adds)`` over an array of ``shape`` in the
+    gradient's program, each as (params, whether the operand is an array of
+    zeros made beside it)."""
+    found = {"gather": [], "scatter-add": []}
+    for jaxpr, eqn in _eqns_of(jax.make_jaxpr(
+            jax.grad(fn, (0, 1, 2, 3)))(*args).jaxpr):
+        name = eqn.primitive.name
+        if name in found and tuple(eqn.invars[0].aval.shape) == shape:
+            fresh = any(e.primitive.name == "broadcast_in_dim"
+                        and eqn.invars[0] in e.outvars for e in jaxpr.eqns)
+            found[name].append((eqn.params, fresh))
+    return found["gather"], found["scatter-add"]
+
+
+@pytest.mark.parametrize("slot_rows", [16, 48])
+def test_every_row_is_moved_in_place_by_an_operation_told_it_is_alone(
+        slot_rows):
+    """The gradient's program scatter-adds into no fresh (S, D) zero array
+    (autodiff's transpose of a gather did, once an expert, and added the
+    result to the running sum), and every gather and scatter-add over the
+    (S, D) arrays is told its indices are unique (the gathers: sorted too)
+    - which they are, since a dead row j carries the index S + j."""
+    held, _, args = _held_and_plain(ROUTINGS["three_experts"], slot_rows)
+    gathers, scatters = _row_moves(held, args, (48, 12))
+    # first slot and the loop's body: x forward; the cotangent and x backward
+    assert len(gathers) == 6 and len(scatters) == 4
+    for params, fresh in gathers + scatters:
+        assert params["unique_indices"] and not fresh
+    # told its indices are sorted, XLA's TPU scatter is slower and rounds
+    # (PERF.md section 6, PR 35): the gathers alone are told
+    assert all(params["indices_are_sorted"] for params, _ in gathers)
+    assert not any(params["indices_are_sorted"] for params, _ in scatters)
+
+
+def test_dead_rows_carry_indices_of_their_own():
+    from mxnet_tpu.parallel.moe import _slot_pairs
+    token = jnp.pad(jnp.array([1, 4, 7, 2, 3], jnp.int32), (0, 4))
+    w = jnp.pad(jnp.arange(1.0, 6.0), (0, 4))
+    tok, ws, at, live = _slot_pairs(token, w, 3, 0, 0, 4, 8)
+    assert tok.tolist() == [1, 4, 7, 8 + 3] and ws.tolist() == [1, 2, 3, 0]
+    tok, ws, at, live = _slot_pairs(token, w, 2, 3, 0, 4, 8)
+    assert tok.tolist() == [2, 3, 8 + 2, 8 + 3] and int(at) == 3
+    assert live.tolist() == [True, True, False, False]
+    tok, _, at, _ = _slot_pairs(token, w, 5, 0, 1, 4, 8)    # a further slot
+    assert tok.tolist() == [3, 8 + 5, 8 + 6, 8 + 7] and int(at) == 4
+    assert bool((jnp.diff(tok) > 0).all())
+
+
 def test_the_blocked_loss_keeps_its_log_sum_exp_for_the_backward_pass():
     """The backward loop reads the forward loop's log-sum-exp (one number a
     row) and takes no maximum again: the forward loop cannot be scheduled
