@@ -1148,37 +1148,33 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
     ``q_t`` and ``k_s``, s <= t.  ``(I + A)^{-1}`` is applied to ``beta V``
     and ``beta K exp G`` for all chunks at once (one triangular solve);
     only ``U = U~ - W S_0``, the output and the state's update run in a
-    ``lax.scan`` over the chunks.
+    ``lax.scan`` over the chunks (`_delta_rule_chunked`, which
+    `gdn_chunked` shares).
 
     The decay is per channel, so ``A`` is no product of two factors that
     stay finite (``exp(-G)`` overflows under a strong decay).  As
     ``fla``'s ``chunk_kda`` does, a chunk is cut in sub-blocks of
-    ``KDA_SUB`` rows: between two sub-blocks both factors are taken from
-    the later one's first row, where each exponent is <= 0; inside a
-    sub-block the (KDA_SUB, KDA_SUB, dk) decays are made one by one.  No
-    exponent is ever positive.  The largest temporary is (B, H, T, KDA_SUB,
-    dk), linear in T.  A ``T`` that ``chunk`` does not divide is padded with
-    steps that change nothing (``k = 0``, ``beta = 0``, ``g = 0``) and cut
-    back; a ``chunk`` that ``KDA_SUB`` does not divide is one sub-block."""
+    ``KDA_SUB`` rows (`_pairs_by_channel`): between two sub-blocks both
+    factors are taken from the later one's first row, where each exponent
+    is <= 0; inside a sub-block the (KDA_SUB, KDA_SUB, dk) decays are made
+    one by one.  No exponent is ever positive.  The largest temporary is
+    (B, H, T, KDA_SUB, dk), linear in T.  A ``T`` that ``chunk`` does not
+    divide is padded with steps that change nothing (``k = 0``, ``beta =
+    0``, ``g = 0``) and cut back; a ``chunk`` that ``KDA_SUB`` does not
+    divide is one sub-block."""
     _count_route("kda.xla_chunked")
-    B, T, H, dk = k.shape
-    dv = v.shape[-1]
-    f32, out_dtype = jnp.float32, v.dtype
-    pad = -T % chunk
-    if pad:
-        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                      for a in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    nc = (T + pad) // chunk
+    return _delta_rule_chunked(q, k, v, g, beta, chunk, _pairs_by_channel)
+
+
+def _pairs_by_channel(q, k, G):
+    """``(A_kk, A_qk)``, each (B, H, nc, chunk, chunk) and zero above the
+    diagonal: ``sum_c a_t[c] k_s[c] exp(G_t[c] - G_s[c])`` for ``a`` = k
+    and q, s <= t, from the chunked ``q``, ``k`` and running log-decay ``G``
+    (B, H, nc, chunk, dk) by sub-blocks of ``KDA_SUB`` rows."""
+    B, H, nc, chunk, _ = k.shape
+    f32 = jnp.float32
     sub = KDA_SUB if chunk % KDA_SUB == 0 else chunk
     n = chunk // sub
-
-    def chunks(a):                      # (B, T, H, X) -> (B, H, nc, chunk, X)
-        return a.astype(f32).reshape(B, nc, chunk, H, -1) \
-            .transpose(0, 3, 1, 2, 4)
-    q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
-    beta = chunks(beta[..., None])                        # (B,H,nc,chunk,1)
-    G = jnp.cumsum(g, axis=3)
     blocks = lambda a: a.reshape(B, H, nc, n, sub, a.shape[-1])
     Gs, ks = blocks(G), blocks(k)
     rows = jnp.stack([ks, blocks(q)])                     # (2,B,H,nc,n,sub,dk)
@@ -1199,14 +1195,39 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
                      decay * ks[..., None, :, :],
                      preferred_element_type=f32)
     pair = pair + own[..., None, :] * jnp.eye(n, dtype=f32)[:, None, :, None]
-    a_kk, a_qk = pair.reshape(2, B, H, nc, chunk, chunk)
+    return pair.reshape(2, B, H, nc, chunk, chunk)
+
+
+def _delta_rule_chunked(q, k, v, g, beta, chunk, pairs):
+    """The gated delta rule by chunks for a log-decay ``g`` (B, T, H, dk)
+    a channel or (B, T, H, 1) a head; ``pairs(q, k, G)`` gives the pairwise
+    decayed products inside a chunk.  Everything after them broadcasts over
+    the decay's last axis: the one triangular solve and the one scan over
+    the chunk states of this file."""
+    B, T, H, dk = k.shape
+    dv = v.shape[-1]
+    f32, out_dtype = jnp.float32, v.dtype
+    pad = -T % chunk
+    if pad:
+        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    nc = (T + pad) // chunk
+
+    def chunks(a):                      # (B, T, H, X) -> (B, H, nc, chunk, X)
+        return a.astype(f32).reshape(B, nc, chunk, H, -1) \
+            .transpose(0, 3, 1, 2, 4)
+    q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
+    beta = chunks(beta[..., None])                        # (B,H,nc,chunk,1)
+    G = jnp.cumsum(g, axis=3)
+    a_kk, a_qk = pairs(q, k, G)
     a_kk = beta * jnp.tril(a_kk, -1)
     eg = jnp.exp(G)
     solved = lax.linalg.triangular_solve(
         a_kk, beta * jnp.concatenate([v, k * eg], axis=-1), left_side=True,
         lower=True, unit_diagonal=True)
     u0, w = solved[..., :dv], solved[..., dv:]
-    g_end = eg[..., -1, :]                                # (B,H,nc,dk)
+    g_end = eg[..., -1, :]                                # (B,H,nc,dk or 1)
     k_end = k * jnp.exp(G[..., -1:, :] - G)
 
     def step(S, xs):
@@ -1226,3 +1247,40 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
                           (u0, w, q * eg, a_qk, k_end, g_end)))
     o = o.transpose(1, 0, 3, 2, 4).reshape(B, T + pad, H, dv)
     return o[:, :T].astype(out_dtype)
+
+
+# ------------------------------------- gated delta rule, one decay a head
+# Gated DeltaNet (arXiv:2412.06464; ``fla.layers.GatedDeltaNet``), the
+# linear layers of models/olmo_hybrid.py.
+def _pairs_by_head(q, k, G):
+    """`_pairs_by_channel` for a running log-decay ``G`` (B, H, nc, chunk,
+    1) that is one number a head and step: the decay leaves the sum over
+    the channels, so ``A[t, s] = (a_t . k_s) exp(G_t - G_s)`` is one ``K
+    K^T`` product times a (chunk, chunk) matrix whose exponents, taken for
+    s <= t only, are never positive.  No sub-blocks, no per-channel tiles."""
+    chunk = k.shape[3]
+    upto = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(upto, G - jnp.swapaxes(G, -1, -2), -jnp.inf))
+    return jnp.einsum("xbhctd,bhcsd->xbhcts", jnp.stack([k, q]), k,
+                      preferred_element_type=jnp.float32) * decay
+
+
+def gdn_chunked(q, k, v, g, beta, chunk=64):
+    """The gated delta rule with **one decay a head and step** by chunks:
+    per head, with a state ``S`` (dk, dv) from zero, ``S_t = exp(g_t) (I -
+    beta_t k_t k_t^T) S_{t-1} + beta_t k_t v_t^T`` and ``o_t = S_t^T q_t``.
+
+    ``q`` and ``k`` (B, T, H, dk), ``v`` (B, T, H, dv) with any dk and dv,
+    ``g`` (B, T, H) the log-decay (not positive), ``beta`` (B, T, H) ->
+    (B, T, H, dv).  The caller normalises and scales ``q`` and ``k``.
+
+    It is `kda_chunked`'s computation (the WY / UT form: ``(I + A)`` solved
+    once for ``[beta V | beta K exp G]`` over all chunks, a ``lax.scan``
+    over the chunk states, the same padding of a ``T`` that ``chunk`` does
+    not divide) with the pairwise decays of a chunk from `_pairs_by_head`:
+    fed the same decay on every channel `kda_chunked` gives the same result
+    and pays for (B, H, T, KDA_SUB, dk) tiles that this does not make.  A
+    composition in XLA: it counts ``dispatch.gdn.xla_chunked``."""
+    _count_route("gdn.xla_chunked")
+    return _delta_rule_chunked(q, k, v, g[..., None], beta, chunk,
+                               _pairs_by_head)

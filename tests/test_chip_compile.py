@@ -258,3 +258,35 @@ def test_default_int8_routes_compile(one_chip, compiled_kernels):
         assert pallas_int8.eligible_int8(shape, qw.shape, True)
         _compile(pallas_int8.qconv3x3_affine, qx, qw, v, v, res)
         _compile(pallas_int8.qconv3x3_affine, qx, qw, v, v)
+
+
+def test_olmo_hybrid_mixers_fwd_and_grad_at_the_cell_shape(one_chip,
+                                                          compiled_kernels):
+    """What `olmo-hybrid-train-gdn` runs: 15 linear heads with keys 96 and
+    values 192 wide at T = 8192 through `ops/nn.py::gdn_chunked` (a
+    composition: no kernel, no per-channel (16, 96) decay tile, no (T, T)
+    matrix), and 15 query = 15 key-value heads of 128 behind the QK-norm,
+    which meet the causal attention kernels' rule."""
+    from mxnet_tpu.models import olmo_hybrid
+    from mxnet_tpu.ops import nn
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                            sharding=one_chip)
+    args = (s(1, 8192, 15, 96), s(1, 8192, 15, 96), s(1, 8192, 15, 192),
+            s(1, 8192, 15), s(1, 8192, 15))
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(nn.gdn_chunked(*a) ** 2),
+        argnums=tuple(range(5)))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert "16,96]" not in text, "a per-channel decay tile was made"
+    _no_square(text, 8192)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+    assert pallas_kernels.causal_attention_use_pallas(8192, 15, 15, 128)
+    proj, w = s(1, 8192, 1920), s(1920)
+    text = _compile(jax.grad(
+        lambda q, k, v, qw, kw: jnp.sum(olmo_hybrid._qknorm_attention_core(
+            q, k, v, qw, kw, heads=15, kv=15, hd=128, eps=1e-6) ** 2),
+        argnums=(0, 1, 2, 3, 4)), proj, proj, proj, w, w)
+    assert "mx_causal_attn_fwd" in text and "mx_causal_attn_bwd" in text
+    _no_square(text, 8192)
