@@ -763,3 +763,22 @@ def recompute(fn, *args):
     for p, v in zip(written, aux):
         p.set_data(NDArray(v))
     return NDArray(out)
+
+
+def materialize(x, site):
+    """``x`` behind an identity ``lax.optimization_barrier`` while a
+    *training* program is traced, so that XLA stores it once instead of
+    fusing the elementwise code that produced it into every product that
+    reads it (a producer fused into a product's operand is evaluated again
+    for each output tile; its transposition puts the same barrier on the
+    cotangent).  A recomputed block says so in its own ``forward`` for a
+    value that stands between two products (``models/olmo_hybrid.py``) and
+    names the ``site``: ``dispatch.materialized.<site>`` counts once a
+    traced call.  Identity in value and in gradient; outside a traced
+    training program (eager, inference, serving) ``x`` itself comes back
+    and nothing is counted."""
+    if not (_trace_ctx.active and tape.is_training()):
+        return x
+    from .. import telemetry
+    telemetry.counter_add("dispatch.materialized." + site)
+    return NDArray(jax.lax.optimization_barrier(x._data))

@@ -49,7 +49,7 @@ from jax import lax
 
 from .. import initializer as init
 from ..gluon import nn
-from ..gluon.block import recompute
+from ..gluon.block import materialize, recompute
 from ..gluon.parameter import Parameter
 from ..numpy import _call
 from ..ops import nn as _nn
@@ -196,7 +196,7 @@ class SwiGLUMLP(nn.HybridBlock):
         with jax.named_scope("mlp.up"):
             h = self.gate_up_proj(x)
         with jax.named_scope("mlp.act"):
-            h = _call(_nn.swiglu, h)
+            h = materialize(_call(_nn.swiglu, h), "mlp_act")
         with jax.named_scope("mlp.down"):
             return self.down_proj(h)
 
@@ -212,7 +212,8 @@ class PostNormLayer(nn.HybridBlock):
         self.norm = nn.RMSNorm(epsilon=eps, in_channels=hidden_size)
 
     def forward(self, h):
-        return recompute(lambda h: h + self.norm(self.mixer(h)), h)
+        return recompute(lambda h: h + self.norm(
+            materialize(self.mixer(h), "sublayer_out")), h)
 
 
 def block_pattern(layer_types):

@@ -290,3 +290,53 @@ def test_olmo_hybrid_mixers_fwd_and_grad_at_the_cell_shape(one_chip,
         argnums=(0, 1, 2, 3, 4)), proj, proj, proj, w, w)
     assert "mx_causal_attn_fwd" in text and "mx_causal_attn_bwd" in text
     _no_square(text, 8192)
+
+
+def test_olmo_hybrid_mlp_block_s_weight_gradient_reads_stored_operands(
+        one_chip):
+    """One `F` block of `olmo-hybrid-train-gdn` (8192 x 3840 x 11008,
+    float32) with Adam through `Trainer.fuse_step`: the fusion that writes
+    `down_proj`'s updated weight (the weight gradient's product with the
+    update as its epilogue) reads SwiGLU's output stored once as
+    `bf16[1,8192,11008]` and the post-norm's cotangent stored once, not the
+    `f32[1,8192,22016]` gate-up product with `silu(gate) * up` evaluated
+    again for every output tile (`gluon.block.materialize`, PERF.md section
+    6, PR 37: 19.8 -> 4-6 ms on the chip)."""
+    import re
+
+    from mxnet_tpu.gluon import Trainer, loss as gloss
+    from mxnet_tpu.models import olmo_hybrid
+    T, D, F = 8192, 3840, 11008
+    net = olmo_hybrid.PostNormLayer(olmo_hybrid.SwiGLUMLP(D, F), D)
+    net.initialize()
+    net.hybridize()
+    trainer = Trainer(net.collect_params(), "adam", {"learning_rate": 1e-4})
+    step = trainer.fuse_step(gloss.L2Loss())
+    init_state = step._opt.init_state       # shapes of the moments only
+    step._opt.init_state = lambda w: jax.eval_shape(init_state, w)
+    step._build_data(jnp.zeros((1, 8, D), jnp.float32))
+    step._build_jit()
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    weights = lambda names: {n: like(step._params[n].data()._data)
+                             for n in names}
+    states = {n: jax.tree_util.tree_map(like, trainer._states[step._tname[n]])
+              for n in step._tr_names}
+    x = jax.ShapeDtypeStruct((1, T, D), jnp.float32, sharding=one_chip)
+    compiled = step._compiled.lower(
+        weights(step._tr_names), weights(step._fr_names), states,
+        jax.tree_util.tree_map(like, step._ctl),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip), x, x
+    ).compile()
+    text = compiled.as_text()
+    types = dict(re.findall(
+        r"^\s*(?:ROOT\s+)?(%[^\s=]+) = (\(?\w+\[[\d,]*\])", text, re.M))
+    updates = [line for line in text.splitlines()
+               if re.search(r"= \(f32\[3840,11008\]", line)
+               and " fusion(" in line and "mlp.down" in line]
+    assert len(updates) == 1, updates
+    operands = [types.get(name, "") for name in re.findall(
+        r"%[\w.\-]+", updates[0].split(" fusion(", 1)[1].split(")", 1)[0])]
+    assert "bf16[1,8192,11008]" in operands, operands
+    assert "bf16[1,8192,3840]" in operands, operands
+    assert "f32[1,8192,22016]" not in operands, operands
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

@@ -143,7 +143,11 @@ def test_fused_step_loss_is_the_reference_loss_and_counts_its_routes():
                       "dispatch.attention.causal.xla_blocked": 1,
                       # head_dim 8 is no lane tile: the kernel says no
                       "dispatch.pallas.fallbacks.causal_attention.8": 1,
-                      "dispatch.loss.linear_blocked": 1}
+                      "dispatch.loss.linear_blocked": 1,
+                      # one stored value a traced site: SwiGLU's output in
+                      # the four MLPs, the sub-layer's output in all eight
+                      "dispatch.materialized.mlp_act": 4,
+                      "dispatch.materialized.sublayer_out": 8}
 
 
 def test_fused_step_trains_without_retraces():
