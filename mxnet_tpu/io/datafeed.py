@@ -27,6 +27,7 @@ staging thread.
 """
 from __future__ import annotations
 
+import itertools
 import os
 import queue as _q
 import threading
@@ -114,6 +115,7 @@ class DataFeed:
 
     # -------------------------------------------------------- lifecycle --
     def _start(self):
+        self._drawn = 0         # draws since this ring started
         if self._depth == 0:
             self._stats["sync_mode"] = True
             self._sync_it = iter(self._iter_source())
@@ -269,13 +271,18 @@ class DataFeed:
         import jax
         from ..ndarray import NDArray
         host = arr._data if isinstance(arr, NDArray) else np.asarray(arr)
-        dev = jax.device_put(host, self._get_device())
+        nbytes = int(getattr(host, "nbytes", 0))
+        # the host's cost of enqueueing the copy: nothing here waits for
+        # it to land, so copy k overlaps the staging of batch k + 1
+        with _telemetry.span("datafeed.h2d", bytes=nbytes):
+            dev = jax.device_put(host, self._get_device())
         with self._lock:
-            self._stats["h2d_bytes"] += int(getattr(host, "nbytes", 0))
+            self._stats["h2d_bytes"] += nbytes
         if is_data and self._needs_finalize(host):
-            fn = self._finalize_fn((is_data, str(host.dtype), host.ndim,
-                                    tuple(host.shape)))
-            dev = fn(dev)
+            with _telemetry.span("datafeed.finalize"):
+                fn = self._finalize_fn((is_data, str(host.dtype), host.ndim,
+                                        tuple(host.shape)))
+                dev = fn(dev)
         return NDArray(dev)
 
     def _stage(self, item):
@@ -312,26 +319,20 @@ class DataFeed:
 
     def _stage_loop(self):
         queue, abandoned = self._queue, self._abandoned
+        source = self._iter_source()
         try:
-            for item in self._iter_source():
-                staged = self._stage(item)
-                with self._lock:
-                    self._stats["staged_batches"] += 1
-                self._gauge("datafeed/staged",
-                            self._stats["staged_batches"])
-                try:
-                    queue.put_nowait(staged)
-                except _q.Full:
-                    # ring full: the device is the bottleneck (the
-                    # healthy state) — count once per batch, then wait
-                    with self._lock:
-                        self._stats["backpressure_waits"] += 1
-                    while not abandoned.is_set():
-                        try:
-                            queue.put(staged, timeout=0.1)
-                            break
-                        except _q.Full:
-                            continue
+            # batch = draws since the ring started, which is also the
+            # consumer's count: its datafeed.wait names the same batch
+            for seq in itertools.count():
+                with _telemetry.span("datafeed.source", parent=None,
+                                     batch=seq):
+                    item = next(source, _SENTINEL)
+                if item is _SENTINEL:
+                    return
+                # opened once there is a batch: the source's end is no stage
+                with _telemetry.span("datafeed.stage", parent=None,
+                                     batch=seq):
+                    self._stage_into(queue, abandoned, item)
                 if abandoned.is_set():
                     return
                 self._gauge("datafeed/ring_depth", queue.qsize())
@@ -344,6 +345,30 @@ class DataFeed:
                     break
                 except _q.Full:
                     continue
+
+    def _stage_into(self, queue, abandoned, item):
+        """Stage ``item`` and hand it over, on the producer's thread.
+        Children of the caller's ``datafeed.stage``: ``datafeed.h2d`` and
+        ``datafeed.finalize`` an array, and ``datafeed.backpressure`` where
+        the ring was full."""
+        staged = self._stage(item)
+        with self._lock:
+            self._stats["staged_batches"] += 1
+        self._gauge("datafeed/staged", self._stats["staged_batches"])
+        try:
+            queue.put_nowait(staged)
+        except _q.Full:
+            # ring full: the device is the bottleneck (the healthy
+            # state) — count once per batch, then wait
+            with self._lock:
+                self._stats["backpressure_waits"] += 1
+            with _telemetry.span("datafeed.backpressure"):
+                while not abandoned.is_set():
+                    try:
+                        queue.put(staged, timeout=0.1)
+                        break
+                    except _q.Full:
+                        continue
 
     def _gauge(self, name, value):
         try:
@@ -374,12 +399,14 @@ class DataFeed:
             # histogram twin (datafeed.wait_us) is what the obs recorder
             # derives the input-stall fraction from
             t0 = time.perf_counter()
-            with _telemetry.span("datafeed.wait", mode="sync"):
+            with _telemetry.span("datafeed.wait", mode="sync",
+                                 batch=self._drawn):
                 item = next(self._sync_it)           # StopIteration flows
                 staged = self._stage(item)
             _telemetry.observe("datafeed.wait_us",
                                (time.perf_counter() - t0) * 1e6)
             self._wait_resident(staged)
+            self._drawn += 1
             with self._lock:
                 self._stats["consumed"] += 1
             return staged
@@ -392,7 +419,8 @@ class DataFeed:
                 self._stats["consumer_waits"] += 1
                 self._stats["sync_fallbacks"] += 1
             t0 = time.perf_counter()
-            with _telemetry.span("datafeed.wait", mode="stall"):
+            with _telemetry.span("datafeed.wait", mode="stall",
+                                 batch=self._drawn):
                 item = self._wait_for_batch()
             waited = time.perf_counter() - t0
             _telemetry.observe("datafeed.wait_us", waited * 1e6)
@@ -404,6 +432,7 @@ class DataFeed:
                 raise err
             raise StopIteration
         self._wait_resident(item)
+        self._drawn += 1
         with self._lock:
             self._stats["consumed"] += 1
         return item
@@ -425,7 +454,8 @@ class DataFeed:
             return
         import jax
         t0 = time.perf_counter()
-        with _telemetry.span("datafeed.wait", mode="copy"):
+        with _telemetry.span("datafeed.wait", mode="copy",
+                             batch=self._drawn):
             jax.block_until_ready(late)
         waited = time.perf_counter() - t0
         _telemetry.observe("datafeed.wait_us", waited * 1e6)

@@ -108,7 +108,7 @@ class NDArray:
 
     # -------------------------------------------------------------- transfer
     def asnumpy(self) -> _onp.ndarray:
-        return _onp.asarray(self._data)
+        return _fetch(self._data, _onp.asarray)
 
     def numpy(self):
         return self.asnumpy()
@@ -146,7 +146,7 @@ class NDArray:
             else out
 
     def item(self):
-        return self._data.item()
+        return _fetch(self._data, _item)
 
     def tolist(self):
         return self.asnumpy().tolist()
@@ -179,10 +179,10 @@ class NDArray:
 
     # ------------------------------------------------------------------ sync
     def wait_to_read(self):
-        jax.block_until_ready(self._data)
+        _fetch(self._data, jax.block_until_ready)
 
     def wait_to_write(self):
-        jax.block_until_ready(self._data)
+        _fetch(self._data, jax.block_until_ready)
 
     # -------------------------------------------------------------- autograd
     def attach_grad(self, grad_req: str = "write"):
@@ -533,3 +533,29 @@ def waitall():
         jax.effects_barrier()
     except Exception:
         pass
+
+
+# ------------------------------------------------- the wait for the device
+# After the last line on purpose: every line above keeps its number, and
+# with it the compile cache's key of each program traced through this file
+# (PERF.md, PR 27).
+from . import telemetry as _telemetry  # noqa: E402
+
+
+def _item(data):
+    return data.item()
+
+
+def _fetch(data, how):
+    """``how(data)`` where the host asks for an array's value or waits for
+    it.  An array that has landed records nothing; one that has not is a
+    ``nd.fetch`` span around the wait (``bytes=``).  An array without
+    ``is_ready`` counts as landed.  With MXNET_TRACE=0 it is ``how(data)``
+    alone."""
+    if not _telemetry.trace_enabled():
+        return how(data)
+    landed = getattr(data, "is_ready", None)
+    if landed is None or landed():
+        return how(data)
+    with _telemetry.span("nd.fetch", bytes=int(getattr(data, "nbytes", 0))):
+        return how(data)

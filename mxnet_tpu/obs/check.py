@@ -113,7 +113,7 @@ def _serve_load(router, stop_evt: threading.Event, qps: float = 15.0):
 
 def _train_loop(feed, step, stop_evt: threading.Event, errs: list):
     """Consume the feed through the fused step until told to stop —
-    the datafeed.wait_us / fused.step_us ratio IS the stall signal."""
+    the datafeed.wait_us / fused.step_gap_us ratio IS the stall signal."""
     import jax.numpy as jnp
     from ..ndarray import NDArray
     try:

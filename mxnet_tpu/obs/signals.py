@@ -9,9 +9,15 @@ and mirrored into the registry as fixed-point ``obs.*_ppm`` gauges
 ``tools/diagnose.py`` and bench rows all see them:
 
 * ``input_stall_frac`` — µs the consumer spent waiting on the feed
-  (``datafeed.wait_us``) per µs of fused train step (``fused.step_us``)
-  in the window; >1 means the accelerator is input-bound.
-* ``ckpt_pause_frac`` — ``checkpoint.pause_us`` overhead per step µs.
+  (``datafeed.wait_us``) per µs of step-to-step interval
+  (``fused.step_gap_us``: the whole loop, not the launch) in the
+  window; the share of the loop's time spent waiting for input.
+* ``ckpt_pause_frac`` — ``checkpoint.pause_us`` per µs of the same
+  interval.  Neither is reported for a frame without
+  ``fused.step_gap_us`` (no second step since the first or since a
+  ``sync()``; the histogram is kept under MXNET_TELEMETRY, as the frame
+  is, whatever MXNET_TRACE says): the launch alone (``fused.step_us``)
+  is 1/30 to 1/280 of a step and no denominator.
 * ``goodput`` — (admitted − rejected − abandoned) / offered request
   rate, clamped to [0, 1]; present only when the window offered load.
 * ``mfu`` — ``obs.model_flops_per_step`` (published by the fused
@@ -67,16 +73,17 @@ def compute(frame: dict) -> Dict[str, float]:
     out: Dict[str, float] = {}
 
     step_q = quants.get("fused.step_us")
-    step_us_per_s = _win_sum_us(step_q)         # µs of step per second
     if step_q:
         out["steps_per_s"] = float(step_q.get("rate", 0.0))
         if step_q.get("p50_us") is not None:
             out["step_p50_us"] = float(step_q["p50_us"])
-    if step_us_per_s > 0.0:
+    # µs of step-to-step interval per second of the window
+    gap_us_per_s = _win_sum_us(quants.get("fused.step_gap_us"))
+    if gap_us_per_s > 0.0:
         out["input_stall_frac"] = \
-            _win_sum_us(quants.get("datafeed.wait_us")) / step_us_per_s
+            _win_sum_us(quants.get("datafeed.wait_us")) / gap_us_per_s
         out["ckpt_pause_frac"] = \
-            _win_sum_us(quants.get("checkpoint.pause_us")) / step_us_per_s
+            _win_sum_us(quants.get("checkpoint.pause_us")) / gap_us_per_s
 
     offered = rates.get("serve.requests", 0.0)
     if offered > 0.0:

@@ -293,7 +293,7 @@ class FusedTrainStep:
         # rotate the per-step trace id: this step's span, the DataFeed
         # wait that follows it and any checkpoint pause share one trace
         _telemetry.set_current_trace()
-        with _telemetry.span("train.step", step=self._opt.num_update + 1):
+        with _step_span(self, self._opt.num_update + 1):
             with _building() if first else _NOT_BUILDING:
                 with _telemetry.span("train.prep"):
                     x_raw, y_raw = self._prepare(x, y)
@@ -359,7 +359,7 @@ class FusedTrainStep:
                 self._params[k]._data = NDArray(self._fr[k])
 
     def sync(self):
-        jax.block_until_ready(self._tr)
+        jax.block_until_ready(_drained(self)._tr)
 
 
 class TrainerFusedStep:
@@ -629,8 +629,8 @@ class TrainerFusedStep:
             # _fused_step is about to commit — continues across a
             # checkpoint restore because num_update is restored state)
             _telemetry.set_current_trace()
-            with _telemetry.span("train.step",
-                                 step=int(self._opt.num_update) + 1):
+            with _step_span(self,
+                            int(self._opt.num_update) + 1):
                 out = self._fused_step(x_raw, y_raw, batch_size)
             if out is not None:
                 return out
@@ -745,7 +745,7 @@ class TrainerFusedStep:
     def sync(self):
         for n in self._tr_names or ():
             jax.block_until_ready(self._params[n]._data._data)
-        _publish_aux(self)
+        _publish_aux(_drained(self))
 
     def hlo_text(self, x, y):
         """The compiled step program's HLO text for a batch like ``(x,
@@ -832,6 +832,115 @@ def _publish_aux(step):
             worst = max(worst or 0.0, float(held.max() / held.mean()))
     if worst is not None:
         _telemetry.gauge_set("moe.load_max_over_mean", int(1000 * worst))
+
+
+# ------------------------------------------------------- step-to-step gaps
+# Below the step builders on purpose: what is traced above keeps its line
+# numbers, and with them the compile cache's keys (PERF.md, PR 27).
+import collections  # noqa: E402
+import resource  # noqa: E402
+import statistics  # noqa: E402
+import threading  # noqa: E402
+
+# A gap is a stall where it passes both 1.25 x the median of the step
+# object's last 16 gaps and that median + 20 ms.
+_STALL_RATIO = 1.25
+_STALL_FLOOR_US = 20_000
+_STALL_HISTORY = 16
+
+
+class _StepGaps:
+    """What a step object keeps from one ``__call__`` to the next: the
+    wall clock at the last start (None before the first step and after a
+    ``sync()``), the gaps before it and, while spans are recorded, the
+    calling thread's CPU clock at the last start and the process's
+    ``getrusage`` at the first step or the last stall."""
+
+    __slots__ = ("wall", "cpu", "gaps", "rusage")
+
+    def __init__(self, tracing):
+        self.wall = self.cpu = None
+        self.gaps = collections.deque(maxlen=_STALL_HISTORY)
+        self.rusage = resource.getrusage(resource.RUSAGE_SELF) \
+            if tracing else None
+
+
+def _drained(step):
+    """``step``, its last start forgotten: ``sync()`` has fetched all that
+    was in flight, so the interval up to the next step holds a drain the
+    caller asked for.  That step carries no ``gap_us`` and is no stall;
+    the gaps before the drain stay the detector's history (the loop's
+    period has not changed, and the step after the next refills the
+    pipeline in no time at all)."""
+    kept = step.__dict__.get("_gaps")
+    if kept is not None:
+        kept.wall = None
+    return step
+
+
+def _step_span(owner, step):
+    """The ``train.step`` span of ``owner``'s step number ``step``.  From
+    the second step after the object's first or a ``sync()``,
+    ``fused.step_gap_us`` observes the time since the previous step
+    *started* (under the telemetry switch: two clock reads, and the
+    observability signals divide by it).  While spans are recorded the
+    span carries that ``gap_us`` and ``cpu_us``, the calling thread's CPU
+    time over the interval, and a gap that passes the two limits above
+    also leaves a ``train.stall`` span over its excess, with what the host
+    can say about the interval: a thread that was running for the excess
+    was in Python, one that was not was blocked or descheduled."""
+    tracing = _telemetry.trace_enabled()
+    if not tracing and not _telemetry.enabled():
+        owner._gaps = None          # switched on later, it starts afresh
+        return _telemetry.span("train.step", step=step)
+    wall = time.perf_counter_ns()
+    cpu = time.thread_time_ns() if tracing else None
+    kept = owner.__dict__.get("_gaps")
+    if kept is None:
+        kept = owner._gaps = _StepGaps(tracing)
+    was_wall, was_cpu, kept.wall, kept.cpu = kept.wall, kept.cpu, wall, cpu
+    if was_wall is None:            # the first step, or one after a sync()
+        return _telemetry.span("train.step", step=step)
+    gap_us = (wall - was_wall) // 1000
+    _telemetry.observe("fused.step_gap_us", gap_us)
+    if cpu is None or was_cpu is None:      # spans are off, or were
+        kept.gaps.append(gap_us)
+        return _telemetry.span("train.step", step=step)
+    cpu_us = (cpu - was_cpu) // 1000
+    median = statistics.median(kept.gaps) if kept.gaps else gap_us
+    kept.gaps.append(gap_us)
+    if gap_us > _STALL_RATIO * median and gap_us > median + _STALL_FLOOR_US:
+        _record_stall(kept, step=step, gap_us=gap_us,
+                      excess_us=int(gap_us - median), cpu_us=cpu_us)
+    return _telemetry.span("train.step", step=step, gap_us=gap_us,
+                           cpu_us=cpu_us)
+
+
+def _record_stall(kept, gap_us, excess_us, **attrs):
+    """One ``train.stall`` span back-dated over the excess of the gap that
+    ends now; counter ``fused.stalls``.  From the ring, over the gap:
+    ``waits`` and ``wait_us`` are this thread's ``nd.fetch`` spans,
+    ``ready`` that there was none (whatever it fetched had landed: the
+    device had finished and the host was late), ``gc_us`` the ``host.gc``
+    spans of any thread.  The faults and context switches are the
+    process's since the previous stall or the first step."""
+    NAME, START, DUR, TID = 3, 4, 5, 6              # fields of a span record
+    now_us = time.time_ns() // 1000
+    since, me = now_us - gap_us, threading.get_ident()
+    spans = [s for s in _telemetry.trace_spans() if s[START] >= since]
+    waited = [s[DUR] for s in spans
+              if s[NAME] == "nd.fetch" and s[TID] == me]
+    after = resource.getrusage(resource.RUSAGE_SELF)
+    before, kept.rusage = kept.rusage or after, after
+    _telemetry.counter_add("fused.stalls")
+    _telemetry.record_span(
+        "train.stall", now_us - excess_us, excess_us, gap_us=gap_us,
+        excess_us=excess_us, waits=len(waited), wait_us=sum(waited),
+        ready=not waited,
+        gc_us=sum(s[DUR] for s in spans if s[NAME] == "host.gc"),
+        majflt=after.ru_majflt - before.ru_majflt,
+        nivcsw=after.ru_nivcsw - before.ru_nivcsw,
+        nvcsw=after.ru_nvcsw - before.ru_nvcsw, **attrs)
 
 
 # --------------------------------------------------------------------- check

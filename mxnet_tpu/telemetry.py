@@ -32,7 +32,9 @@ registry so chrome traces and scrapes share names.
 from __future__ import annotations
 
 import atexit
+import collections
 import ctypes
+import gc
 import json
 import os
 import re
@@ -50,7 +52,8 @@ __all__ = ["snapshot", "raw_snapshot", "summary", "dump_prometheus", "dump",
            "reset", "enabled", "set_enabled", "counter_add", "gauge_set",
            "observe", "timed", "register_ring", "register_publisher",
            "quantile", "quantile_from_hist", "BUCKET_BOUNDS_US", "SECTIONS",
-           "span", "trace_enabled", "set_trace_enabled", "trace_header",
+           "span", "record_span", "trace_enabled", "set_trace_enabled",
+           "trace_header",
            "parse_trace_header", "current_context", "set_current_trace",
            "dump_trace", "trace_events", "trace_spans", "trace_stats",
            "trace_reset", "TRACE_HEADER"]
@@ -540,31 +543,55 @@ class span:
         tl.trace_id, tl.span_id = self._prev
         if exc_type is not None and "error" not in self.attrs:
             self.attrs["error"] = exc_type.__name__
-        ident = threading.get_ident()
-        if ident not in _tid_names:
-            _tid_names[ident] = threading.current_thread().name
         _span_recorder.record(
             (self._trace_id, self._span_id, self._parent_id, self.name,
-             self._t0, max(0, t_end - self._t0), ident,
+             self._t0, max(0, t_end - self._t0), _named_ident(),
              self.attrs or None, self._links))
         self._t0 = None
         return False
 
 
+def _named_ident() -> int:
+    """The calling thread's ident, its name kept for the "M" rows."""
+    ident = threading.get_ident()
+    if ident not in _tid_names:
+        _tid_names[ident] = threading.current_thread().name
+    return ident
+
+
+def record_span(name: str, t_start_us, dur_us, **attrs):
+    """Write a span whose interval is already past into the flight
+    recorder: ``t_start_us`` is wall-clock microseconds as ``span``
+    stamps them (``time.time_ns() // 1000``).  It joins the calling
+    thread's current trace as a child of its open span, and opens no
+    ``TraceAnnotation``: the profiler cannot be told of the past.  With
+    MXNET_TRACE=0 it records nothing."""
+    if not _trace_on:
+        return
+    tl = _trace_tl
+    _span_recorder.record(
+        (tl.trace_id or _new_id(), _new_id(), tl.span_id, name,
+         int(t_start_us), max(0, int(dur_us)), _named_ident(),
+         attrs or None, None))
+
+
 def trace_spans() -> List[tuple]:
     """The flight recorder's live contents, oldest first — raw record
     tuples for tests and in-process analysis."""
+    _drain_gc()
     return _span_recorder.spans()
 
 
 def trace_stats() -> dict:
     """{"spans": live records, "dropped": ring overwrites} — recorder
     pressure, embedded per bench row."""
+    _drain_gc()
     return _span_recorder.stats()
 
 
 def trace_reset():
     """Clear the span ring (drop counters included)."""
+    _gc_seen.clear()
     _span_recorder.reset()
 
 
@@ -590,7 +617,7 @@ def trace_events() -> List[dict]:
     ]
     seen_tids = set()
     for (trace_id, span_id, parent_id, name, t_start_us, dur_us, tid,
-         attrs, links) in _span_recorder.spans():
+         attrs, links) in trace_spans():
         if tid not in seen_tids:
             seen_tids.add(tid)
             evs.append({"ph": "M", "name": "thread_name", "pid": pid,
@@ -974,15 +1001,75 @@ def _on_usr2(signum, frame):
         _prev_usr2(signum, frame)
 
 
+# ------------------------------------------------------------ the collector
+# One ``gc.callbacks`` entry names the collector's pauses: every thread
+# stands still while one runs (it holds the interpreter lock), so a long
+# one is a stall of the training loop whatever thread set it off.  The
+# callback runs inside whatever bytecode set the collection off, which may
+# hold a registry or ring lock (neither is reentrant): it takes no lock and
+# calls nothing of this module, it only notes the collection in ``_gc_seen``
+# (a deque's append is one C call).  ``_drain_gc`` writes the notes into
+# the ring later, from a reader of the ring.  A collection cannot start
+# inside another, so one slot of module state carries "start" to "stop".
+_GC_SPAN_US = 1000          # a younger generation's collection is a span from here
+_gc_t0 = 0                  # perf_counter_ns at "start" while tracing, else 0
+_gc_ann = None              # the open generation-2 collection's TraceAnnotation
+_gc_seen: "collections.deque" = collections.deque(maxlen=1024)
+
+
+def _on_gc(phase, info):
+    """Note a generation-2 collection, or a younger one of ``_GC_SPAN_US``
+    or more, for ``_drain_gc`` to write as a ``host.gc`` span; shorter
+    young ones leave nothing: the ring must not fill with generation-0
+    collections.  A generation-2 collection is also a ``TraceAnnotation``
+    from "start" to "stop" (the profiler's own buffer, no lock of ours),
+    once a span has loaded the class: nothing is imported in here."""
+    global _gc_t0, _gc_ann
+    if phase == "start":
+        if _trace_on:
+            _gc_t0 = time.perf_counter_ns()
+            if info["generation"] == 2 and _TraceAnnotation is not None:
+                _gc_ann = _TraceAnnotation("host.gc", generation=2)
+                _gc_ann.__enter__()
+        return
+    if not _gc_t0:
+        return
+    ns, _gc_t0 = time.perf_counter_ns() - _gc_t0, 0
+    if _gc_ann is not None:
+        ann, _gc_ann = _gc_ann, None
+        ann.__exit__(None, None, None)
+    if info["generation"] == 2 or ns >= _GC_SPAN_US * 1000:
+        _gc_seen.append(((time.time_ns() - ns) // 1000, ns // 1000,
+                         threading.get_ident(), info["generation"],
+                         info.get("collected", 0)))
+
+
+def _drain_gc():
+    """Write the collections ``_on_gc`` has noted into the ring as root
+    ``host.gc`` spans on the thread that ran them.  Called by the ring's
+    readers before they read, outside any lock."""
+    while _gc_seen:
+        try:
+            t_start_us, dur_us, ident, gen, found = _gc_seen.popleft()
+        except IndexError:              # another reader drained it
+            return
+        _span_recorder.record(
+            (_new_id(), _new_id(), None, "host.gc", t_start_us, dur_us,
+             ident, {"generation": gen, "collected": found}, None))
+
+
 def _install_hooks():
     """SIGUSR2 → dump (MXNET_TELEMETRY_SIGNAL=0 opts out), and
     MXNET_TELEMETRY_DUMP_ON_EXIT=1 → dump at interpreter exit.  When
     MXNET_TRACE_DIR is set every process also leaves its chrome-trace
-    shard there at exit (the fleet members' mergeable artifacts).
+    shard there at exit (the fleet members' mergeable artifacts).  With
+    telemetry enabled the collector's pauses are noted (``_on_gc``).
     Signal installation only works on the main thread — skipped
     silently elsewhere (e.g. when the package is imported from a
     worker)."""
     global _prev_usr2
+    if enabled():
+        gc.callbacks.append(_on_gc)
     if os.environ.get("MXNET_TELEMETRY_DUMP_ON_EXIT",
                       "").lower() in ("1", "true", "on"):
         atexit.register(lambda: dump(reason="exit"))

@@ -327,6 +327,7 @@ def test_a_batch_is_handed_over_once_its_copy_has_landed(monkeypatch):
     """A staged batch whose copy is still in flight is waited for inside a
     ``datafeed.wait`` span and the time counts in ``consumer_wait_s``; one
     that has landed records nothing (the fast path)."""
+    from mxnet_tpu import telemetry
     from mxnet_tpu.io import DataFeed
     from mxnet_tpu.ndarray import NDArray
 
@@ -348,10 +349,16 @@ def test_a_batch_is_handed_over_once_its_copy_has_landed(monkeypatch):
     with DataFeed(iter([(x, x)] * 2), depth=0) as feed:
         monkeypatch.setattr(
             feed, "_stage", lambda item: (NDArray(late), NDArray(landed)))
+        telemetry.trace_reset()
         next(feed)
         waited = feed.stats()["consumer_wait_s"]
         assert late.blocked == 1 and landed.blocked == 0
         assert waited >= 0.03
+        if telemetry.trace_enabled():
+            # the draw (sync mode) and the wait for the copy name batch 0
+            assert [(s[7]["mode"], s[7]["batch"])
+                    for s in telemetry.trace_spans()
+                    if s[3] == "datafeed.wait"] == [("sync", 0), ("copy", 0)]
         next(feed)                     # both have landed now: nothing more
         assert late.blocked == 1
         assert feed.stats()["consumer_wait_s"] == waited

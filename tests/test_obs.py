@@ -194,9 +194,12 @@ def test_signals_compute():
         "gauges": {"serve.queue_depth": 64,
                    "obs.model_flops_per_step": 1_000_000},
         "quantiles": {
+            # the launch alone: 1 ms of a 10 ms step-to-step interval
             "fused.step_us": {"rate": 4.0, "mean_us": 1000.0,
                               "p50_us": 900.0},
-            "datafeed.wait_us": {"rate": 4.0, "mean_us": 500.0}},
+            "fused.step_gap_us": {"rate": 4.0, "mean_us": 10000.0},
+            "datafeed.wait_us": {"rate": 4.0, "mean_us": 5000.0},
+            "checkpoint.pause_us": {"rate": 1.0, "mean_us": 2000.0}},
     }
     old = os.environ.get("MXNET_OBS_PEAK_FLOPS")
     os.environ["MXNET_OBS_PEAK_FLOPS"] = "1e8"
@@ -207,7 +210,10 @@ def test_signals_compute():
             os.environ.pop("MXNET_OBS_PEAK_FLOPS", None)
         else:
             os.environ["MXNET_OBS_PEAK_FLOPS"] = old
+    # both over the step-to-step interval, not over the launch
     assert sig["input_stall_frac"] == pytest.approx(0.5)
+    assert sig["ckpt_pause_frac"] == pytest.approx(0.05)
+    assert sig["step_p50_us"] == pytest.approx(900.0)
     assert sig["goodput"] == pytest.approx(0.8)
     assert sig["steps_per_s"] == pytest.approx(4.0)
     assert sig["retrace_rate"] == pytest.approx(0.5)
@@ -221,8 +227,28 @@ def test_signals_compute():
     # steps but no waits -> stall is a true 0 (clears the alert)
     sig3 = obs_signals.compute({
         "rates": {}, "gauges": {},
-        "quantiles": {"fused.step_us": {"rate": 4.0, "mean_us": 1000.0}}})
+        "quantiles": {"fused.step_us": {"rate": 4.0, "mean_us": 1000.0},
+                      "fused.step_gap_us": {"rate": 4.0,
+                                            "mean_us": 10000.0}}})
     assert sig3["input_stall_frac"] == 0.0
+    assert sig3["ckpt_pause_frac"] == 0.0
+
+
+def test_signals_without_the_step_gap_report_no_stall_fraction():
+    """A frame whose window saw no step follow another (or one from a
+    program before PR 38) holds the launch's histogram alone: the two
+    fractions are left out, never divided by the launch; the step rate
+    keeps its input."""
+    sig = obs_signals.compute({
+        "rates": {}, "gauges": {},
+        "quantiles": {"fused.step_us": {"rate": 4.0, "mean_us": 1000.0,
+                                        "p50_us": 900.0},
+                      "datafeed.wait_us": {"rate": 4.0, "mean_us": 500.0},
+                      "checkpoint.pause_us": {"rate": 1.0,
+                                              "mean_us": 2000.0}}})
+    assert "input_stall_frac" not in sig and "ckpt_pause_frac" not in sig
+    assert sig["steps_per_s"] == pytest.approx(4.0)
+    assert sig["step_p50_us"] == pytest.approx(900.0)
 
 
 def test_signals_published_as_ppm_gauges(enabled_telemetry):
@@ -301,7 +327,10 @@ def test_build_report_roles_signals_straggler():
                 "rates": {"fused.steps": 5.0 * (1 + t)},   # regressing
                 "quantiles": {"fused.step_us":
                               {"p50_us": p50, "rate": 5.0,
-                               "mean_us": p50}},
+                               "mean_us": p50},
+                              "fused.step_gap_us":
+                              {"p50_us": 20 * p50, "rate": 5.0,
+                               "mean_us": 20 * p50}},
                 "signals": {"input_stall_frac": 0.1, "mfu": 0.3}})
     rep = tool.build_report({"frames": frames})
     assert rep["roles"]["serve"]["nonzero_rates"] == 3

@@ -301,6 +301,9 @@ def test_spans_land_in_a_profiler_trace(tmp_path, tracing_on, entry):
     steps = sorted(e for e in events if e[0] == "train.step")
     assert len(steps) == 3
     assert [int(s[3]["step"]) for s in steps] == [1, 2, 3]
+    # the step-to-step interval rides on the annotation from the second on
+    assert ["gap_us" in s[3] and "cpu_us" in s[3] for s in steps] == \
+        [False, True, True]
 
     def inside(name, outer):
         return [e for e in events if e[0] == name
