@@ -1147,9 +1147,13 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
     ``O = (Q exp G) S_0 + A_qk U`` with the same pairwise decays between
     ``q_t`` and ``k_s``, s <= t.  ``(I + A)^{-1}`` is applied to ``beta V``
     and ``beta K exp G`` for all chunks at once (one triangular solve);
-    only ``U = U~ - W S_0``, the output and the state's update run in a
-    ``lax.scan`` over the chunks (`_delta_rule_chunked`, which
-    `gdn_chunked` shares).
+    only ``U = U~ - W S_0``, the output and the state's update run chunk
+    after chunk (`_delta_rule_chunked`, which `gdn_chunked` shares): as the
+    kernel pair ``mx_delta_rule_fwd`` / ``mx_delta_rule_bwd`` with the state
+    in VMEM where `pallas_kernels.delta_rule_use_pallas` says so (one TPU,
+    ``chunk`` 64, dk and dv in 8s), as a ``lax.scan`` elsewhere (the CPU, a
+    mesh, other chunks).  The pairwise products and the solve are XLA's on
+    both routes.
 
     The decay is per channel, so ``A`` is no product of two factors that
     stay finite (``exp(-G)`` overflows under a strong decay).  As
@@ -1163,7 +1167,8 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
     0``, ``g = 0``) and cut back; a ``chunk`` that ``KDA_SUB`` does not
     divide is one sub-block."""
     _count_route("kda.xla_chunked")
-    return _delta_rule_chunked(q, k, v, g, beta, chunk, _pairs_by_channel)
+    return _delta_rule_chunked(q, k, v, g, beta, chunk, _pairs_by_channel,
+                               "kda")
 
 
 def _pairs_by_channel(q, k, G):
@@ -1198,12 +1203,20 @@ def _pairs_by_channel(q, k, G):
     return pair.reshape(2, B, H, nc, chunk, chunk)
 
 
-def _delta_rule_chunked(q, k, v, g, beta, chunk, pairs):
+def _delta_rule_chunked(q, k, v, g, beta, chunk, pairs, kind):
     """The gated delta rule by chunks for a log-decay ``g`` (B, T, H, dk)
     a channel or (B, T, H, 1) a head; ``pairs(q, k, G)`` gives the pairwise
-    decayed products inside a chunk.  Everything after them broadcasts over
-    the decay's last axis: the one triangular solve and the one scan over
-    the chunk states of this file."""
+    decayed products inside a chunk; ``kind`` ("kda" or "gdn") names the
+    counters.  Everything after the pairs broadcasts over the decay's last
+    axis: the one triangular solve of this file (XLA's on every route) and
+    the one recurrence over the chunk states — `pallas_kernels.
+    delta_rule_fused` (``mx_delta_rule_fwd`` / ``mx_delta_rule_bwd``: the
+    state in VMEM, the chunk's tiles read in place, ``w`` and ``u0`` out of
+    the solve's one result ``[w | u0]``) where `delta_rule_use_pallas` says
+    so, `_delta_states_scan` elsewhere.  The three inner scopes
+    ``delta.pairs``, ``delta.solve`` and ``delta.states`` split the mixers'
+    ``kda.scan`` / ``gdn.scan`` in a device trace."""
+    from . import pallas_kernels as _pk
     B, T, H, dk = k.shape
     dv = v.shape[-1]
     f32, out_dtype = jnp.float32, v.dtype
@@ -1220,15 +1233,39 @@ def _delta_rule_chunked(q, k, v, g, beta, chunk, pairs):
     q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
     beta = chunks(beta[..., None])                        # (B,H,nc,chunk,1)
     G = jnp.cumsum(g, axis=3)
-    a_kk, a_qk = pairs(q, k, G)
-    a_kk = beta * jnp.tril(a_kk, -1)
+    with jax.named_scope("delta.pairs"):
+        a_kk, a_qk = pairs(q, k, G)
     eg = jnp.exp(G)
-    solved = lax.linalg.triangular_solve(
-        a_kk, beta * jnp.concatenate([v, k * eg], axis=-1), left_side=True,
-        lower=True, unit_diagonal=True)
-    u0, w = solved[..., :dv], solved[..., dv:]
+    fused = _pk.delta_rule_use_pallas(T + pad, H, dk, dv, chunk, kind)
+    # the kernels read w and u0 out of the solve's one result where it lies
+    rhs = [k * eg, v] if fused else [v, k * eg]
+    with jax.named_scope("delta.solve"):
+        solved = lax.linalg.triangular_solve(
+            beta * jnp.tril(a_kk, -1),
+            beta * jnp.concatenate(rhs, axis=-1), left_side=True,
+            lower=True, unit_diagonal=True)
     g_end = eg[..., -1, :]                                # (B,H,nc,dk or 1)
     k_end = k * jnp.exp(G[..., -1:, :] - G)
+    with jax.named_scope("delta.states"):
+        if fused:
+            o = _pk.delta_rule_fused(kind, solved, q * eg, a_qk, k_end, g_end)
+        else:
+            o = _delta_states_scan(solved[..., :dv], solved[..., dv:], q * eg,
+                                   a_qk, k_end, g_end)
+    o = o.transpose(0, 2, 3, 1, 4)                        # (B,nc,chunk,H,dv)
+    return o.reshape(B, T + pad, H, dv)[:, :T].astype(out_dtype)
+
+
+def _delta_states_scan(u0, w, qg, a_qk, k_end, g_end):
+    """The recurrence over the chunk states as a ``lax.scan``: with the state
+    ``S`` (dk, dv) entering a chunk, ``u = u0 - w S``, ``o = qg S + a_qk u``,
+    ``S <- g_end * S + k_end^T u``.  ``u0`` (B, H, nc, chunk, dv); ``w``,
+    ``qg`` and ``k_end`` (B, H, nc, chunk, dk); ``a_qk`` (B, H, nc, chunk,
+    chunk); ``g_end`` (B, H, nc, dk or 1) -> ``o`` (B, H, nc, chunk, dv).
+    `pallas_kernels.delta_rule_fused` is the same quantity as a kernel pair."""
+    f32 = jnp.float32
+    B, H, _, _, dv = u0.shape
+    dk = w.shape[-1]
 
     def step(S, xs):
         u0, w, qg, a_qk, k_end, g_end = xs
@@ -1244,9 +1281,8 @@ def _delta_rule_chunked(q, k, v, g, beta, chunk, pairs):
     by_chunk = lambda a: jnp.moveaxis(a, 2, 0)
     _, o = lax.scan(step, jnp.zeros((B, H, dk, dv), f32),
                     tuple(by_chunk(a) for a in
-                          (u0, w, q * eg, a_qk, k_end, g_end)))
-    o = o.transpose(1, 0, 3, 2, 4).reshape(B, T + pad, H, dv)
-    return o[:, :T].astype(out_dtype)
+                          (u0, w, qg, a_qk, k_end, g_end)))
+    return jnp.moveaxis(o, 0, 2)
 
 
 # ------------------------------------- gated delta rule, one decay a head
@@ -1275,12 +1311,15 @@ def gdn_chunked(q, k, v, g, beta, chunk=64):
     (B, T, H, dv).  The caller normalises and scales ``q`` and ``k``.
 
     It is `kda_chunked`'s computation (the WY / UT form: ``(I + A)`` solved
-    once for ``[beta V | beta K exp G]`` over all chunks, a ``lax.scan``
+    once for ``[beta V | beta K exp G]`` over all chunks, the recurrence
     over the chunk states, the same padding of a ``T`` that ``chunk`` does
     not divide) with the pairwise decays of a chunk from `_pairs_by_head`:
     fed the same decay on every channel `kda_chunked` gives the same result
     and pays for (B, H, T, KDA_SUB, dk) tiles that this does not make.  A
-    composition in XLA: it counts ``dispatch.gdn.xla_chunked``."""
+    composition in XLA but for the recurrence over the chunk states, which
+    is the kernel pair of `pallas_kernels.delta_rule_fused` where
+    `delta_rule_use_pallas` allows: it counts ``dispatch.gdn.xla_chunked``,
+    and ``dispatch.pallas.hits.gdn.<dk>`` or ``...fallbacks.gdn.<dk>``."""
     _count_route("gdn.xla_chunked")
     return _delta_rule_chunked(q, k, v, g[..., None], beta, chunk,
-                               _pairs_by_head)
+                               _pairs_by_head, "gdn")

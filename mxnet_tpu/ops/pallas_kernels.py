@@ -1187,3 +1187,203 @@ def rows_scatter_add(y3, tok, live, upd):
         interpret=_interpret(),
         name="mx_rows_scatter_add",
     )(tok, jnp.reshape(live, (1,)).astype(jnp.int32), upd, y3)
+
+
+# ------------------------- the gated delta rule's chunk states (KDA and GDN)
+#
+# `ops/nn.py::_delta_rule_chunked` ends in a recurrence over the chunks of a
+# head: with the state S (dk, dv) entering a chunk, u = u0 − w S,
+# o = qg S + a_qk u, S ← g_end ⊙ S + k_endᵀ u.  The grid is (B, H / heads a
+# step, T / chunk), chunks sequential: the state is a VMEM scratch, the
+# chunk's tiles are read in place from the (B, H, nc, chunk, ·) arrays the
+# composition holds, and a step takes several heads so that their chains of
+# dependent products overlap.  u0 and w are read out of the triangular
+# solve's one result `wu` = [w | u0] (no slice of it is made in HBM; Mosaic
+# takes the lane offset dk as it is).  The backward walks the
+# chunks in reverse with the state's cotangent as its scratch and makes u
+# again; the one residual the forward emits for it is the state entering
+# every chunk.
+#
+# The state is held transposed, (dv, dk): the decay of a chunk is then a row
+# (1, dk) — or (1, 1) where it is one number a head — that broadcasts over
+# it, and its cotangent is a sum over sublanes.  dk and dv are what the
+# model has (Mosaic takes 96 and 192 as they are: a block's last axis is the
+# array's).
+#
+# Precision: HBM arrays, the state, its cotangent, u and every sum are
+# float32; MXU operands are rounded to bfloat16, as for the kernels above.
+
+_DELTA_CHUNK = 64            # the chunk the route takes (both models')
+_DELTA_MAX_STATE = 256 * 256  # dk·dv of a head, each in whole lane tiles
+_DELTA_VMEM = 6 * 2 ** 20    # a step's blocks, once (the pipeline holds two)
+
+
+def _lanes(d):
+    """`d` lanes in whole 128-lane tiles."""
+    return -(-d // 128) * 128
+
+
+def _delta_fwd_kernel(wu_ref, qg_ref, a_ref, ke_ref, g_ref, o_ref, *rest):
+    """One chunk of a few heads: o, the state carried on, and (where the
+    call has an output for it) the state that entered, for the backward."""
+    *s_ref, st_ref = rest
+    f32 = jnp.float32
+    t, dv = o_ref.shape[3:]
+    dk = qg_ref.shape[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        st_ref[...] = jnp.zeros(st_ref.shape, f32)
+
+    for h in range(o_ref.shape[1]):
+        st = st_ref[h]                                       # (dv, dk)
+        if s_ref:
+            s_ref[0][0, h, 0] = st
+        sq = _mxu(st)
+        both = jax.lax.dot_general(
+            _mxu(jnp.concatenate([wu_ref[0, h, 0, :, :dk],
+                                  qg_ref[0, h, 0]], axis=0)),
+            sq, _NT, preferred_element_type=f32)             # (2t, dv)
+        uq = _mxu(wu_ref[0, h, 0, :, dk:] - both[:t])
+        o_ref[0, h, 0] = both[t:] + jnp.dot(_mxu(a_ref[0, h, 0]), uq,
+                                            preferred_element_type=f32)
+        st_ref[h] = g_ref[0, h, 0] * st + jax.lax.dot_general(
+            uq, _mxu(ke_ref[0, h, 0]), _TN, preferred_element_type=f32)
+
+
+def _delta_bwd_kernel(wu_ref, qg_ref, a_ref, ke_ref, g_ref, s_ref, do_ref,
+                      dwu_ref, dqg_ref, da_ref, dke_ref, dg_ref, dst_ref):
+    """One chunk of a few heads, chunks in reverse: the cotangents of the
+    five arrays (`dwu` = [dw | du0]) and the cotangent of the state that
+    entered, carried on.  `dst` holds the cotangent of the state the chunk
+    leaves, transposed as the state is."""
+    f32 = jnp.float32
+    dk = qg_ref.shape[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dst_ref[...] = jnp.zeros(dst_ref.shape, f32)
+
+    for h in range(do_ref.shape[1]):
+        st, dst = s_ref[0, h, 0], dst_ref[h]                 # (dv, dk)
+        sq, dsq = _mxu(st), _mxu(dst)
+        wq, qgq = _mxu(wu_ref[0, h, 0, :, :dk]), _mxu(qg_ref[0, h, 0])
+        keq, aq = _mxu(ke_ref[0, h, 0]), _mxu(a_ref[0, h, 0])
+        doq = _mxu(do_ref[0, h, 0])
+        uq = _mxu(wu_ref[0, h, 0, :, dk:] - jax.lax.dot_general(
+            wq, sq, _NT, preferred_element_type=f32))
+        du = jax.lax.dot_general(aq, doq, _TN, preferred_element_type=f32) + \
+            jax.lax.dot_general(keq, dsq, _NT, preferred_element_type=f32)
+        duq = _mxu(du)
+        dwu_ref[0, h, 0, :, dk:] = du
+        dwu_ref[0, h, 0, :, :dk] = -jnp.dot(duq, sq,
+                                            preferred_element_type=f32)
+        da_ref[0, h, 0] = jax.lax.dot_general(doq, uq, _NT,
+                                              preferred_element_type=f32)
+        dqg_ref[0, h, 0] = jnp.dot(doq, sq, preferred_element_type=f32)
+        dke_ref[0, h, 0] = jnp.dot(uq, dsq, preferred_element_type=f32)
+        g = g_ref[0, h, 0]
+        dg = jnp.sum(dst * st, axis=0, keepdims=True)        # (1, dk)
+        dg_ref[0, h, 0] = dg if g.shape == dg.shape else \
+            jnp.sum(dg, axis=1, keepdims=True)
+        # dSᵀ = doᵀ qg + g ⊙ dS'ᵀ − duᵀ w, the two products as one
+        dst_ref[h] = g * dst + jax.lax.dot_general(
+            jnp.concatenate([doq, duq], axis=0),
+            jnp.concatenate([qgq, -wq], axis=0), _TN,
+            preferred_element_type=f32)
+
+
+def _delta_heads_a_step(heads, arrays):
+    """The most heads (a divisor of `heads`) whose blocks of a step — one
+    head's (rows, lanes) of every array of `arrays`, float32, lanes in
+    whole tiles as VMEM holds them — stay within `_DELTA_VMEM`."""
+    one = 4 * sum(a.shape[-2] * _lanes(a.shape[-1]) for a in arrays)
+    return _fit_block(heads, max(1, _DELTA_VMEM // one))
+
+
+def _delta_call(kernel, name, ins, outs, reverse):
+    """One kernel over the (B, H / per, nc) grid, chunk k (or, `reverse`,
+    the k-th from the end) of `per` heads a step; every array is
+    (B, H, nc, rows, lanes), `ins` starts with wu and qg, and a block is
+    `per` heads' (rows, lanes) of one chunk.  The scratch is `per` states
+    (dv, dk)."""
+    from jax.experimental.pallas import tpu as pltpu
+    bsz, heads, nc = ins[0].shape[:3]
+    dk = ins[1].shape[-1]
+    dv = ins[0].shape[-1] - dk
+    per = _delta_heads_a_step(heads, list(ins) + list(outs))
+
+    def spec(a):
+        return pl.BlockSpec(
+            (1, per, 1) + a.shape[3:],
+            (lambda b, h, k: (b, h, nc - 1 - k, 0, 0)) if reverse else
+            (lambda b, h, k: (b, h, k, 0, 0)))
+
+    return pl.pallas_call(
+        kernel, out_shape=outs, grid=(bsz, heads // per, nc),
+        in_specs=[spec(a) for a in ins], out_specs=[spec(a) for a in outs],
+        scratch_shapes=[pltpu.VMEM((per, dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(), name=name)(*ins)
+
+
+def _delta_fwd_pallas(counted, wu, qg, a_qk, k_end, g_end, emit=False):
+    """→ [o (B, H, nc, chunk, dv)], with `emit` also the states entering
+    the chunks, transposed: (B, H, nc, dv, dk) float32.  `counted` is the
+    (kernel, last_dim) of the hit."""
+    f32 = jnp.float32
+    bsz, heads, nc, chunk, dk = qg.shape
+    dv = wu.shape[-1] - dk
+    _count("hits", *counted)
+    outs = [jax.ShapeDtypeStruct((bsz, heads, nc, chunk, dv), f32)]
+    if emit:
+        outs.append(jax.ShapeDtypeStruct((bsz, heads, nc, dv, dk), f32))
+    return _delta_call(_delta_fwd_kernel, "mx_delta_rule_fwd",
+                       (wu, qg, a_qk, k_end, g_end), outs, False)
+
+
+def delta_rule_use_pallas(t, heads, dk, dv, chunk, kind="kda"):
+    """The routing decision of `nn._delta_rule_chunked`'s recurrence: one
+    TPU (or the tests' interpret switch), chunks of `_DELTA_CHUNK` steps
+    (T is padded to whole chunks before it), dk and dv in whole sublane
+    tiles and a state of at most `_DELTA_MAX_STATE` numbers in VMEM.  A
+    "no" counts one fallback under `kind` ("kda" or "gdn"); the "yes" is
+    counted where the forward kernel is emitted."""
+    del t, heads          # any: T is in whole chunks, heads are grid steps
+    ok = (_FORCE_INTERPRET or _pb.one_tpu()) and chunk == _DELTA_CHUNK and \
+        dk % 8 == 0 and dv % 8 == 0 and \
+        _lanes(dk) * _lanes(dv) <= _DELTA_MAX_STATE
+    if not ok:
+        _count("fallbacks", kind, dk)
+    return ok
+
+
+def delta_rule_fused(kind, wu, qg, a_qk, k_end, g_end):
+    """The recurrence over the chunk states of `nn._delta_rule_chunked`,
+    read in place from its float32 arrays: wu (B, H, nc, chunk, dk + dv) =
+    [w | u0]; qg and k_end (B, H, nc, chunk, dk); a_qk (B, H, nc, chunk,
+    chunk); g_end (B, H, nc, dk) or (B, H, nc, 1) → o (B, H, nc, chunk,
+    dv).  `kind` ("kda" or "gdn") names the counter."""
+    return _delta_rule_tiles((kind, qg.shape[-1]), wu, qg, a_qk, k_end,
+                             g_end[..., None, :])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _delta_rule_tiles(counted, *tiles):
+    return _delta_fwd_pallas(counted, *tiles)[0]
+
+
+def _delta_vjp_fwd(counted, *tiles):
+    o, states = _delta_fwd_pallas(counted, *tiles, emit=True)
+    return o, (tiles, states)
+
+
+def _delta_vjp_bwd(counted, res, do):
+    tiles, states = res
+    return tuple(_delta_call(
+        _delta_bwd_kernel, "mx_delta_rule_bwd", tiles + (states, do),
+        [jax.ShapeDtypeStruct(a.shape, jnp.float32) for a in tiles], True))
+
+
+_delta_rule_tiles.defvjp(_delta_vjp_fwd, _delta_vjp_bwd)

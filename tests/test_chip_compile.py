@@ -260,28 +260,55 @@ def test_default_int8_routes_compile(one_chip, compiled_kernels):
         _compile(pallas_int8.qconv3x3_affine, qx, qw, v, v)
 
 
-def test_olmo_hybrid_mixers_fwd_and_grad_at_the_cell_shape(one_chip,
-                                                          compiled_kernels):
-    """What `olmo-hybrid-train-gdn` runs: 15 linear heads with keys 96 and
-    values 192 wide at T = 8192 through `ops/nn.py::gdn_chunked` (a
-    composition: no kernel, no per-channel (16, 96) decay tile, no (T, T)
-    matrix), and 15 query = 15 key-value heads of 128 behind the QK-norm,
-    which meet the causal attention kernels' rule."""
-    from mxnet_tpu.models import olmo_hybrid
+# name -> (operator, T, heads, dk, dv, a decay a channel)
+DELTA_CELLS = {
+    "olmo-hybrid-train-gdn": ("gdn_chunked", 8192, 15, 96, 192, False),
+    "solar-open2-train-kda": ("kda_chunked", 4096, 8, 128, 128, True),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(DELTA_CELLS))
+def test_delta_rule_fwd_and_grad_at_the_cell_shapes(one_chip, compiled_kernels,
+                                                    cell):
+    """What the two linear-attention cells run in each of their three mixers
+    through `ops/nn.py::gdn_chunked` / `kda_chunked`: the predicate says
+    yes, the program holds the named kernels under the default scoped VMEM
+    limit — 96 and 192 wide as they are, w and u0 sliced out of the solve's
+    result in VMEM at lane 96 — and no `while` loop (the chunk-state
+    `lax.scan`), no (T, T) matrix; the pairwise products and the triangular
+    solve are still XLA's."""
     from mxnet_tpu.ops import nn
+    name, t, heads, dk, dv, channel = DELTA_CELLS[cell]
+    fn = getattr(nn, name)
     s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                             sharding=one_chip)
-    args = (s(1, 8192, 15, 96), s(1, 8192, 15, 96), s(1, 8192, 15, 192),
-            s(1, 8192, 15), s(1, 8192, 15))
+    args = (s(1, t, heads, dk), s(1, t, heads, dk), s(1, t, heads, dv),
+            s(1, t, heads, dk) if channel else s(1, t, heads),
+            s(1, t, heads))
+    assert pallas_kernels.delta_rule_use_pallas(t, heads, dk, dv, 64)
+    text = _compile(fn, *args)
+    assert "mx_delta_rule_fwd" in text and "mx_delta_rule_bwd" not in text
+    assert " while(" not in text
     compiled = jax.jit(jax.grad(
-        lambda *a: jnp.sum(nn.gdn_chunked(*a) ** 2),
+        lambda *a: jnp.sum(fn(*a) ** 2),
         argnums=tuple(range(5)))).lower(*args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
-    assert "16,96]" not in text, "a per-channel decay tile was made"
-    _no_square(text, 8192)
+    assert "mx_delta_rule_fwd" in text and "mx_delta_rule_bwd" in text
+    assert " while(" not in text, "a loop over the chunk states is left"
+    if not channel:
+        assert "16,96]" not in text, "a per-channel decay tile was made"
+    _no_square(text, t)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
+
+def test_olmo_hybrid_attention_fwd_and_grad_at_the_cell_shape(
+        one_chip, compiled_kernels):
+    """What `olmo-hybrid-train-gdn` runs in its attention block: 15 query =
+    15 key-value heads of 128 behind the QK-norm, which meet the causal
+    attention kernels' rule."""
+    from mxnet_tpu.models import olmo_hybrid
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                            sharding=one_chip)
     assert pallas_kernels.causal_attention_use_pallas(8192, 15, 15, 128)
     proj, w = s(1, 8192, 1920), s(1920)
     text = _compile(jax.grad(

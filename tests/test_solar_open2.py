@@ -122,6 +122,8 @@ def test_fused_step_loss_is_the_reference_loss_and_counts_its_routes():
     # the checkpoint's transpose; the counters count the first only where
     # the second replays a cached trace
     assert routes == {"dispatch.kda.xla_chunked": 3,
+                      # no TPU here: the chunk states run as the lax.scan
+                      "dispatch.pallas.fallbacks.kda.8": 3,
                       "dispatch.moe.sorted_slots": 4,
                       "dispatch.attention.causal.xla_blocked": 1,
                       # head_dim 8 is no lane tile: the kernel says no
