@@ -110,12 +110,13 @@ def _experts_core(x, router_w, bias, up, down, *, held, top_k, scaling,
     return y.reshape(B, T, D), load
 
 
-def _attention_core(q, k, v, *, heads, kv, hd):
+def _attention_core(q, k, v, *, heads, kv, hd, window=None):
+    """Scope ``attn.core``; a windowed call's is ``attn.window``."""
     B, T, _ = q.shape
-    with jax.named_scope("attn.core"):
+    with jax.named_scope("attn.core" if window is None else "attn.window"):
         o = _nn.causal_gqa_attention(q.reshape(B, T, heads, hd),
                                      k.reshape(B, T, kv, hd),
-                                     v.reshape(B, T, kv, hd))
+                                     v.reshape(B, T, kv, hd), window=window)
     return o.reshape(B, T, heads * hd)
 
 

@@ -87,8 +87,8 @@ def _kda_core(q, k, v, f, b, gate, q_conv, k_conv, v_conv, dt_bias, a_log,
         return o * jax.nn.sigmoid(gate)
 
 
-def _gated_attention_core(q, k, v, gate, *, heads, kv, hd):
-    o = _attention_core(q, k, v, heads=heads, kv=kv, hd=hd)
+def _gated_attention_core(q, k, v, gate, *, heads, kv, hd, window=None):
+    o = _attention_core(q, k, v, heads=heads, kv=kv, hd=hd, window=window)
     with jax.named_scope("attn.gate"):
         return o * jax.nn.sigmoid(gate)
 

@@ -974,10 +974,13 @@ def ssd_chunked(x, dt, a, b, c, d=None, chunk=128):
     return y if d is None else y + x * d[:, None]
 
 
-def causal_gqa_attention(q, k, v, q_block=512, k_block=1024):
+def causal_gqa_attention(q, k, v, q_block=512, k_block=1024, window=None):
     """Causal ``softmax(q k^T / sqrt(hd)) v`` with grouped keys and values:
     ``q`` (B, T, Hq, hd), ``k`` and ``v`` (B, T, Hkv, hd), each key-value
-    head serving Hq / Hkv query heads -> (B, T, Hq, hd).
+    head serving Hq / Hkv query heads -> (B, T, Hq, hd).  With ``window``
+    (static) query i sees key j iff ``0 <= i - j < window``: itself and the
+    ``window - 1`` before it; key blocks wholly behind the window are
+    skipped like those above the diagonal.
 
     A loop over blocks of ``q_block`` query rows, and inside it a loop over
     blocks of ``k_block`` keys with the running maximum, sum and weighted
@@ -992,15 +995,17 @@ def causal_gqa_attention(q, k, v, q_block=512, k_block=1024):
     Where `pallas_kernels.causal_attention_use_pallas` says so (one TPU,
     head_dim in 128s, whole groups, T in 128s, a K/V head of at most
     8192 x 128) the same quantity is one forward and one backward Pallas
-    kernel that read the heads in place and keep every tile in VMEM."""
+    kernel that read the heads in place and keep every tile in VMEM
+    (``mx_causal_attn_*``; ``mx_window_attn_*`` with a window)."""
     from . import pallas_kernels as _pk
     B, T, Hq, hd = q.shape
     G = k.shape[2]
-    if _pk.causal_attention_use_pallas(T, Hq, G, hd):
+    if _pk.causal_attention_use_pallas(T, Hq, G, hd, window):
         return _pk.causal_gqa_attention_fused(
             q.reshape(B, T, Hq * hd), k.reshape(B, T, G * hd),
-            v.reshape(B, T, G * hd), Hq, G).reshape(q.shape)
-    _count_route("attention.causal.xla_blocked")
+            v.reshape(B, T, G * hd), Hq, G, None, window).reshape(q.shape)
+    _count_route("attention.causal.xla_blocked" if window is None
+                 else "attention.window.xla_blocked")
     r = Hq // G
     qb = q_block if T % q_block == 0 else T
     kb = k_block if T % k_block == 0 else T
@@ -1022,8 +1027,11 @@ def causal_gqa_attention(q, k, v, q_block=512, k_block=1024):
                 m, l, acc = carry
                 s = jnp.einsum("bgrqd,bgkd->bgrqk", q_i, k_j,
                                preferred_element_type=f32)
-                s = jnp.where(row[:, None] >= j * kb + jnp.arange(kb)[None],
-                              s, low)
+                key = j * kb + jnp.arange(kb)[None]
+                keep = row[:, None] >= key
+                if window is not None:
+                    keep &= row[:, None] - key < window
+                s = jnp.where(keep, s, low)
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1))
                 p = jnp.exp(s - m_new[..., None])
                 old = jnp.exp(m - m_new)
@@ -1031,8 +1039,10 @@ def causal_gqa_attention(q, k, v, q_block=512, k_block=1024):
                                 v_j, preferred_element_type=f32)
                 return (m_new, l * old + jnp.sum(p, axis=-1),
                         acc * old[..., None] + pv)
-            return lax.cond(j * kb <= i * qb + qb - 1, attend,
-                            lambda c: c, carry), None
+            needed = j * kb <= i * qb + qb - 1
+            if window is not None:      # its last key within the first row's
+                needed &= j * kb + kb - 1 > i * qb - window
+            return lax.cond(needed, attend, lambda c: c, carry), None
 
         init = (jnp.full((B, G, r, qb), low, f32),
                 jnp.zeros((B, G, r, qb), f32),
@@ -1043,6 +1053,34 @@ def causal_gqa_attention(q, k, v, q_block=512, k_block=1024):
 
     o = lax.map(rows, (jnp.arange(T // qb), qs))          # (nq,B,G,r,qb,hd)
     return o.transpose(1, 0, 4, 2, 3, 5).reshape(B, T, Hq, hd).astype(q.dtype)
+
+
+def rope_tables(positions, dim, theta=10000.0):
+    """Rotary embedding's tables for ``rotate_half`` pairing (channel c
+    with c + dim / 2): ``positions`` (T,) -> ``(cos, sin)``, each (T, dim)
+    float32, the angle of channel c and of c + dim / 2 being ``position *
+    theta ** (-2 c / dim)``; ``sin`` carries the minus sign of the first
+    half, so that ``rope`` is two multiplies and an add."""
+    c = jnp.arange(dim)
+    freq = theta ** (-(c % (dim // 2)).astype(jnp.float32) * 2.0 / dim)
+    angle = positions.astype(jnp.float32)[:, None] * freq[None]
+    return jnp.cos(angle), jnp.where(c < dim // 2, -1.0, 1.0) * jnp.sin(angle)
+
+
+@jax.jit
+def rope(x, cos, sin):
+    """``x cos + rotate_half(x) sin`` over the last axis of ``x``
+    (B, T, H, dim) with ``rope_tables``' (T, dim) tables:
+    ``(x1 cos - x2 sin, x2 cos + x1 sin)`` for the halves x1, x2.  A
+    program of its own wherever it is not traced into a larger one (the
+    tape's backward pass runs a recomputed block operation by operation):
+    the TPU compiler fails on the half-tile rotation compiled alone
+    (``IsFusibleUnalignedDUS``, libtpu 0.0.34) and takes it beside the
+    multiplies."""
+    half = x.shape[-1] // 2
+    xf = x.astype(jnp.float32)
+    out = xf * cos[:, None] + jnp.roll(xf, half, axis=-1) * sin[:, None]
+    return out.astype(x.dtype)
 
 
 def linear_cross_entropy(h, weight, labels, block=1024):

@@ -507,6 +507,15 @@ def self_attention_fused(qkv, heads):
 # float32, MXU operands rounded to bfloat16, q scaled in float32 before it
 # is rounded — what the two-scan composition in `ops/nn.py` computes at
 # XLA's DEFAULT precision.
+#
+# With a static `window` W (a row sees its own key and the W − 1 before
+# it) the same two bodies are the kernels `mx_window_attn_fwd` / `_bwd`:
+# the key loop of the query block starting at row `first` begins at block
+# max(0, first − W + 1) // block_k, the leading blocks that hold a key too
+# far back for the block's last row are masked (row − key < W), the middle
+# ones are not, the diagonal ones are.  Work, and the blocks of dk and dv
+# touched, follow the band.  Without a window the bodies trace to what
+# they were.
 
 _CAUSAL_BLOCK = 512          # query rows a grid step, keys a loop step
 _CAUSAL_MAX_HEAD = 8192 * 128    # T·hd of a K or V head whole in VMEM: the
@@ -515,10 +524,10 @@ _CAUSAL_MAX_HEAD = 8192 * 128    # T·hd of a K or V head whole in VMEM: the
 _CAUSAL_LOW = -1e30          # a masked score (the composition's)
 
 
-def _causal_blocks(t):
-    """→ (block_q, block_k): the most 128-row groups up to `_CAUSAL_BLOCK`
-    that divide t."""
-    return (128 * max(n for n in range(1, _CAUSAL_BLOCK // 128 + 1)
+def _causal_blocks(t, most=_CAUSAL_BLOCK):
+    """→ (block_q, block_k): the most 128-row groups up to `most` that
+    divide t."""
+    return (128 * max(n for n in range(1, most // 128 + 1)
                       if (t // 128) % n == 0),) * 2
 
 
@@ -527,6 +536,38 @@ def _causal_span(first, block_q, block_k):
     index `full` lie wholly at or below its first row (no mask), those
     from `full` to `need` cross the diagonal."""
     return (first + 1) // block_k, (first + block_q + block_k - 1) // block_k
+
+
+_WINDOW_BLOCK = 256          # a windowed call's rows and keys a step: a query
+#                              block needs window + block_q - 1 keys, so at
+#                              512 the band of 2048 in 8192 is 51 % of the
+#                              triangle's tiles, at 256 it is 48 %
+
+
+def _window_span(first, block_q, block_k, window):
+    """→ (lo, lead, full, need): of `_causal_span`'s blocks a query block
+    that sees the last `window` keys (row − key < window) visits those from
+    `lo`; those below `lead` hold a key too far back for its last row and
+    are masked, those from `lead` to `full` are not, those from `full` to
+    `need` cross the diagonal (and the window's edge, where it is that
+    near)."""
+    full, need = _causal_span(first, block_q, block_k)
+    lo = jnp.maximum(first - window + 1, 0) // block_k
+    edge = jnp.maximum(first + block_q - 1 - window + block_k, 0) // block_k
+    return lo, jnp.clip(edge, lo, full), full, need
+
+
+def window_tiles(t, window, blocks=None):
+    """→ (visited, causal): the (block_q, block_k) tiles the windowed
+    kernels' key loops run for one head of `t` rows, and those of the whole
+    causal triangle at the same blocks.  Counted from the shapes."""
+    block_q, block_k = blocks or _causal_blocks(t, _WINDOW_BLOCK)
+    visited = causal = 0
+    for first in range(0, t, block_q):
+        need = _causal_span(first, block_q, block_k)[1]
+        visited += need - max(first - window + 1, 0) // block_k
+        causal += need
+    return visited, causal
 
 
 def _causal_round_kv(k_ref, v_ref, kb_ref, vb_ref, block_k):
@@ -540,9 +581,44 @@ def _causal_round_kv(k_ref, v_ref, kb_ref, vb_ref, block_k):
     jax.lax.fori_loop(0, k_ref.shape[1] // block_k, rows, 0)
 
 
+def _causal_keep(row, key, window):
+    """The mask of a tile that crosses an edge."""
+    keep = row >= key
+    return keep if window is None else keep & (row - key < window)
+
+
+def _causal_loops(tile, carry, first, block_q, block_k, window):
+    """`tile(j, carry, masked, wide=1)` over the key blocks a query block
+    needs, in order: the window's masked leading edge (none without a
+    window), the blocks no mask touches, the masked diagonal.  A windowed
+    call takes the unmasked blocks two at a time (`wide=2`: one tile of
+    2 x block_k keys; what a tile costs beside its products - the row
+    maxima and sums, the rescaled accumulator - it then costs half as
+    often), and a last one alone where their number is odd."""
+    if window is None:
+        lead, (full, need) = 0, _causal_span(first, block_q, block_k)
+    else:
+        lo, lead, full, need = _window_span(first, block_q, block_k, window)
+        carry = jax.lax.fori_loop(
+            lo, lead, functools.partial(tile, masked=True), carry)
+        pairs = (full - lead) // 2
+        carry = jax.lax.fori_loop(
+            0, pairs, lambda p, c: tile(lead + 2 * p, c, masked=False,
+                                        wide=2), carry)
+        lead = lead + 2 * pairs
+    carry = jax.lax.fori_loop(lead, full,
+                              functools.partial(tile, masked=False), carry)
+    return jax.lax.fori_loop(full, need, functools.partial(tile, masked=True),
+                             carry)
+
+
 def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, kb_ref, vb_ref,
-                       *, scale, block_k):
-    """One query block of one query head: o and the rows' log-sum-exp."""
+                       *, scale, block_k, window=None):
+    """One query block of one query head: o and the rows' log-sum-exp.
+    A row of a windowed call whose keys in a leading block are all too far
+    back reads that block as if it saw all of it; its next real score
+    scales that to nothing (exp(LOW − m) = 0), and every row has one: its
+    own key."""
     h, i = pl.program_id(2), pl.program_id(3)
     block_q, d = q_ref.shape[1:]
 
@@ -553,16 +629,16 @@ def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, kb_ref, vb_ref,
     q = _mxu(q_ref[0].astype(jnp.float32), scale=scale)
     first = i * block_q
 
-    def tile(j, carry, masked):
+    def tile(j, carry, masked, wide=1):
         m, l, acc = carry
-        keys = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        keys = pl.ds(pl.multiple_of(j * block_k, block_k), wide * block_k)
         s = jax.lax.dot_general(q, kb_ref[keys, :], _NT,
                                 preferred_element_type=jnp.float32)
         if masked:
             shape = (block_q, block_k)
             row = first + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
             key = j * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-            s = jnp.where(row >= key, s, _CAUSAL_LOW)
+            s = jnp.where(_causal_keep(row, key, window), s, _CAUSAL_LOW)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         old = jnp.exp(m - m_new)
@@ -571,14 +647,11 @@ def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, kb_ref, vb_ref,
         return (m_new, l * old + jnp.sum(p, axis=-1, keepdims=True),
                 acc * old + pv)
 
-    full, need = _causal_span(first, block_q, block_k)
-    carry = (jnp.full((block_q, 1), _CAUSAL_LOW, jnp.float32),
-             jnp.zeros((block_q, 1), jnp.float32),
-             jnp.zeros((block_q, d), jnp.float32))
-    carry = jax.lax.fori_loop(0, full, functools.partial(tile, masked=False),
-                              carry)
-    m, l, acc = jax.lax.fori_loop(full, need,
-                                  functools.partial(tile, masked=True), carry)
+    m, l, acc = _causal_loops(
+        tile, (jnp.full((block_q, 1), _CAUSAL_LOW, jnp.float32),
+               jnp.zeros((block_q, 1), jnp.float32),
+               jnp.zeros((block_q, d), jnp.float32)),
+        first, block_q, block_k, window)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     # the statistics are a column (block_q, 1); the backward wants a row
     lse = jnp.broadcast_to(m + jnp.log(l), (block_q, 128))
@@ -587,7 +660,7 @@ def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, kb_ref, vb_ref,
 
 def _causal_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                        dq_ref, dk_ref, dv_ref, kb_ref, vb_ref,
-                       *, scale, block_k):
+                       *, scale, block_k, window=None):
     """One query block of one query head: its dq, and its part of the
     key-value head's dk and dv.  Scores are recomputed TRANSPOSED,
     (block_k, block_q), as in `_attn_bwd_kernel`: lse and Δ = Σ g·o are
@@ -608,8 +681,8 @@ def _causal_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     delta = delta_ref[0, 0]
     first = i * block_q
 
-    def tile(j, dq, masked):
-        keys = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+    def tile(j, dq, masked, wide=1):
+        keys = pl.ds(pl.multiple_of(j * block_k, block_k), wide * block_k)
         k = kb_ref[keys, :]
         s = jax.lax.dot_general(k, q, _NT,
                                 preferred_element_type=jnp.float32)
@@ -617,7 +690,7 @@ def _causal_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             shape = (block_k, block_q)
             key = j * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
             row = first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-            s = jnp.where(row >= key, s, _CAUSAL_LOW)
+            s = jnp.where(_causal_keep(row, key, window), s, _CAUSAL_LOW)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(vb_ref[keys, :], g, _NT,
                                  preferred_element_type=jnp.float32)
@@ -629,24 +702,27 @@ def _causal_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         return dq + jax.lax.dot_general(ds, k, _TN,
                                         preferred_element_type=jnp.float32)
 
-    full, need = _causal_span(first, block_q, block_k)
-    dq = jax.lax.fori_loop(0, full, functools.partial(tile, masked=False),
-                           jnp.zeros((block_q, d), jnp.float32))
-    dq = jax.lax.fori_loop(full, need, functools.partial(tile, masked=True),
-                           dq)
+    dq = _causal_loops(tile, jnp.zeros((block_q, d), jnp.float32), first,
+                       block_q, block_k, window)
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _causal_call(kernel, name, ins, outs, heads, kv, blocks, vmem):
+def _causal_call(kernel, name, ins, outs, heads, kv, blocks, vmem,
+                 window=None):
     """One kernel over the (B, Hkv, Hq/Hkv, T/block_q) grid.  `ins` and
     `outs` are (array or shape, kind) pairs: "q" a query head's
     (block_q, hd) block, "kv" a key-value head whole, "row" a query head's
     (1, block_q) block of a (B, Hq, 1, T) array of row statistics.
-    `blocks` = (block_q, block_k) is the tests'; None: `_causal_blocks`."""
+    `blocks` = (block_q, block_k) is the tests'; None: `_causal_blocks`,
+    up to `_WINDOW_BLOCK` with a `window`, which also names the kernel
+    `mx_window_attn_*`."""
     from jax.experimental.pallas import tpu as pltpu
     b, t, width = ins[0][0].shape
     d, r = width // heads, heads // kv
-    block_q, block_k = blocks or _causal_blocks(t)
+    block_q, block_k = blocks or _causal_blocks(
+        t, _CAUSAL_BLOCK if window is None else _WINDOW_BLOCK)
+    if window is not None:
+        name = name.replace("causal", "window")
     specs = {
         "q": pl.BlockSpec((1, block_q, d),
                           lambda b, g, h, i: (b, i, g * r + h)),
@@ -655,7 +731,8 @@ def _causal_call(kernel, name, ins, outs, heads, kv, blocks, vmem):
                             lambda b, g, h, i: (b, g * r + h, 0, i)),
     }
     return pl.pallas_call(
-        functools.partial(kernel, scale=d ** -0.5, block_k=block_k),
+        functools.partial(kernel, scale=d ** -0.5, block_k=block_k,
+                          window=window),
         out_shape=[a for a, _ in outs],
         grid=(b, kv, r, t // block_q),
         in_specs=[specs[kind] for _, kind in ins],
@@ -677,20 +754,30 @@ def _causal_vmem(t, d, whole_f32):
     return t * d * (8 * whole_f32 + 4) + (24 << 20)
 
 
-def _causal_fwd_pallas(q, k, v, heads, kv, blocks):
+def _causal_counts_as(window):
+    """The `<kernel>` of a call's `dispatch.pallas.{hits,fallbacks}.*`."""
+    return "causal_attention" if window is None else "window_attention"
+
+
+def _causal_fwd_pallas(q, k, v, heads, kv, blocks, window=None):
     """→ o (B, T, Hq·hd), lse (B, Hq, 1, T) float32."""
     b, t, width = q.shape
     d = width // heads
-    _count("hits", "causal_attention", d)
+    _count("hits", _causal_counts_as(window), d)
+    if window is not None:
+        tele = _pb._tele()
+        for what, n in zip(("visited", "causal"),
+                           window_tiles(t, window, blocks)):
+            tele.counter_add(f"attn.window.tiles_{what}", b * heads * n)
     return _causal_call(
         _causal_fwd_kernel, "mx_causal_attn_fwd",
         ((q, "q"), (k, "kv"), (v, "kv")),
         ((jax.ShapeDtypeStruct(q.shape, q.dtype), "q"),
          (jax.ShapeDtypeStruct((b, heads, 1, t), jnp.float32), "row")),
-        heads, kv, blocks, _causal_vmem(t, d, 2))
+        heads, kv, blocks, _causal_vmem(t, d, 2), window)
 
 
-def _causal_bwd_pallas(q, k, v, o, lse, g, heads, kv, blocks):
+def _causal_bwd_pallas(q, k, v, o, lse, g, heads, kv, blocks, window=None):
     """→ dq, dk, dv (dk and dv float32: sums over a group's heads)."""
     b, t, width = q.shape
     d = width // heads
@@ -704,38 +791,42 @@ def _causal_bwd_pallas(q, k, v, o, lse, g, heads, kv, blocks):
          (delta, "row")),
         ((jax.ShapeDtypeStruct(q.shape, q.dtype), "q"), (kv_sum, "kv"),
          (kv_sum, "kv")),
-        heads, kv, blocks, _causal_vmem(t, d, 4))
+        heads, kv, blocks, _causal_vmem(t, d, 4), window)
 
 
-def causal_attention_use_pallas(t, heads, kv, d):
+def causal_attention_use_pallas(t, heads, kv, d, window=None):
     """The routing decision of `nn.causal_gqa_attention`: one TPU (or the
     tests' interpret switch), head_dim in lane tiles, whole groups, a
     length in 128s whose K/V head VMEM holds whole.  A "no" counts one
-    fallback; the "yes" is counted where the forward kernel is emitted."""
+    fallback (`window_attention`'s for a windowed call); the "yes" is
+    counted where the forward kernel is emitted."""
     ok = (_FORCE_INTERPRET or _pb.one_tpu()) and d % 128 == 0 and \
         heads % kv == 0 and t % 128 == 0 and t * d <= _CAUSAL_MAX_HEAD
     if not ok:
-        _count("fallbacks", "causal_attention", d)
+        _count("fallbacks", _causal_counts_as(window), d)
     return ok
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def causal_gqa_attention_fused(q, k, v, heads, kv, blocks=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def causal_gqa_attention_fused(q, k, v, heads, kv, blocks=None, window=None):
     """Causal softmax(q·kᵀ/√hd)·v of `heads` query heads over `kv`
     key-value heads, read in place from the projections: q (B, T, Hq·hd),
     k and v (B, T, Hkv·hd) → (B, T, Hq·hd).  `blocks` = (block_q,
-    block_k) is the tests' (default: `_causal_blocks`)."""
-    return _causal_fwd_pallas(q, k, v, heads, kv, blocks)[0]
+    block_k) is the tests' (default: `_causal_blocks`).  With `window` a
+    row sees its own key and the `window` − 1 before it, and the kernels
+    (`mx_window_attn_fwd` / `_bwd`) visit the band's key blocks only."""
+    return _causal_fwd_pallas(q, k, v, heads, kv, blocks, window)[0]
 
 
-def _causal_vjp_fwd(q, k, v, heads, kv, blocks):
-    o, lse = _causal_fwd_pallas(q, k, v, heads, kv, blocks)
+def _causal_vjp_fwd(q, k, v, heads, kv, blocks, window):
+    o, lse = _causal_fwd_pallas(q, k, v, heads, kv, blocks, window)
     return o, (q, k, v, o, lse)
 
 
-def _causal_vjp_bwd(heads, kv, blocks, res, g):
+def _causal_vjp_bwd(heads, kv, blocks, window, res, g):
     q, k, v, o, lse = res
-    dq, dk, dv = _causal_bwd_pallas(q, k, v, o, lse, g, heads, kv, blocks)
+    dq, dk, dv = _causal_bwd_pallas(q, k, v, o, lse, g, heads, kv, blocks,
+                                    window)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 

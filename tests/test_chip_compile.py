@@ -140,6 +140,30 @@ def test_causal_gqa_attention_fwd_and_grad_at_the_cell_shape(one_chip,
     no_tile(text)
 
 
+def test_windowed_attention_fwd_and_grad_at_the_cell_shape(one_chip,
+                                                          compiled_kernels):
+    """What `trinity-mini-train-swa8k` runs four times a step: 32 query
+    heads over 4 key-value heads of 128 at T = 8192 with a window of 2048,
+    routed from `ops/nn.py::causal_gqa_attention(window=)`.  The program
+    holds the windowed pair by name, not the causal one, and no score
+    tile."""
+    from mxnet_tpu.ops import nn
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.float32,
+                              sharding=one_chip)
+    assert pallas_kernels.causal_attention_use_pallas(8192, 32, 4, 128, 2048)
+    text = _compile(jax.grad(
+        lambda a, b, c: jnp.sum(
+            nn.causal_gqa_attention(a, b, c, window=2048) ** 2),
+        argnums=(0, 1, 2)), q, kv, kv)
+    assert "mx_window_attn_fwd" in text and "mx_window_attn_bwd" in text
+    assert "mx_causal_attn" not in text
+    assert text.count("tpu_custom_call") >= 2
+    assert "256,256]" not in text and "256,512]" not in text
+    _no_square(text, 8192)
+
+
 def test_ssd_fwd_and_grad_at_the_cell_shape(one_chip, compiled_kernels):
     """What `nemotron3-nano-train-8k` runs in each of its four Mamba-2
     layers: 64 heads of 64 in 8 groups, state 128, T = 8192 in chunks of
