@@ -1478,3 +1478,226 @@ def _delta_vjp_bwd(counted, res, do):
 
 
 _delta_rule_tiles.defvjp(_delta_vjp_fwd, _delta_vjp_bwd)
+
+
+# ---------------------------------- a held expert's products, its live rows
+# `parallel/moe.py::moe_topk_held` gives every held expert a slot of `rows`
+# sorted rows: the first `live` are its tokens, the rest the gather's zeros.
+# These kernels run a slot's products on the row tiles that hold a live row
+# and on no other: the grid's length is that number of tiles (one at
+# least), so a tile wholly past `live` is neither read nor written.  Its
+# rows of a result keep whatever HBM held, and nothing reads them (the row
+# adds stop at `live`, the sorted weights' cotangent is masked by it).
+# Inside a computed tile the rows past `live` come out zero: their inputs
+# are zero, and so is `act(0)`.
+#
+# Rows on the output (`mx_moe_live_fwd`, `mx_moe_live_bwd`): a (tile, D)
+# tile of rows through both of the expert's weights, each held once in VMEM
+# as bfloat16 -- read in place from the stack of the held experts' weights
+# at the prefetched expert, one buffer (its block never moves) -- with the
+# activation, its derivative and the sorted weights applied to the float32
+# tiles between the products.  Rows on the reduction (`mx_moe_live_*_grad`):
+# aᵀ b over the live tiles, summed into a float32 block of the weight's
+# cotangent that stays in VMEM while they pass; the cotangent is cut along
+# the model width D so that a block fits.
+#
+# Precision is the TPU's default for the float32 products they replace:
+# MXU operands rounded to bfloat16, float32 sums.  The hidden rows and
+# their cotangent, which are only ever MXU operands, are stored rounded.
+_LIVE_TILE = 128             # rows a grid step
+_LIVE_WEIGHTS = 40 * 2 ** 20  # an expert's two weights whole in VMEM, bf16
+_LIVE_BLOCK = 4 * 2 ** 20    # a block of a weight's float32 cotangent
+
+
+def live_use_pallas(rows, d, f, fu, dtype):
+    """The routing decision of `moe_topk_held`'s products: one TPU (or the
+    tests' interpret switch), float32 rows, D in whole 128-lane blocks, a
+    slot in whole tiles of 128 rows, an expert's (D, fu) and (F, D)
+    weights whole in VMEM as bfloat16.  The expert width is taken whole,
+    so F need not be in lane blocks -- but for an activation that splits a
+    fused (fu = 2F) product in two, each half in whole lane blocks.
+    Counted either way, once a traced layer:
+    ``dispatch.pallas.hits.moe_live.<D>`` / ``...fallbacks.moe_live.<D>``."""
+    ok = (_FORCE_INTERPRET or _pb.one_tpu()) and d % 128 == 0 and \
+        rows % _LIVE_TILE == 0 and (fu == f or f % 128 == 0) and \
+        2 * d * (f + fu) <= _LIVE_WEIGHTS and jnp.dtype(dtype) == jnp.float32
+    _count("hits" if ok else "fallbacks", "moe_live", d)
+    return ok
+
+
+def _live_tiles(live):
+    """The grid's length: the tiles that hold a live row, one at least (a
+    weight's cotangent is written even where the slot is empty)."""
+    return jnp.maximum((live + _LIVE_TILE - 1) // _LIVE_TILE, 1)
+
+
+def _live_fwd_kernel(_, x_ref, w_ref, up_ref, down_ref, o_ref, *, act):
+    f32 = jnp.float32
+    h = act(jnp.dot(_mxu(x_ref[...]), up_ref[...],
+                    preferred_element_type=f32))
+    o_ref[...] = jnp.dot(_mxu(h), down_ref[...],
+                         preferred_element_type=f32) * w_ref[...]
+
+
+def _live_bwd_kernel(_, x_ref, g_ref, w_ref, up_ref, down_ref, h_ref, dh_ref,
+                     dx_ref, dw_ref, *, act):
+    """The hidden rows' cotangent from ``g downᵀ``, which also gives the
+    sorted weights': Σ act(x up) · (g downᵀ) over F -- no second product by
+    down is needed."""
+    f32 = jnp.float32
+    gh = jax.lax.dot_general(_mxu(g_ref[...]), down_ref[...], _NT,
+                             preferred_element_type=f32)
+    h, back = jax.vjp(act, jnp.dot(_mxu(x_ref[...]), up_ref[...],
+                                   preferred_element_type=f32))
+    dh = back(gh * w_ref[...])[0]
+    h_ref[...] = h.astype(h_ref.dtype)
+    dh_ref[...] = dh.astype(dh_ref.dtype)
+    dw_ref[...] = jnp.sum(h * gh, axis=1, keepdims=True)
+    dx_ref[...] = jax.lax.dot_general(_mxu(dh), up_ref[...], _NT,
+                                      preferred_element_type=f32)
+
+
+def _live_rows(kernel, name, e, live, rows_in, stacks, outs):
+    """`kernel` on the live row tiles: `rows_in` (rows, c) arrays in
+    (tile, c) blocks, expert `e`'s weights of `stacks` (count, ·, ·) whole
+    and once; `outs` the (rows, c) results' shapes."""
+    from jax.experimental.pallas import tpu as pltpu
+    tm = _LIVE_TILE
+
+    def tile(a):
+        return pl.BlockSpec((tm, a.shape[1]), lambda i, e: (i, 0))
+
+    def whole(w):
+        return pl.BlockSpec((None,) + w.shape[1:], lambda i, e: (e[0], 0, 0),
+                            pipeline_mode=pl.Buffered(1))
+
+    widths = [a.shape[1] for a in list(rows_in) + list(outs)] + \
+        [w.shape[2] for w in stacks]
+    vmem = sum(w.size // w.shape[0] * w.dtype.itemsize for w in stacks) + \
+        4 * tm * (2 * sum(_lanes(c) for c in widths) +
+                  6 * max(_lanes(c) for c in widths)) + (4 << 20)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(_live_tiles(live),),
+            in_specs=[tile(a) for a in rows_in] + [whole(w) for w in stacks],
+            out_specs=[tile(a) for a in outs]),
+        out_shape=outs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
+        interpret=_interpret(), name=name,
+    )(jnp.reshape(e, (1,)).astype(jnp.int32), *rows_in, *stacks)
+
+
+def _live_sum_kernel(_, a_ref, b_ref, *refs, scaled, add):
+    w_ref = refs[0] if scaled else None
+    stack_ref, o_ref = refs[-2:]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        o_ref[...] = stack_ref[...] if add else \
+            jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    b = b_ref[...]
+    if scaled:
+        b = b * w_ref[...]
+    o_ref[0] += jax.lax.dot_general(_mxu(a_ref[...]), _mxu(b), _TN,
+                                    preferred_element_type=jnp.float32)
+
+
+def _live_sum(name, e, live, a, b, w, stack, add, cut_a=True):
+    """aᵀ (b w) over the live row tiles of a (rows, P) and b (rows, Q) (w
+    (rows, 1), or none) → `stack` (count, P, Q) float32 with expert `e`'s
+    (P, Q) replaced by it (`add`: by it added to what was there), in place;
+    made in blocks of a's columns (`cut_a`) or b's, in whole lane tiles."""
+    from jax.experimental.pallas import tpu as pltpu
+    rows, p = a.shape
+    q = b.shape[1]
+    tm = _LIVE_TILE
+    cut, whole = (p, q) if cut_a else (q, p)
+    blk = 128 * _fit_block(cut // 128,
+                           max(1, _LIVE_BLOCK // (128 * 4 * _lanes(whole))))
+    specs = [pl.BlockSpec((tm, blk if cut_a else p),
+                          lambda j, i, e: (i, j if cut_a else 0)),
+             pl.BlockSpec((tm, q if cut_a else blk),
+                          lambda j, i, e: (i, 0 if cut_a else j))]
+    ins = [a, b]
+    if w is not None:
+        ins.append(w)
+        specs.append(pl.BlockSpec((tm, 1), lambda j, i, e: (i, 0)))
+    out = pl.BlockSpec((1,) + ((blk, q) if cut_a else (p, blk)),
+                       lambda j, i, e: (e[0], j, 0) if cut_a else
+                       (e[0], 0, j))
+    # the block in, out and summed (twice each), its product's temporaries
+    vmem = 4 * (8 * blk * _lanes(whole) +
+                tm * 3 * (_lanes(specs[0].block_shape[1]) +
+                          _lanes(specs[1].block_shape[1]))) + (4 << 20)
+    return pl.pallas_call(
+        functools.partial(_live_sum_kernel, scaled=w is not None, add=add),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(cut // blk, _live_tiles(live)),
+            # what was there is read only where it is added onto
+            in_specs=specs + [out if add else pl.BlockSpec(
+                memory_space=pl.ANY)],
+            out_specs=out),
+        out_shape=jax.ShapeDtypeStruct(stack.shape, stack.dtype),
+        input_output_aliases={len(ins) + 1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
+        interpret=_interpret(), name=name,
+    )(jnp.reshape(e, (1,)).astype(jnp.int32), *ins, stack)
+
+
+def slot_products(act, e, live, xs, up, down, w):
+    """What one slot of held expert `e` gives, ``act(xs up_e) down_e * w``,
+    on its live row tiles: xs (rows, D), its first `live` rows live, float32
+    or already rounded to bfloat16; up (count, D, fu) and down (count, F,
+    D), the held experts' stacks, bfloat16; w (rows, 1) float32 → (rows,
+    D) float32."""
+    rows, d = xs.shape
+    return _live_rows(
+        functools.partial(_live_fwd_kernel, act=act), "mx_moe_live_fwd", e,
+        live, (xs, w), (up, down),
+        [jax.ShapeDtypeStruct((rows, d), jnp.float32)])[0]
+
+
+def slot_products_vjp(act, e, live, xs, up, down, w, dout, sums, add):
+    """The cotangents of `slot_products` for the rows' ``dout`` (rows, D):
+    → (d xs, d up, d down, d w (rows, 1)), float32, where the weights' are
+    `sums` (as `weight_sums` makes them) with expert `e`'s written in place
+    (`add`: added onto what was there)."""
+    rows, d = xs.shape
+    f, fu = down.shape[1], up.shape[2]
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    h, dh, dxs, dw = _live_rows(
+        functools.partial(_live_bwd_kernel, act=act), "mx_moe_live_bwd", e,
+        live, (xs, dout, w), (up, down),
+        [jax.ShapeDtypeStruct((rows, f), bf16),
+         jax.ShapeDtypeStruct((rows, fu), bf16),
+         jax.ShapeDtypeStruct((rows, d), f32),
+         jax.ShapeDtypeStruct((rows, 1), f32)])
+    up_t = sums[0].shape != up.shape
+    d_up = _live_sum("mx_moe_live_up_grad", e, live, *(
+        (dh, xs) if up_t else (xs, dh)), None, sums[0], add, cut_a=not up_t)
+    d_down = _live_sum("mx_moe_live_down_grad", e, live, h, dout, w, sums[1],
+                       add, cut_a=False)
+    return dxs, d_up, d_down, dw
+
+
+def weight_sums(up, down):
+    """Zeros for the held experts' weight cotangents as `slot_products_vjp`
+    writes them.  Up's is made as (count, fu, D) where fu is no whole lane
+    tiles: the TPU lays such a (count, D, fu) array out with D minor, so
+    that `weights_of` then moves nothing."""
+    if up.shape[2] % 128:
+        return (jnp.zeros((up.shape[0], up.shape[2], up.shape[1]),
+                          jnp.float32), jnp.zeros(down.shape, jnp.float32))
+    return jnp.zeros(up.shape, jnp.float32), jnp.zeros(down.shape, jnp.float32)
+
+
+def weights_of(sums, up):
+    """The weights' cotangents from `weight_sums`' form: up's, down's."""
+    d_up, d_down = sums
+    return (d_up if d_up.shape == up.shape else jnp.swapaxes(d_up, 1, 2),
+            d_down)

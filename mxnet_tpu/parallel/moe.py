@@ -186,8 +186,10 @@ def moe_topk_held(x, router_w, bias, up, down, held, top_k, scaling=1.0,
         token = jnp.pad((order // top_k).astype(jnp.int32), (0, rows))
         w_sorted = jnp.pad(weight.reshape(-1)[order], (0, rows))
     kernel = pallas_kernels.rows_use_pallas(rows, D, x.dtype)
-    return _held_experts(rows, act, kernel, x, up, down, w_sorted, token,
-                         sizes, starts), load
+    tiles = pallas_kernels.live_use_pallas(rows, D, down.shape[1],
+                                           up.shape[2], x.dtype)
+    return _held_experts(rows, act, (kernel, tiles), x, up, down, w_sorted,
+                         token, sizes, starts), load
 
 
 # Every row a held expert reads or writes is moved once, in place, by an
@@ -200,24 +202,31 @@ def moe_topk_held(x, router_w, bias, up, down, held, top_k, scaling=1.0,
 # sorted, XLA's TPU scatter takes another path that is slower (0.73 -> 0.90
 # ms a slot at Nemotron's shape, 0.46 -> 0.96 at Solar's) and rounds (the
 # gradients came out 1e-3 off; PERF.md section 6, PR 35).  On one TPU the
-# adds are ``pallas_kernels.rows_scatter_add``'s instead.
+# adds are ``pallas_kernels.rows_scatter_add``'s instead, and the products
+# ``pallas_kernels.slot_products``' (and its vjp's), which stop at the last
+# 128-row tile that holds a live row: a slot's rows past it are read and
+# written by nothing.
 
 
 def _slot_pairs(token, w_sorted, size, start, r, rows, S):
     """Rows [r * rows, (r + 1) * rows) of one expert's sorted pairs: their
     tokens (S + j for a row past ``size``), their weights (0 there), where
-    they lie among the sorted pairs, and which are live (they lead)."""
+    they lie among the sorted pairs, which are live (they lead), and how
+    many."""
     j = r * rows + jnp.arange(rows, dtype=jnp.int32)
     live = j < size
     at = start + r * rows
     tok = jnp.where(live, lax.dynamic_slice(token, (at,), (rows,)), S + j)
     w = jnp.where(live, lax.dynamic_slice(w_sorted, (at,), (rows,)), 0)
-    return tok, w, at, live
+    return tok, w, at, live, jnp.clip(size - r * rows, 0, rows)
 
 
-def _slot_rows(a, tok):
-    return a.at[tok].get(mode="fill", fill_value=0, indices_are_sorted=True,
+def _slot_rows(a, tok, rounded=False):
+    """Rows ``tok`` of ``a``; ``rounded``: as bfloat16, for the kernels,
+    whose products round every operand so (the cast is the gather's)."""
+    rows = a.at[tok].get(mode="fill", fill_value=0, indices_are_sorted=True,
                          unique_indices=True)
+    return rows.astype(jnp.bfloat16) if rounded else rows
 
 
 def _no_rows(x, kernel):
@@ -234,10 +243,9 @@ def _as_rows_of(x, y, kernel):
     return lax.optimization_barrier(y.reshape(x.shape)) if kernel else y
 
 
-def _add_rows(y, tok, live, upd, kernel):
+def _add_rows(y, tok, n, upd, kernel):
     if kernel:
-        return pallas_kernels.rows_scatter_add(
-            y, tok, live.sum(dtype=jnp.int32), upd.astype(y.dtype))
+        return pallas_kernels.rows_scatter_add(y, tok, n, upd.astype(y.dtype))
     return y.at[tok].add(upd.astype(y.dtype), mode="drop",
                          unique_indices=True)
 
@@ -247,82 +255,113 @@ def _slot_products(act, xs, e_up, e_down, w):
     return jnp.matmul(h.astype(xs.dtype), e_down) * w[:, None]
 
 
+def _experts_as_read(up, down, tiles):
+    """What the scan over the held experts hands each, and the stacks a
+    slot's products read in place: for the kernels, its index and the
+    stacks rounded to bfloat16 (as the TPU's default precision rounds
+    them); for XLA, its weights and none."""
+    if tiles:
+        return jnp.arange(up.shape[0], dtype=jnp.int32), (
+            up.astype(jnp.bfloat16), down.astype(jnp.bfloat16))
+    return (up, down), ()
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _held_experts(rows, act, kernel, x, up, down, w_sorted, token, sizes,
+def _held_experts(rows, act, kernels, x, up, down, w_sorted, token, sizes,
                   starts):
     """What the held experts add, (S, D): a scan over them with the result
     as its carry; each is given its first slot always and a further one
     while it has rows (a loop of as many trips, not differentiated).
-    ``kernel``: the rows are added by ``mx_rows_scatter_add``."""
+    ``kernels = (rows, tiles)``: the rows are added by
+    ``mx_rows_scatter_add``; the products run on the live row tiles."""
     S = x.shape[0]
+    kernel, tiles = kernels
+    each, stacks = _experts_as_read(up, down, tiles)
 
-    def slot(r, y, e_up, e_down, size, start):
-        tok, w, _, live = _slot_pairs(token, w_sorted, size, start, r, rows,
+    def slot(r, y, e, size, start):
+        tok, w, _, _, n = _slot_pairs(token, w_sorted, size, start, r, rows,
                                       S)
         with jax.named_scope("moe.dispatch"):
-            xs = _slot_rows(x, tok)
+            xs = _slot_rows(x, tok, tiles)
         with jax.named_scope("moe.experts"):
-            out = _slot_products(act, xs, e_up, e_down, w)
+            out = pallas_kernels.slot_products(
+                act, e, n, xs, *stacks, w[:, None]) if tiles else \
+                _slot_products(act, xs, *e, w)
         with jax.named_scope("moe.combine"):
-            return _add_rows(y, tok, live, out, kernel)
+            return _add_rows(y, tok, n, out, kernel)
 
     def expert(y, held_e):
-        slots = -(-held_e[2] // rows)       # the first always, more if sent
+        slots = -(-held_e[1] // rows)       # the first always, more if sent
         return lax.fori_loop(1, slots, lambda r, y: slot(r, y, *held_e),
                              slot(0, y, *held_e)), None
 
     with jax.named_scope("moe.combine"):
         y = _no_rows(x, kernel)
-    y, _ = lax.scan(expert, y, (up, down, sizes, starts))
+    y, _ = lax.scan(expert, y, (each, sizes, starts))
     with jax.named_scope("moe.combine"):
         return _as_rows_of(x, y, kernel)
 
 
-def _held_experts_fwd(rows, act, kernel, *args):
+def _held_experts_fwd(rows, act, kernels, *args):
     # nothing per expert or per slot is kept: the backward pass gathers a
     # slot's rows again
-    return _held_experts(rows, act, kernel, *args), args
+    return _held_experts(rows, act, kernels, *args), args
 
 
-def _held_experts_bwd(rows, act, kernel, res, g):
+def _held_experts_bwd(rows, act, kernels, res, g):
     """By hand over the sorted slots: a scan over the held experts carrying
     x's cotangent (S, D) and the sorted weights'; a slot gathers its rows of
-    ``g`` and of ``x``, takes ``jax.vjp`` of its products (``act`` is any
-    callable), and adds its rows' cotangent in place on the carry."""
+    ``g`` and of ``x``, takes the cotangents of its products -- on the live
+    row tiles (``pallas_kernels.slot_products_vjp``), or by ``jax.vjp``
+    (``act`` is any callable) -- and adds its rows' cotangent in place on
+    the carry.  The weights' cotangents are the kernels' stacks in the
+    carry, each expert's written in place; for XLA the scan's results."""
     x, up, down, w_sorted, token, sizes, starts = res
     S = x.shape[0]
+    kernel, tiles = kernels
+    each, stacks = _experts_as_read(up, down, tiles)
 
-    def slot(r, dx, dws, e_up, e_down, size, start):
-        tok, w, at, live = _slot_pairs(token, w_sorted, size, start, r, rows,
-                                       S)
+    def slot(r, first, dx, dws, s_up, s_down, e, size, start):
+        tok, w, at, live, n = _slot_pairs(token, w_sorted, size, start, r,
+                                          rows, S)
         with jax.named_scope("moe.combine"):
             dout = _slot_rows(g, tok)
         with jax.named_scope("moe.dispatch"):
-            xs = _slot_rows(x, tok)
+            xs = _slot_rows(x, tok, tiles)
         with jax.named_scope("moe.experts"):
-            dxs, d_up, d_down, dw = jax.vjp(
-                functools.partial(_slot_products, act), xs, e_up, e_down,
-                w)[1](dout)
+            if tiles:
+                dxs, d_up, d_down, dw = pallas_kernels.slot_products_vjp(
+                    act, e, n, xs, *stacks, w[:, None], dout,
+                    (s_up, s_down), not first)
+                dw = dw[:, 0]
+            else:
+                dxs, d_up, d_down, dw = jax.vjp(
+                    functools.partial(_slot_products, act), xs, *e,
+                    w)[1](dout)
+                if not first:
+                    d_up, d_down = s_up + d_up, s_down + d_down
         with jax.named_scope("moe.dispatch"):
-            dx = _add_rows(dx, tok, live, dxs, kernel)
+            dx = _add_rows(dx, tok, n, dxs, kernel)
             # the slot's pairs are contiguous among the sorted ones
             dws = lax.dynamic_update_slice(dws, jnp.where(
                 live, dw, lax.dynamic_slice(dws, (at,), (rows,))), (at,))
         return dx, dws, d_up, d_down
 
     def expert(carry, held_e):
-        def more(r, c):
-            dx, dws, d_up, d_down = slot(r, *c[:2], *held_e)
-            return dx, dws, c[2] + d_up, c[3] + d_down
-
+        # a further slot adds its weights' cotangents onto the first's
         dx, dws, d_up, d_down = lax.fori_loop(
-            1, -(-held_e[2] // rows), more, slot(0, *carry, *held_e))
-        return (dx, dws), (d_up, d_down)
+            1, -(-held_e[1] // rows),
+            lambda r, c: slot(r, False, *c, *held_e),
+            slot(0, True, *carry, *held_e))
+        if tiles:
+            return (dx, dws, d_up, d_down), None
+        return (dx, dws, None, None), (d_up, d_down)
 
     with jax.named_scope("moe.dispatch"):
-        none = _no_rows(x, kernel), jnp.zeros_like(w_sorted)
-    (dx, dws), (d_up, d_down) = lax.scan(expert, none,
-                                         (up, down, sizes, starts))
+        none = (_no_rows(x, kernel), jnp.zeros_like(w_sorted)) + (
+            pallas_kernels.weight_sums(up, down) if tiles else (None, None))
+    (dx, dws, *sums), d_ws = lax.scan(expert, none, (each, sizes, starts))
+    d_up, d_down = pallas_kernels.weights_of(sums, up) if tiles else d_ws
     with jax.named_scope("moe.dispatch"):
         return (_as_rows_of(x, dx, kernel), d_up, d_down, dws, None, None,
                 None)

@@ -222,11 +222,70 @@ def test_routed_experts_rows_at_the_cell_shapes(one_chip, compiled_kernels,
     assert pallas_kernels.rows_use_pallas(slot, d, jnp.float32)
     text = _compile(jax.grad(held, (0, 1, 2, 3)), arg(s, d), arg(e, d),
                     arg(8, d, fu), arg(8, f, d), arg(s, d))
-    assert text.count('custom_call_target="tpu_custom_call"') == 4
-    assert "mx_rows_scatter_add" in text
+    assert _kernel_calls(text, "mx_rows_scatter_add") == 4
     assert f"f32[{s},{d // 128},128]" in text
     assert not [line for line in text.splitlines()
                 if " scatter(" in line and f"= f32[{s},{d}]" in line]
+
+
+def _kernel_calls(text, name):
+    """How many calls of the kernel `name` the compiled text makes."""
+    return sum('custom_call_target="tpu_custom_call"' in line and
+               f"/{name}/" in line for line in text.splitlines())
+
+
+# held experts' slots at the three cells' shapes: tokens, D, F, up's width
+# (2F for SwiGLU's fused product), experts, top-k, held, slot rows
+EXPERT_CELLS = {
+    "trinity-mini-train-swa8k": (8192, 2048, 1024, 2048, 128, 8, 16, 5120,
+                                 "swiglu"),
+    "solar-open2-train-kda": (4096, 4096, 1280, 2560, 320, 8, 8, 1024,
+                              "swiglu"),
+    "nemotron3-nano-train-8k": (8192, 2688, 1856, 1856, 128, 6, 8, None,
+                                "relu2"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_routed_experts_products_on_live_rows_at_the_cell_shapes(
+        one_chip, compiled_kernels, monkeypatch, cell):
+    """`moe_topk_held` forward and gradient at each expert cell's shape
+    (Nemotron's F = 1856 taken as one whole-width block): every product of
+    a slot runs in the live-row kernels -- forward, backward and both
+    weight gradients, for the first slot and the loop of further ones --
+    and the program needs no more memory than the dense route's (the
+    kernels read the bfloat16 stacks in place and write the weights'
+    cotangents into the stacks the scan carries)."""
+    from mxnet_tpu.ops import nn
+    from mxnet_tpu.parallel.moe import moe_topk_held
+    s, d, f, fu, e, k, held, rows, act = EXPERT_CELLS[cell]
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def compiled():
+        def layer(x, rw, up, down, gy):     # a new function a route
+            y, _ = moe_topk_held(x, rw, jnp.zeros((e,)), up, down,
+                                 (0, held), k, 2.5, slot_rows=rows,
+                                 act=getattr(nn, act))
+            return jnp.sum(jnp.tanh(y) * gy)
+        return jax.jit(jax.grad(layer, (0, 1, 2, 3))).lower(
+            arg(s, d), arg(e, d), arg(held, d, fu), arg(held, f, d),
+            arg(s, d)).compile()
+
+    assert pallas_kernels.live_use_pallas(rows or 2304, d, f, fu,
+                                          jnp.float32)
+    live = compiled()
+    text = live.as_text()
+    for name in ("mx_moe_live_fwd", "mx_moe_live_bwd", "mx_moe_live_up_grad",
+                 "mx_moe_live_down_grad"):
+        assert _kernel_calls(text, name) == 2, name
+    monkeypatch.setattr(pallas_kernels, "live_use_pallas",
+                        lambda *shapes: False)
+    dense = compiled()
+    assert "mx_moe_live" not in dense.as_text()
+    assert live.memory_analysis().temp_size_in_bytes <= \
+        dense.memory_analysis().temp_size_in_bytes
 
 
 def _stage_shape(stage, n=128):

@@ -117,6 +117,8 @@ def test_fused_step_loss_is_the_reference_loss_and_takes_the_blocked_route():
                       "dispatch.pallas.fallbacks.ssd.8": 4,
                       # rows of 32 float32 are no lane block: XLA's adds
                       "dispatch.pallas.fallbacks.moe_rows.32": 4,
+                      # and the products on the live rows: XLA's dense ones
+                      "dispatch.pallas.fallbacks.moe_live.32": 4,
                       "dispatch.loss.linear_blocked": 1}
 
 

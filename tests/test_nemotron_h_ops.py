@@ -432,13 +432,15 @@ def test_dead_rows_carry_indices_of_their_own():
     from mxnet_tpu.parallel.moe import _slot_pairs
     token = jnp.pad(jnp.array([1, 4, 7, 2, 3], jnp.int32), (0, 4))
     w = jnp.pad(jnp.arange(1.0, 6.0), (0, 4))
-    tok, ws, at, live = _slot_pairs(token, w, 3, 0, 0, 4, 8)
+    tok, ws, at, live, n = _slot_pairs(token, w, 3, 0, 0, 4, 8)
     assert tok.tolist() == [1, 4, 7, 8 + 3] and ws.tolist() == [1, 2, 3, 0]
-    tok, ws, at, live = _slot_pairs(token, w, 2, 3, 0, 4, 8)
+    assert int(n) == 3
+    tok, ws, at, live, n = _slot_pairs(token, w, 2, 3, 0, 4, 8)
     assert tok.tolist() == [2, 3, 8 + 2, 8 + 3] and int(at) == 3
-    assert live.tolist() == [True, True, False, False]
-    tok, _, at, _ = _slot_pairs(token, w, 5, 0, 1, 4, 8)    # a further slot
+    assert live.tolist() == [True, True, False, False] and int(n) == 2
+    tok, _, at, _, n = _slot_pairs(token, w, 5, 0, 1, 4, 8)  # a further slot
     assert tok.tolist() == [3, 8 + 5, 8 + 6, 8 + 7] and int(at) == 4
+    assert int(n) == 1
     assert bool((jnp.diff(tok) > 0).all())
 
 

@@ -233,7 +233,8 @@ def test_fused_step_loss_is_the_reference_loss_and_counts_its_routes():
     assert routes.pop("dispatch.attention.causal.xla_blocked") \
         == routes.pop("dispatch.pallas.fallbacks.causal_attention.8") >= 1
     assert routes.pop("dispatch.moe.sorted_slots") \
-        == routes.pop("dispatch.pallas.fallbacks.moe_rows.32") >= 4
+        == routes.pop("dispatch.pallas.fallbacks.moe_rows.32") \
+        == routes.pop("dispatch.pallas.fallbacks.moe_live.32") >= 4
     routes.pop("dispatch.cache_misses", None)
     assert routes == {"dispatch.loss.linear_blocked": 1,
                       # one stored value a traced site: SwiGLU's output in
